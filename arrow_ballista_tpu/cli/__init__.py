@@ -208,9 +208,6 @@ def exec_file(ctx, path: str, opts: PrintOptions) -> bool:
 
 
 def main(argv=None) -> None:
-    from ..utils import apply_jax_platform_env
-
-    apply_jax_platform_env()
     ap = argparse.ArgumentParser(
         "ballista-tpu-cli", description="Ballista-TPU interactive SQL shell"
     )

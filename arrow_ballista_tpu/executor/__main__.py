@@ -12,6 +12,7 @@ scheduler via ExecutorStopped (``main.rs:252-299``).
 from __future__ import annotations
 
 import argparse
+import json
 import logging
 import os
 import shutil
@@ -155,14 +156,23 @@ class ShuffleJanitor(threading.Thread):
 
 
 def main(argv=None) -> None:
-    from ..utils import apply_jax_platform_env
-
-    apply_jax_platform_env()
     cfg = load_config(argv)
     from ..scheduler.__main__ import init_logging
 
     init_logging(cfg)
     log = logging.getLogger("ballista.executor")
+
+    # This is the one process that may hold the chip.  Claim it before
+    # registering: a chip that is busy, absent or not the platform
+    # JAX_PLATFORMS asked for stops the executor here, not at the first
+    # query's timeout.
+    from ..utils import resolve_backend
+
+    try:
+        backend = resolve_backend()
+    except RuntimeError as e:
+        log.error("cannot claim the jax backend: %s", e)
+        raise SystemExit(3)
 
     import tempfile
 
@@ -209,9 +219,24 @@ def main(argv=None) -> None:
         metadata, work_dir, cfg["concurrent_tasks"],
         task_isolation=cfg["task_isolation"], plugin_dir=cfg["plugin_dir"],
     )
+    from .. import native
+    from ..ops import routing
+
+    # one machine-readable line: chip_smoke.py (and an operator) reads
+    # which backend THIS process holds and what it runs with
     log.info(
-        "executor %s starting: flight :%d, policy=%s, work_dir=%s",
-        executor.id, flight.port, policy.value, work_dir,
+        "executor %s starting: %s",
+        executor.id,
+        json.dumps(
+            {
+                **backend,
+                "native_partitioner": native.status(),
+                "routing_table": routing.current().source,
+                "flight_port": flight.port,
+                "policy": policy.value,
+                "work_dir": work_dir,
+            }
+        ),
     )
 
     janitor = None
@@ -267,7 +292,17 @@ def main(argv=None) -> None:
         while not stop["flag"]:
             time.sleep(0.5)
     finally:
-        log.info("executor %s shutting down", executor.id)
+        import jax
+
+        # peak device memory, where the backend reports it (TPU does)
+        peaks = [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.local_devices()
+        ]
+        log.info(
+            "executor %s shutting down: %s",
+            executor.id, json.dumps({"peak_device_bytes": peaks}),
+        )
         try:
             stub.ExecutorStopped(
                 pb.ExecutorStoppedParams(

@@ -211,7 +211,10 @@ class Executor:
         error becomes a Failed TaskStatus."""
         if self.task_isolation == "process" and self._worker_eligible(task):
             return self._execute_in_worker(task)
+        from ..ops import xla_meter
         from ..testing.faults import fault_point
+
+        xla_before = xla_meter.snapshot()
 
         # observability ratchets on with the first traced task and the
         # task's trace context (minted at the scheduler) adopts on this
@@ -287,6 +290,10 @@ class Executor:
                     ):
                         if k in wvals:
                             wspan.set_attr(k, wvals[k])
+                # executables this task's thread had XLA compile (or load
+                # from the persistent cache), on the stage's root operator
+                for k, v in xla_meter.since(xla_before).items():
+                    writer.metrics.add(k, v)
                 metrics = collect_plan_metrics(writer)
                 self.metrics_collector.record_stage(
                     pid.job_id, pid.stage_id, pid.partition_id, writer, metrics

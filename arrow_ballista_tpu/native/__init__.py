@@ -1,13 +1,19 @@
 """Native (C++) data-plane kernels, bound via ctypes.
 
-``partitioner.cc`` is compiled lazily to ``build/libabt_native.so`` on
-first import (g++ is part of the baked toolchain); if compilation is
-impossible the pure-Python fallbacks take over transparently.
+``partitioner.cc`` is compiled on first use (g++ is part of the baked
+toolchain) into ``build/libabt_native-<key>.so``, where the key hashes
+the source, the compiler flags and this host's CPU feature flags: a
+binary is only ever loaded on the kind of machine that built it from
+this exact source (``-march=native`` output copied from another host is
+a SIGILL).  If compilation is impossible the pure-Python fallbacks take
+over — with a WARNING, and :func:`status` says so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -16,38 +22,69 @@ from typing import Optional
 import numpy as np
 import pyarrow as pa
 
+log = logging.getLogger(__name__)
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "partitioner.cc")
 _BUILD_DIR = os.path.join(_HERE, "build")
-_SO = os.path.join(_BUILD_DIR, "libabt_native.so")
+_CXXFLAGS = ["-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_lock = threading.Lock()
 _build_failed = False
 
 
-def _compile() -> bool:
+def _cpu_flags() -> bytes:
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"flags", b"Features")):
+                    return line
+    except OSError:
+        pass
+    return b""
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_cpu_flags())
+    return os.path.join(_BUILD_DIR, f"libabt_native-{h.hexdigest()[:16]}.so")
+
+
+def _compile(so: str) -> bool:
+    """Build to a private temp name, then rename: executor and task-runner
+    children may build at once, and none may load a half-written file."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            [
-                "g++",
-                "-O3",
-                "-march=native",
-                "-shared",
-                "-fPIC",
-                "-std=c++17",
-                "-o",
-                _SO,
-                _SRC,
-            ],
+            ["g++", *_CXXFLAGS, "-o", tmp, _SRC],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, so)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        log.warning(
+            "native partitioner build failed; shuffle partitioning runs "
+            "the pure-Python path: %s %s",
+            e,
+            (getattr(e, "stderr", b"") or b"").decode(errors="replace")[-2000:],
+        )
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
         return False
+
+
+def status() -> str:
+    """``"loaded"`` or ``"python-fallback"`` (builds/loads on first call)."""
+    return "loaded" if get_lib() is not None else "python-fallback"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -59,13 +96,14 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not _compile():
-                _build_failed = True
-                return None
+        so = _so_path()
+        if not os.path.exists(so) and not _compile(so):
+            _build_failed = True
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            log.warning("native partitioner %s did not load: %s", so, e)
             _build_failed = True
             return None
         u8p = ctypes.c_void_p

@@ -478,6 +478,8 @@ def render_explain_analyze(report: dict) -> str:
                 f"tpu {_fmt_ms(tpu.get('compile_ms', 0))} compile / "
                 f"{_fmt_ms(tpu.get('execute_ms', 0))} exec"
             )
+            if tpu.get("device_error"):
+                bits.append(f"DEVICE ERROR x{tpu['device_error']} (re-ran on CPU)")
         skew = (row.get("skew") or {}).get("runtime_ms")
         if skew and skew.get("max_over_median", 0) >= SKEW_COEFFICIENT:
             bits.append(f"skew {skew['max_over_median']:.1f}x")

@@ -364,6 +364,11 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
             if op.startswith("TpuStage") or op.startswith("TpuWindow"):
                 for k, v in vals.items():
                     tpu[k] = tpu.get(k, 0) + v
+            elif op.startswith("MeshGang"):
+                # the gang wrapper owns its own degradation counters
+                for k in ("device_error", "mesh_fallback"):
+                    if vals.get(k):
+                        tpu[k] = tpu.get(k, 0) + vals[k]
             shuffle_bytes += vals.get("bytes_fetched", 0)
             replica_fetches += vals.get("replica_fetches", 0)
             for k in fetch_locality:
@@ -481,6 +486,18 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
                 "compile_cache_hits": tpu.get("compile_cache_hits", 0),
                 "compile_cache_misses": tpu.get("compile_cache_misses", 0),
             }
+            # why a device stage left the device: device_error is the
+            # chip/compiler refusing; the rest are routes the data chose
+            left = {
+                k: tpu[k]
+                for k in (
+                    "device_error", "tpu_fallback", "cpu_fallback",
+                    "mesh_fallback", "join_fallback", "highcard_fallback",
+                )
+                if tpu.get(k)
+            }
+            if left:
+                row["tpu"].update(left)
             # keyed device path: where the group encode ran and whether
             # the encode→sort→segment-reduce pipeline fused into single
             # dispatches (ISSUE 9) — next to the host encode time it

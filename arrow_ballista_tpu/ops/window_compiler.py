@@ -36,6 +36,7 @@ from ..exec.operators import ExecutionPlan, Partitioning, TaskContext
 from ..exec.window import RANKING, VALUE_FNS, WindowExec, WindowSpec
 from . import kernels as K
 from .bridge import arrow_to_numpy, make_key_encoder
+from .stage_compiler import _JaxRuntimeError, note_device_error
 
 _AGG_FNS = {"sum", "avg", "min", "max", "count"}
 
@@ -240,13 +241,11 @@ class TpuWindowExec(ExecutionPlan):
         try:
             with self.metrics.timer("window_time_ns"):
                 win_cols = self._device_eval(batches, n)
-        except (K.NotLowerable, ExecutionError, RuntimeError) as e:
-            self.metrics.add("tpu_fallback", 1)
-            import logging
-
-            logging.getLogger(__name__).debug(
-                "window device path fell back: %s", e
-            )
+        except (K.NotLowerable, ExecutionError, _JaxRuntimeError) as e:
+            if isinstance(e, _JaxRuntimeError):
+                note_device_error(self.metrics, str(self), e)
+            else:  # the DATA chose the CPU window (unshippable keys/values)
+                self.metrics.add("tpu_fallback", 1)
             yield from self._cpu(batches, partition, ctx)
             return
         table = pa.Table.from_batches(batches, schema=self.input.schema)

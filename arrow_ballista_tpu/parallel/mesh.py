@@ -110,6 +110,9 @@ def make_distributed_agg_step(
 
 
 # ------------------------------------------------- on-device repartition
+_EXCHANGE_CACHE: dict = {}
+
+
 def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
     """Multi-column hash-repartition exchange over ICI.
 
@@ -131,6 +134,13 @@ def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
     from jax import shard_map
 
     n_dev = mesh.devices.size
+    # one jitted program per (devices, columns, capacity), reused across
+    # plan instances: a fresh jit per exchange re-traced and re-compiled
+    # every stage of every query
+    cache_key = (tuple(d.id for d in mesh.devices.flat), n_cols, capacity)
+    cached = _EXCHANGE_CACHE.get(cache_key)
+    if cached is not None:
+        return cached
 
     def local_exchange(dest, valid, *cols):
         rows = dest.shape[0]
@@ -163,14 +173,16 @@ def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
         recv_valid = route(None, fill_ok=True)
         return recv_cols + (recv_valid, n_dropped)
 
-    fn = shard_map(
-        local_exchange,
-        mesh=mesh,
-        in_specs=(P(DATA_AXIS),) * (2 + n_cols),
-        out_specs=(P(DATA_AXIS),) * (n_cols + 1) + (P(),),
-        check_vma=False,
+    fn = _EXCHANGE_CACHE[cache_key] = jax.jit(
+        shard_map(
+            local_exchange,
+            mesh=mesh,
+            in_specs=(P(DATA_AXIS),) * (2 + n_cols),
+            out_specs=(P(DATA_AXIS),) * (n_cols + 1) + (P(),),
+            check_vma=False,
+        )
     )
-    return jax.jit(fn)
+    return fn
 
 
 class BatchExchanger:

@@ -144,9 +144,15 @@ def make_backend(cfg: dict):
 
 
 def main(argv=None) -> None:
-    from ..utils import apply_jax_platform_env
+    # The scheduler plans; it never runs a kernel.  Planning imports
+    # modules that import jax (gang_eligible), so pin this process to the
+    # CPU platform whatever the environment says: the chip belongs to the
+    # executor, and a scheduler that claimed it would lock the executor
+    # out.  The config API leaves os.environ alone, so children launched
+    # from here (the autoscaler's executors) still see the host's setting.
+    import jax
 
-    apply_jax_platform_env()
+    jax.config.update("jax_platforms", "cpu")
     cfg = load_config(argv)
     init_logging(cfg)
     log = logging.getLogger("ballista.scheduler")

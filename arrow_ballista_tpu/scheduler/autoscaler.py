@@ -301,6 +301,25 @@ class LocalProcessProvider(ExecutorProvider):
             *self.extra_args,
         ]
         env = {**os.environ, **self.env, **spec.env}
+        # One process per chip: an executor claims every chip of its host
+        # at start-up, so a second one told to use the accelerator can
+        # only fail there.  Refuse it here, by name, instead of feeding
+        # the heal loop a child that dies at start-up.  (CPU executors —
+        # ``env={"JAX_PLATFORMS": "cpu"}`` — are not limited.)
+        from ..utils import asked_platform
+
+        if asked_platform(env) not in ("", "cpu"):
+            with self._lock:
+                live = [
+                    eid for eid, p in self._procs.items() if p.poll() is None
+                ]
+            if live:
+                raise RuntimeError(
+                    f"refusing to launch {spec.executor_id}: JAX_PLATFORMS="
+                    f"{env['JAX_PLATFORMS']!r} and executor(s) {live} "
+                    "already run on this host; a chip belongs to one "
+                    "process (one executor drives every chip of its host)"
+                )
         # the parent may import the package via a sys.path edit (notebook,
         # scratch-dir driver); the child's -m lookup only sees PYTHONPATH,
         # so pin the package root or launches fail rc=1 outside the repo

@@ -5,17 +5,36 @@ from __future__ import annotations
 import os
 
 
-def apply_jax_platform_env() -> None:
-    """Make ``JAX_PLATFORMS`` authoritative.
+def asked_platform(env=os.environ) -> str:
+    """The platform ``JAX_PLATFORMS`` names first ("" when unset)."""
+    return env.get("JAX_PLATFORMS", "").split(",")[0].strip()
 
-    jax honors the env var itself, but platform *plugins* registered via
-    entry points can pin a different backend regardless; the config API
-    always wins, so process entry points (scheduler/executor binaries,
-    benchmark harnesses) call this before any jax compute to guarantee
-    ``JAX_PLATFORMS=cpu`` really means cpu.
+
+def resolve_backend() -> dict:
+    """Touch the jax backend NOW, on purpose, and say what came up.
+
+    A chip belongs to one process: the process that runs kernels claims it
+    at start-up through this call, so a busy or absent chip is a start-up
+    error with jax's own message instead of a first query that hangs.
+    jax raises by itself when a platform listed in ``JAX_PLATFORMS`` cannot
+    initialise; this adds the check that the platform named FIRST there is
+    the one that became the default.
+
+    Returns ``{"platform", "device_kind", "device_count"}`` as jax reports
+    them in this process.
     """
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", platforms)
+    asked = asked_platform()
+    devices = jax.devices()
+    platform = devices[0].platform
+    if asked and asked != platform:
+        raise RuntimeError(
+            f"JAX_PLATFORMS asks for {asked!r} but the default backend "
+            f"is {platform!r}"
+        )
+    return {
+        "platform": platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }

@@ -43,8 +43,6 @@ import pyarrow.parquet as pq
 
 BASE = {
     "ballista.tpu.enable": "false",
-    # jax 0.4.37 in this image lacks shard_map; mesh stages cannot run
-    "ballista.mesh.enable": "false",
 }
 
 

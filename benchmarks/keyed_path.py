@@ -50,8 +50,6 @@ BASE = {
     "ballista.tpu.min_rows": "0",
     # the A/B isolates the execution path, not the device column cache
     "ballista.tpu.cache_columns": "false",
-    # jax 0.4.37 in this image lacks shard_map; mesh stages cannot run
-    "ballista.mesh.enable": "false",
     "ballista.shuffle.partitions": "1",
 }
 

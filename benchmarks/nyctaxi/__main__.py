@@ -58,9 +58,6 @@ def gen_tripdata(n_rows: int, seed: int = 7) -> pa.Table:
 
 
 def main(argv=None) -> None:
-    from arrow_ballista_tpu.utils import apply_jax_platform_env
-
-    apply_jax_platform_env()
     ap = argparse.ArgumentParser("nyctaxi", description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
 

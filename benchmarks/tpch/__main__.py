@@ -295,9 +295,6 @@ def cmd_loadtest(args) -> None:
 
 
 def main(argv=None) -> None:
-    from arrow_ballista_tpu.utils import apply_jax_platform_env
-
-    apply_jax_platform_env()
     ap = argparse.ArgumentParser("tpch", description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
 

@@ -67,12 +67,27 @@ def _dates(rng: np.random.Generator, n: int) -> np.ndarray:
     return rng.integers(_START, _END, n, dtype=np.int32)
 
 
-def gen_lineitem(sf: float, seed: int = 42) -> pa.Table:
-    rng = np.random.default_rng(seed)
-    n_orders = int(1_500_000 * sf)
-    lines_per_order = rng.integers(1, 8, n_orders)
+def _chunk(total: int, seed: int, chunk):
+    """``(rng, lo, hi)`` for one slice of a keyed table.
+
+    ``chunk=None`` is the whole table on the seed's own stream (what every
+    fixture has always used).  ``chunk=(i, k)`` is the i-th of k slices of
+    the key range ``[0, total)`` on an independent stream, so a large
+    table can be generated and written by k processes at once: the slices
+    together have the whole table's keys, schema and distributions — not
+    the rows ``chunk=None`` would draw.
+    """
+    if chunk is None:
+        return np.random.default_rng(seed), 0, total
+    i, k = chunk
+    return np.random.default_rng([seed, i]), total * i // k, total * (i + 1) // k
+
+
+def gen_lineitem(sf: float, seed: int = 42, chunk=None) -> pa.Table:
+    rng, lo, hi = _chunk(int(1_500_000 * sf), seed, chunk)
+    lines_per_order = rng.integers(1, 8, hi - lo)
     n = int(lines_per_order.sum())
-    orderkey = np.repeat(_orderkeys(n_orders), lines_per_order)
+    orderkey = np.repeat(_orderkeys(lo, hi), lines_per_order)
     # vectorized within-order line numbers (a 15M-iteration Python loop at
     # SF10 otherwise dominates datagen)
     starts = np.cumsum(lines_per_order) - lines_per_order
@@ -114,11 +129,10 @@ def gen_lineitem(sf: float, seed: int = 42) -> pa.Table:
     )
 
 
-def _orderkeys(n_orders: int) -> np.ndarray:
+def _orderkeys(lo: int, hi: int) -> np.ndarray:
     # dbgen sparsifies order keys: 8 per 32-key block
-    blocks = np.arange(n_orders) // 8
-    within = np.arange(n_orders) % 8
-    return (blocks * 32 + within + 1).astype(np.int64)
+    idx = np.arange(lo, hi)
+    return ((idx // 8) * 32 + idx % 8 + 1).astype(np.int64)
 
 
 def _comments(rng: np.random.Generator, n: int) -> np.ndarray:
@@ -141,10 +155,10 @@ def _part_names(rng: np.random.Generator, n: int) -> np.ndarray:
     )
 
 
-def gen_orders(sf: float, seed: int = 43) -> pa.Table:
-    rng = np.random.default_rng(seed)
-    n = int(1_500_000 * sf)
-    orderkey = _orderkeys(n)
+def gen_orders(sf: float, seed: int = 43, chunk=None) -> pa.Table:
+    rng, lo, hi = _chunk(int(1_500_000 * sf), seed, chunk)
+    n = hi - lo
+    orderkey = _orderkeys(lo, hi)
     orderdate = _dates(rng, n)
     return pa.table(
         {
@@ -163,10 +177,10 @@ def gen_orders(sf: float, seed: int = 43) -> pa.Table:
     )
 
 
-def gen_customer(sf: float, seed: int = 44) -> pa.Table:
-    rng = np.random.default_rng(seed)
-    n = int(150_000 * sf)
-    key = np.arange(1, n + 1, dtype=np.int64)
+def gen_customer(sf: float, seed: int = 44, chunk=None) -> pa.Table:
+    rng, lo, hi = _chunk(int(150_000 * sf), seed, chunk)
+    n = hi - lo
+    key = np.arange(lo + 1, hi + 1, dtype=np.int64)
     return pa.table(
         {
             "c_custkey": pa.array(key, pa.int64()),

@@ -50,8 +50,6 @@ BASE = {
     # already one fused dispatch, and the A/B measures the GENERALIZED
     # fusion for non-cache-eligible stages
     "ballista.tpu.cache_columns": "false",
-    # jax 0.4.37 in this image lacks shard_map; mesh stages cannot run
-    "ballista.mesh.enable": "false",
     "ballista.shuffle.partitions": "1",
 }
 

@@ -277,7 +277,7 @@ def main() -> None:
                 print(f"  rows={r['rows']:>9} {r['operands']:>6}: "
                       f"{r['rows_per_sec'] / 1e6:6.1f}M rows/s{rel}")
 
-        lat = [r for r in rs if r.get("bench") == "tunnel_latency"
+        lat = [r for r in rs if r.get("bench") == "dispatch_latency"
                and "sec" in r]
         for r in lat:
             print(f"latency {r['metric']}: {r['sec'] * 1000:.2f} ms")

@@ -4,9 +4,13 @@ BENCH_SUITE_r05 h2o: the mesh gang's shard_map compile got its
 tpu_compile_helper SIGKILLed and the uncaught JaxRuntimeError destroyed
 the whole run.  These tests inject JaxRuntimeError into the device
 stage and the mesh gang and assert the query still returns the CPU
-oracle's answer, with the fallback recorded in metrics — while
+oracle's answer — loudly: the traceback is logged at WARNING and the
+degradation counts as ``device_error``, apart from the counters of
+routes the DATA chose (``tpu_fallback``, ``mesh_fallback``) — while
 non-jax RuntimeErrors (genuine bugs) still propagate.
 """
+
+import logging
 
 import numpy as np
 import pyarrow as pa
@@ -56,7 +60,17 @@ def _oracle(t):
     return c.sql(SQL).collect().sort_by([("k", "ascending")])
 
 
-def test_stage_jax_runtime_error_degrades_to_cpu(monkeypatch):
+def _assert_logged_device_error(caplog, needle):
+    recs = [
+        r for r in caplog.records
+        if r.levelno == logging.WARNING and "device error" in r.getMessage()
+    ]
+    assert recs, "device-error degradation was not logged at WARNING"
+    assert recs[0].exc_info and needle in str(recs[0].exc_info[1])
+
+
+def test_stage_jax_runtime_error_degrades_to_cpu(monkeypatch, caplog):
+    caplog.set_level(logging.WARNING)
     t = _table()
     want = _oracle(t)
 
@@ -69,7 +83,44 @@ def test_stage_jax_runtime_error_degrades_to_cpu(monkeypatch):
     plan = ctx.sql(SQL).physical_plan()
     got = ctx.execute(plan).sort_by([("k", "ascending")])
     assert got.equals(want)
-    assert _metrics(plan).get("tpu_fallback", 0) >= 1
+    m = _metrics(plan)
+    assert m.get("device_error", 0) >= 1, m
+    assert "tpu_fallback" not in m and "cpu_fallback" not in m, m
+    _assert_logged_device_error(caplog, "tpu_compile_helper SIGKILL")
+
+
+def test_keyed_route_jax_runtime_error_counts_as_device_error(
+    monkeypatch, caplog
+):
+    # the keyed handler used to catch blanket RuntimeError under
+    # tpu_fallback: a device failure there is a device_error too, and a
+    # plain RuntimeError (a bug) propagates
+    caplog.set_level(logging.WARNING)
+    t = _table()
+    want = _oracle(t)
+
+    def boom(self, *args, **kwargs):
+        raise SC._JaxRuntimeError("RESOURCE_EXHAUSTED: keyed buffer")
+
+    monkeypatch.setattr(SC.TpuStageExec, "_run_keyed", boom)
+    ctx = _ctx(True, **{"ballista.tpu.highcard_mode": "device"})
+    monkeypatch.setattr(SC, "_HIGHCARD_MIN_GROUPS", 1)
+    monkeypatch.setattr(SC, "_HIGHCARD_RATIO", 0.0)
+    ctx.register_table("t", MemoryTable.from_table(t, 1))
+    plan = ctx.sql(SQL).physical_plan()
+    got = ctx.execute(plan).sort_by([("k", "ascending")])
+    assert got.equals(want)
+    m = _metrics(plan)
+    assert m.get("keyed_path", 0) >= 1 and m.get("device_error", 0) >= 1, m
+    assert "tpu_fallback" not in m, m
+    _assert_logged_device_error(caplog, "RESOURCE_EXHAUSTED")
+
+    def bug(self, *args, **kwargs):
+        raise RuntimeError("logic bug in the keyed path")
+
+    monkeypatch.setattr(SC.TpuStageExec, "_run_keyed", bug)
+    with pytest.raises(RuntimeError, match="logic bug"):
+        ctx.sql(SQL).collect()
 
 
 def test_stage_plain_runtime_error_propagates(monkeypatch):
@@ -87,8 +138,10 @@ def test_stage_plain_runtime_error_propagates(monkeypatch):
         ctx.sql(SQL).collect()
 
 
-def test_mesh_gang_jax_runtime_error_degrades(monkeypatch):
+def test_mesh_gang_jax_runtime_error_degrades(monkeypatch, caplog):
     from arrow_ballista_tpu.parallel import mesh_stage as MS
+
+    caplog.set_level(logging.WARNING)
 
     t = _table(n=60000, seed=1)
     want = _oracle(t)
@@ -120,5 +173,7 @@ def test_mesh_gang_jax_runtime_error_degrades(monkeypatch):
                     want.column("sum(v)").to_pylist()):
         assert y == pytest.approx(x, rel=1e-9)
     assert sum(
-        g.metrics.values.get("mesh_fallback", 0) for g in gangs
+        g.metrics.values.get("device_error", 0) for g in gangs
     ) >= 1
+    assert not any("mesh_fallback" in g.metrics.values for g in gangs)
+    _assert_logged_device_error(caplog, "remote_compile HTTP 500")
