@@ -20,10 +20,10 @@ the executor is TOLD its platform so jax raises instead of quietly choosing
 the CPU.  There is no fallback: without ``--platform cpu`` a machine with
 no chip fails at executor start-up and nothing is printed on stdout.
 
-stdout: the full report (one JSON line), then as the LAST line
-``{"ok": ..., "device": {"platform", "kind", "count"}, ..., "claim": null}``
-with the device as the executor process reported it.  Exit 0 only if every
-check held.  Wall times in the report are smoke observations on a shared
+stdout: the full report (one JSON line, ending ``"claim": null``), then as
+the LAST line exactly ``{"ok": ..., "device": {"platform", "kind", "count"}}``
+with the device as the executor process reported it from jax.  Exit 0 only
+if every check held.  Wall times in the report are smoke observations on a shared
 host, not benchmark results.
 """
 
@@ -573,6 +573,7 @@ def main() -> int:
     report["seconds"] = round(time.monotonic() - T0, 1)
     report["checks"] = evaluate(report)
     ok = report["ok"] = all(report["checks"].values())
+    report["claim"] = None  # bring-up: the program runs; no gain is claimed
     if args.report:
         with open(args.report, "w") as f:
             json.dump(report, f, indent=1)
@@ -581,21 +582,13 @@ def main() -> int:
             log(f"check failed: {name}")
     info = report["executor"]
     print(json.dumps(report))
-    print(
-        json.dumps(
-            {
-                "ok": ok,
-                "device": {
-                    "platform": info["platform"],
-                    "kind": info["device_kind"],
-                    "count": info["device_count"],
-                },
-                "sf": args.sf,
-                "seconds": report["seconds"],
-                "claim": None,
-            }
-        )
-    )
+    # the last line is the contract's object: these two keys, nothing else
+    device = {
+        "platform": str(info["platform"]),
+        "kind": str(info["device_kind"]),
+        "count": int(info["device_count"]),
+    }
+    print(json.dumps({"ok": bool(ok), "device": device}), flush=True)
     return 0 if ok else 1
 
 

@@ -43,10 +43,18 @@ def rehearsal(tmp_path_factory):
 
 def test_rehearsal_is_green_and_names_cpu(rehearsal):
     p, report = rehearsal
-    last = json.loads(p.stdout.strip().splitlines()[-1])
-    assert last["ok"] is True and last["claim"] is None
+    lines = p.stdout.strip().splitlines()
+    # the last line is the chip check's contract: exactly these keys
+    last = json.loads(lines[-1])
+    assert set(last) == {"ok", "device"} and last["ok"] is True
+    assert set(last["device"]) == {"platform", "kind", "count"}
     assert last["device"]["platform"] == "cpu"
-    assert list(last)[-1] == "claim"
+    assert isinstance(last["device"]["kind"], str)
+    assert type(last["device"]["count"]) is int
+    # the full report is the line before it and ends with the null claim
+    full = json.loads(lines[-2])
+    assert list(full)[-1] == "claim" and full["claim"] is None
+    assert full["checks"] == report["checks"]
     assert report["executor"]["platform"] == "cpu"
     assert report["executor"]["native_partitioner"] == "loaded"
     assert all(report["checks"].values()), report["checks"]

@@ -42,8 +42,8 @@ SEED = 0xBA11157A  # deterministic job ids etc. (pytest.ini `faults` marker)
 EXEC1 = ExecutorMetadata("exec-1", "127.0.0.1", 50051, 50052, ExecutorSpecification(4))
 EXEC2 = ExecutorMetadata("exec-2", "127.0.0.2", 50051, 50052, ExecutorSpecification(4))
 
-# CPU-only operator path: this environment's jax lacks shard_map, and the
-# fault machinery under test is scheduler/executor-level, not device-level
+# CPU-only operator path: the fault machinery under test is
+# scheduler/executor-level, not device-level
 CPU_CONFIG = {
     "ballista.tpu.enable": "false",
     "ballista.mesh.enable": "false",

@@ -43,9 +43,9 @@ from arrow_ballista_tpu.testing import faults
 
 pytestmark = pytest.mark.obs
 
-# CPU-only operator path (this environment's jax lacks shard_map; the
-# pyarrow sort kernel is broken at seed); telemetry/journal/skew live on
-# the scheduler/executor planes these settings exercise
+# mesh off, two shuffle partitions: telemetry/journal/skew live on the
+# scheduler/executor planes, and with the mesh on an eligible stage
+# becomes ONE gang task, which leaves per-task skew nothing to compare
 CLUSTER_CONFIG = {
     "ballista.obs.enabled": "true",
     "ballista.mesh.enable": "false",
