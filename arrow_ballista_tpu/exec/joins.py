@@ -109,7 +109,8 @@ class HashJoinExec(ExecutionPlan):
 
     # ------------------------------------------------------------ execution
     def _collect_side(
-        self, side: ExecutionPlan, partition: Optional[int], ctx: TaskContext
+        self, side: ExecutionPlan, partition: Optional[int], ctx: TaskContext,
+        timer: Optional[str] = None,
     ) -> pa.Table:
         batches: list[pa.RecordBatch] = []
         if partition is None:
@@ -117,21 +118,26 @@ class HashJoinExec(ExecutionPlan):
                 batches.extend(side.execute(p, ctx))
         else:
             batches.extend(side.execute(partition, ctx))
-        return pa.Table.from_batches(batches, schema=side.schema)
+        if timer is None:
+            return pa.Table.from_batches(batches, schema=side.schema)
+        # the join's own part of collecting a side: the child's execute
+        # above is the child's time, not this operator's
+        with self.metrics.timer(timer):
+            return pa.Table.from_batches(batches, schema=side.schema)
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
         if self.partition_mode == COLLECT_LEFT:
             with self._lock:
                 if self._collect_left_cache is None:
-                    with self.metrics.timer("build_time_ns"):
-                        self._collect_left_cache = self._collect_side(
-                            self.left, None, ctx
-                        )
+                    self._collect_left_cache = self._collect_side(
+                        self.left, None, ctx, "build_time_ns"
+                    )
             left_tbl = self._collect_left_cache
             right_tbl = self._collect_side(self.right, partition, ctx)
         else:
-            with self.metrics.timer("build_time_ns"):
-                left_tbl = self._collect_side(self.left, partition, ctx)
+            left_tbl = self._collect_side(
+                self.left, partition, ctx, "build_time_ns"
+            )
             right_tbl = self._collect_side(self.right, partition, ctx)
 
         with self.metrics.timer("join_time_ns"):

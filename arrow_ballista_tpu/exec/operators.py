@@ -165,12 +165,30 @@ class ScanExec(ExecutionPlan):
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
         schema = self.schema
-        with self.metrics.timer("scan_time_ns"):
-            for b in self.provider.scan_partition(
+        clock = time.perf_counter_ns
+        batches = iter(
+            self.provider.scan_partition(
                 partition, self.projection, ctx.batch_size
-            ):
-                self.metrics.add("output_rows", b.num_rows)
-                yield pa.RecordBatch.from_arrays(b.columns, schema=schema)
+            )
+        )
+        # scan_time_ns is the pull from the provider plus from_arrays: the
+        # clock stops before each yield, so the consumer's work between two
+        # next() calls is never counted as scan
+        scan_ns = rows = 0
+        try:
+            while True:
+                t0 = clock()
+                b = next(batches, None)
+                if b is None:
+                    scan_ns += clock() - t0
+                    return
+                out = pa.RecordBatch.from_arrays(b.columns, schema=schema)
+                scan_ns += clock() - t0
+                rows += b.num_rows
+                yield out
+        finally:
+            self.metrics.add("scan_time_ns", scan_ns)
+            self.metrics.add("output_rows", rows)
 
     def with_new_children(self, children):
         assert not children
@@ -448,25 +466,26 @@ class RepartitionExec(ExecutionPlan):
             buckets: list[list[pa.RecordBatch]] = [[] for _ in range(n)]
             for p in range(self.input.output_partitioning().n):
                 for batch in self.input.execute(p, ctx):
-                    if self.partitioning.kind == "hash":
-                        idx = hash_partition_indices(
-                            batch, list(self.partitioning.exprs), n
-                        )
-                        order, bounds = partition_permutation(idx, n)
-                        tbl = batch.take(pa.array(order))
-                        for b in range(n):
-                            lo, hi = bounds[b], bounds[b + 1]
-                            if hi > lo:
-                                buckets[b].append(tbl.slice(lo, hi - lo))
-                    else:  # round robin by batch
-                        buckets[hash(batch.num_rows) % n].append(batch)
+                    # repart_time_ns is the split itself, not the child's
+                    # execute that feeds it
+                    with self.metrics.timer("repart_time_ns"):
+                        if self.partitioning.kind == "hash":
+                            idx = hash_partition_indices(
+                                batch, list(self.partitioning.exprs), n
+                            )
+                            order, bounds = partition_permutation(idx, n)
+                            tbl = batch.take(pa.array(order))
+                            for b in range(n):
+                                lo, hi = bounds[b], bounds[b + 1]
+                                if hi > lo:
+                                    buckets[b].append(tbl.slice(lo, hi - lo))
+                        else:  # round robin by batch
+                            buckets[hash(batch.num_rows) % n].append(batch)
             self._cache = buckets
             return buckets
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
-        with self.metrics.timer("repart_time_ns"):
-            buckets = self._materialize(ctx)
-        for b in buckets[partition]:
+        for b in self._materialize(ctx)[partition]:
             yield b
 
     def with_new_children(self, children):

@@ -15,6 +15,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Dict, List, Optional
 
 from ..config import BallistaConfig
@@ -211,6 +212,7 @@ class Executor:
         error becomes a Failed TaskStatus."""
         if self.task_isolation == "process" and self._worker_eligible(task):
             return self._execute_in_worker(task)
+        t_entry = time.perf_counter_ns()
         from ..ops import xla_meter
         from ..testing.faults import fault_point
 
@@ -294,6 +296,12 @@ class Executor:
                 # from the persistent cache), on the stage's root operator
                 for k, v in xla_meter.since(xla_before).items():
                     writer.metrics.add(k, v)
+                # this task as the executor ran it, entry to status built;
+                # the scheduler's finish - dispatch less this is what the
+                # poll loop and the status report cost around it
+                writer.metrics.add(
+                    "task_run_ns", time.perf_counter_ns() - t_entry
+                )
                 metrics = collect_plan_metrics(writer)
                 self.metrics_collector.record_stage(
                     pid.job_id, pid.stage_id, pid.partition_id, writer, metrics

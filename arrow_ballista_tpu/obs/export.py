@@ -303,6 +303,25 @@ def chrome_trace(spans: List[dict], job_id: str = "") -> dict:
     return out
 
 
+# MeshGangExec's phase counters -> their names in a profile row's "tpu"
+# block (*_ns become *_ms).  The seven phases are self times: they sum to
+# gang_stage_ms up to loop overhead.
+_GANG_PHASES = (
+    ("mesh_stage_time_ns", "gang_stage_ms"),
+    ("gang_scan_ns", "gang_scan_ms"),
+    ("key_encode_time_ns", "gang_encode_ms"),
+    ("gang_convert_ns", "gang_convert_ms"),
+    ("gang_upload_ns", "gang_upload_ms"),
+    ("gang_assemble_ns", "gang_assemble_ms"),
+    ("gang_step_ns", "gang_step_ms"),
+    ("gang_materialize_ns", "gang_materialize_ms"),
+    ("gang_cpu_ns", "gang_cpu_ms"),
+    ("gang_uploads", "gang_uploads"),
+    ("gang_batches", "gang_batches"),
+    ("gang_partitions", "gang_partitions"),
+)
+
+
 def _stage_of(span: dict) -> Optional[int]:
     st = (span.get("attrs") or {}).get("stage")
     try:
@@ -349,6 +368,7 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
         sid = int(r["stage_id"])
         metrics = r.get("metrics") or {}
         tpu = {}
+        gang = {}
         shuffle_bytes = 0
         replica_fetches = 0
         write = {}
@@ -369,6 +389,9 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
                 for k in ("device_error", "mesh_fallback"):
                     if vals.get(k):
                         tpu[k] = tpu.get(k, 0) + vals[k]
+                for k, _ in _GANG_PHASES:
+                    if k in vals:
+                        gang[k] = gang.get(k, 0) + vals[k]
             shuffle_bytes += vals.get("bytes_fetched", 0)
             replica_fetches += vals.get("replica_fetches", 0)
             for k in fetch_locality:
@@ -529,6 +552,17 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
             }
             if any(fusion.values()):
                 row["tpu"].update(fusion)
+        if gang:
+            # mesh gang stage: where its one task's wall (gang_stage_ms)
+            # went, phase by phase, from MeshGangExec's always-on counters
+            row.setdefault("tpu", {}).update(
+                {
+                    name: round(gang[k] / _NS_PER_MS, 3)
+                    if k.endswith("_ns") else gang[k]
+                    for k, name in _GANG_PHASES
+                    if k in gang
+                }
+            )
         stages.append(row)
 
     out = {

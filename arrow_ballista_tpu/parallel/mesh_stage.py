@@ -19,12 +19,16 @@ retries and stats see an ordinary one-task stage.  The reduced
 
 from __future__ import annotations
 
+import ctypes
+import functools
+import time
 from typing import Iterator, Optional
 
 import numpy as np
 import pyarrow as pa
 
 from ..exec.operators import ExecutionPlan, Partitioning, TaskContext
+from ..obs import trace
 
 # jitted shard_map step per (kernel signature, n_devices): reused across
 # plan instances exactly like stage_compiler._KERNEL_CACHE
@@ -40,6 +44,22 @@ class _MeshKeyedRoute(Exception):
     def __init__(self, n_dev: int):
         super().__init__("mesh keyed high-cardinality")
         self.n_dev = n_dev
+
+
+@functools.lru_cache(maxsize=1)
+def _libc_sched_getcpu():
+    try:
+        return ctypes.CDLL(None).sched_getcpu
+    except (OSError, AttributeError):
+        return None
+
+
+def _sched_cpu() -> int:
+    """The core this thread runs on right now, from libc's
+    ``sched_getcpu`` (Python's ``os`` has no such call); -1 where the
+    platform cannot say.  Only traced gang stages ask."""
+    fn = _libc_sched_getcpu()
+    return int(fn()) if fn is not None else -1
 
 
 def gang_eligible(plan: ExecutionPlan) -> bool:
@@ -150,21 +170,47 @@ class MeshGangExec(ExecutionPlan):
         for p in range(self.input.output_partitioning().n):
             yield from inner.execute(p, ctx)
 
-    def _execute_mesh(self, tpu, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
-        """All input partitions → one sharded fused kernel + ICI reduce."""
+    def _execute_mesh(self, tpu, ctx: TaskContext) -> list[pa.RecordBatch]:
+        """All input partitions → one sharded fused kernel + ICI reduce.
+
+        A plain method (the caller materializes the result anyway), so the
+        ``gang.*`` spans nest on the thread's span stack.  The stage's wall
+        is ``mesh_stage_time_ns``; each part of it is counted to exactly
+        one phase (``gang_scan_ns``, ``key_encode_time_ns``,
+        ``gang_convert_ns``, ``gang_upload_ns``, ``gang_assemble_ns``,
+        ``gang_step_ns``, ``gang_materialize_ns``), and what is left is
+        loop overhead.  ``gang_cpu_ns`` is this thread's CPU time over the
+        same wall."""
+        import jax
+
+        clock = time.perf_counter_ns
+        wall0, cpu0 = clock(), time.thread_time_ns()
+        n_dev = self.n_devices or ctx.config.mesh_devices or len(jax.devices())
+        n_dev = max(1, min(n_dev, len(jax.devices())))
+        stage_span = trace.span("gang.stage", n_dev=n_dev)
+        # one check a stage: no per-partition clock, cpu or attr work and
+        # no span object when obs is off or the task is unsampled
+        traced = stage_span is not trace.NOOP
+        try:
+            with stage_span:
+                return self._mesh_phases(tpu, ctx, n_dev, stage_span, traced)
+        finally:
+            self.metrics.add("mesh_stage_time_ns", clock() - wall0)
+            self.metrics.add("gang_cpu_ns", time.thread_time_ns() - cpu0)
+
+    def _mesh_phases(
+        self, tpu, ctx: TaskContext, n_dev: int, stage_span, traced: bool
+    ) -> list[pa.RecordBatch]:
         import jax
 
         from ..ops import kernels as K
+        from ..ops.bridge import make_key_encoder
+        from ..ops.groups import GroupTable
         from . import mesh as M
 
+        clock = time.perf_counter_ns
+        add = self.metrics.add
         fused = tpu.fused
-        n_dev = self.n_devices or ctx.config.mesh_devices or len(jax.devices())
-        n_dev = max(1, min(n_dev, len(jax.devices())))
-
-        from ..ops.groups import GroupTable
-
-        from ..ops.bridge import make_key_encoder
-
         key_encoders = [
             make_key_encoder(tpu._schema.field(i).type)
             for i in range(len(fused.group_exprs))
@@ -179,76 +225,106 @@ class MeshGangExec(ExecutionPlan):
         # whole stage input on host first).  Column order per device chunk:
         # [seg, valid, *flat_names].
         names = ["__seg", "__valid"] + list(tpu._flat_names)
-        n_dev_chunks: list[list[list]] = []  # [device][chunk][column]
-        with self.metrics.timer("mesh_stage_time_ns"):
-            import jax as _jax
-
-            mesh = M.make_mesh(n_dev)
-            devices = list(mesh.devices.flatten())
-            n_dev_chunks = [[] for _ in devices]
-            for p in range(n_parts):
-                for batch in fused.source.execute(p, ctx):
-                    ctx.check_cancelled()
-                    if batch.num_rows == 0:
-                        continue
-                    n = batch.num_rows
-                    if fused.group_exprs:
-                        with self.metrics.timer("key_encode_time_ns"):
+        mesh = M.make_mesh(n_dev)
+        devices = list(mesh.devices.flatten())
+        n_dev_chunks: list[list[list]] = [[] for _ in devices]  # [device][chunk][column]
+        for p in range(n_parts):
+            dev = devices[p % n_dev]
+            part_span = trace.NOOP
+            if traced:
+                part_span = trace.span(
+                    "gang.partition", partition=p, device=p % n_dev,
+                    cpu_start=_sched_cpu(),
+                )
+                part_cpu0 = time.thread_time_ns()
+            # phase times of this partition: local integers, one
+            # metrics.add each at its end (no lock, no timer object a batch)
+            scan_ns = encode_ns = convert_ns = upload_ns = 0
+            batches = uploads = rows = 0
+            with part_span:
+                try:
+                    it = iter(fused.source.execute(p, ctx))
+                    while True:
+                        t0 = clock()
+                        batch = next(it, None)
+                        t1 = clock()
+                        scan_ns += t1 - t0
+                        if batch is None:
+                            break
+                        ctx.check_cancelled()
+                        if batch.num_rows == 0:
+                            continue
+                        n = batch.num_rows
+                        if fused.group_exprs:
                             seg = tpu._encode_groups(
                                 batch, key_encoders, group_table
                             )
-                        if n_rows == 0:
-                            from ..ops.stage_compiler import (
-                                _highcard_detect,
-                                keyed_route_wanted,
-                            )
-
-                            if _highcard_detect(group_table.n_groups, n):
-                                if keyed_route_wanted(tpu.config):
-                                    # groups ~ rows: per-shard KEYED
-                                    # reduction keeps the whole mesh busy
-                                    raise _MeshKeyedRoute(n_dev)
-                                if tpu.config.tpu_highcard_mode != "gid":
-                                    # cpu platform / highcard_mode=cpu:
-                                    # the sequential fallback routes each
-                                    # partition to the C++ hash aggregate
-                                    # (the measured winner off-
-                                    # accelerator); 'gid' pins the gid-
-                                    # table gang path (capacity must fit)
-                                    from ..errors import ExecutionError
-
-                                    raise ExecutionError(
-                                        "high-cardinality gang stage"
-                                    )
-                    else:
-                        seg = np.zeros(n, dtype=np.int32)
-                    with self.metrics.timer("bridge_time_ns"):
+                            if n_rows == 0:
+                                self._check_highcard(
+                                    tpu, group_table.n_groups, n, n_dev
+                                )
+                            t2 = clock()
+                            encode_ns += t2 - t1
+                        else:
+                            seg, t2 = np.zeros(n, dtype=np.int32), t1
                         env = K.build_env(batch, tpu.leaves, n)
                         cols = [seg, np.ones(n, dtype=bool)] + [
                             env[nm] for nm in tpu._flat_names
                         ]
-                        dev = devices[p % n_dev]
+                        t3 = clock()
+                        convert_ns += t3 - t2
                         n_dev_chunks[p % n_dev].append(
-                            [_jax.device_put(c, dev) for c in cols]
+                            [jax.device_put(c, dev) for c in cols]
                         )
-                    n_rows += n
-                    # host copies die with `env`/`cols` at next iteration
+                        upload_ns += clock() - t3
+                        batches += 1
+                        uploads += len(cols)
+                        rows += n
+                        n_rows += n
+                        # host copies die with `env`/`cols` at next iteration
+                finally:
+                    add("gang_scan_ns", scan_ns)
+                    add("key_encode_time_ns", encode_ns)
+                    add("gang_convert_ns", convert_ns)
+                    add("gang_upload_ns", upload_ns)
+                    add("bridge_time_ns", convert_ns + upload_ns)
+                    add("gang_uploads", uploads)
+                    add("gang_batches", batches)
+                    add("gang_partitions", 1)
+                    if traced:
+                        for k, v in (
+                            ("rows", rows), ("batches", batches),
+                            ("scan_ns", scan_ns), ("encode_ns", encode_ns),
+                            ("convert_ns", convert_ns),
+                            ("upload_ns", upload_ns),
+                            ("cpu_ns", time.thread_time_ns() - part_cpu0),
+                            ("cpu_end", _sched_cpu()),
+                        ):
+                            part_span.set_attr(k, v)
 
-            if n_rows == 0:
-                yield from tpu._materialize(
-                    None, key_encoders, group_table, 0, ctx, 0
-                )
-                return
+        if n_rows == 0:
+            return self._timed_materialize(
+                tpu, None, key_encoders, group_table, 0, ctx
+            )
 
-            # same 4x capacity bucketing as the sequential device path —
-            # segment ids beyond the table would be dropped silently
-            cap = tpu.capacity
-            while cap < group_table.n_groups:
-                cap *= 4
-            cap = min(cap, tpu.max_capacity)
-            if cap > tpu.capacity:
-                self.metrics.add("capacity_growths", 1)
+        # same 4x capacity bucketing as the sequential device path —
+        # segment ids beyond the table would be dropped silently
+        cap = tpu.capacity
+        while cap < group_table.n_groups:
+            cap *= 4
+        cap = min(cap, tpu.max_capacity)
+        if cap > tpu.capacity:
+            add("capacity_growths", 1)
+        stage_span.set_attr("rows", n_rows)
+        stage_span.set_attr("groups", group_table.n_groups)
+        stage_span.set_attr("capacity", cap)
 
+        t0 = clock()
+        with trace.span("gang.assemble"):
+            sharded = M.assemble_shards(mesh, n_dev_chunks, len(names))
+        t1 = clock()
+        with trace.span("gang.step") as step_span:
+            compiles = tpu.metrics.values.get("kernel_compiles", 0)
             step_key = (tpu._sig, n_dev, cap) + K.algo_cache_token()
             step = _MESH_STEP_CACHE.get(step_key)
             if step is None:
@@ -257,23 +333,61 @@ class MeshGangExec(ExecutionPlan):
                     raw_kernel, tpu.specs, mesh, cap, tpu._mode
                 )
                 _MESH_STEP_CACHE[step_key] = step
-            with self.metrics.timer("device_time_ns"):
-                sharded = M.assemble_shards(mesh, n_dev_chunks, len(names))
-                # compile/execute attribution lands on the inner stage's
-                # metrics, next to the sequential path's
-                out = tpu._timed_jit(step)(*sharded)
-                # the packed fetch is the sync: one transfer, sliced to
-                # the assigned groups (pow2 bucket)
-                host_states = tpu._fetch_states(
-                    tuple(out),
-                    group_table.n_groups if tpu.fused.group_exprs else None,
-                )
-        self.metrics.add("mesh_rows_in", n_rows)
-        self.metrics.add("mesh_devices", n_dev)
-        yield from tpu._materialize(
-            host_states, key_encoders, group_table, n_rows, ctx, 0
+            # compile/execute attribution lands on the inner stage's
+            # metrics, next to the sequential path's
+            out = tpu._timed_jit(step)(*sharded)
+            step_span.set_attr(
+                "compiled",
+                tpu.metrics.values.get("kernel_compiles", 0) > compiles,
+            )
+        with trace.span("gang.fetch"):
+            # the packed fetch is the sync: one transfer, sliced to
+            # the assigned groups (pow2 bucket)
+            host_states = tpu._fetch_states(
+                tuple(out),
+                group_table.n_groups if fused.group_exprs else None,
+            )
+        t2 = clock()
+        add("gang_assemble_ns", t1 - t0)
+        add("gang_step_ns", t2 - t1)
+        add("device_time_ns", t2 - t0)
+        add("mesh_rows_in", n_rows)
+        add("mesh_devices", n_dev)
+        return self._timed_materialize(
+            tpu, host_states, key_encoders, group_table, n_rows, ctx
         )
 
+    def _timed_materialize(
+        self, tpu, host_states, key_encoders, group_table, n_rows, ctx
+    ) -> list[pa.RecordBatch]:
+        t0 = time.perf_counter_ns()
+        with trace.span("gang.materialize"):
+            out = list(
+                tpu._materialize(
+                    host_states, key_encoders, group_table, n_rows, ctx, 0
+                )
+            )
+        self.metrics.add("gang_materialize_ns", time.perf_counter_ns() - t0)
+        return out
+
+    @staticmethod
+    def _check_highcard(tpu, n_groups: int, n: int, n_dev: int) -> None:
+        """The gang's first batch showed groups ~ rows: leave this path."""
+        from ..ops.stage_compiler import _highcard_detect, keyed_route_wanted
+
+        if not _highcard_detect(n_groups, n):
+            return
+        if keyed_route_wanted(tpu.config):
+            # per-shard KEYED reduction keeps the whole mesh busy
+            raise _MeshKeyedRoute(n_dev)
+        if tpu.config.tpu_highcard_mode != "gid":
+            # cpu platform / highcard_mode=cpu: the sequential fallback
+            # routes each partition to the C++ hash aggregate (the measured
+            # winner off-accelerator); 'gid' pins the gid-table gang path
+            # (capacity must fit)
+            from ..errors import ExecutionError
+
+            raise ExecutionError("high-cardinality gang stage")
 
     def _execute_mesh_keyed(
         self, tpu, ctx: TaskContext, n_dev: int
@@ -615,8 +729,15 @@ class MeshRepartitionExec(ExecutionPlan):
             self.metrics.add("mesh_devices", n_dev)
             MeshRepartitionExec.exchanges_completed += 1
 
-            part_col = len(ext_schema) - 1
-            for recv in ex.to_batches(recv_cols, recv_valid):
+        part_col = len(ext_schema) - 1
+        received = iter(ex.to_batches(recv_cols, recv_valid))
+        while True:
+            # the stage's wall stops at each yield: the writer that
+            # consumes these batches has its own timers
+            with self.metrics.timer("mesh_stage_time_ns"):
+                recv = next(received, None)
+                if recv is None:
+                    return
                 if recv.num_rows == 0:
                     continue
                 parts = np.asarray(recv.column(part_col))
@@ -626,11 +747,11 @@ class MeshRepartitionExec(ExecutionPlan):
                 shuffled = core.take(pa.array(order))
                 bounds = np.searchsorted(
                     sorted_parts, np.arange(n_out + 1)
-                )
-                for out_p in range(n_out):
-                    lo, hi = int(bounds[out_p]), int(bounds[out_p + 1])
-                    if hi > lo:
-                        yield out_p, shuffled.slice(lo, hi - lo)
+                ).tolist()
+            for out_p in range(n_out):
+                lo, hi = bounds[out_p], bounds[out_p + 1]
+                if hi > lo:
+                    yield out_p, shuffled.slice(lo, hi - lo)
 
 
 def maybe_mesh(plan: ExecutionPlan, config) -> ExecutionPlan:

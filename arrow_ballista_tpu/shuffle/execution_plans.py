@@ -466,15 +466,18 @@ class ShuffleWriterExec(ExecutionPlan):
             ]
         sink = None
         replicate = self._replicate_hook()
-        with self.metrics.timer("write_time_ns"):
-            for batch in self.input.execute(input_partition, ctx):
-                ctx.check_cancelled()
+        # write_time_ns is the sink's time only: the input's execute that
+        # feeds the loop is the stage's compute, counted by its operators
+        for batch in self.input.execute(input_partition, ctx):
+            ctx.check_cancelled()
+            with self.metrics.timer("write_time_ns"):
                 if sink is None:
                     sink = self._sink(
                         to_mem, stage_dir, input_partition,
                         input_partition, batch.schema, True,
                     )
                 sink.write(batch)
+        with self.metrics.timer("write_time_ns"):
             if sink is None:
                 sink = self._sink(
                     to_mem, stage_dir, input_partition, input_partition,

@@ -1,0 +1,11 @@
+"""``gang_materialize_ns``: fetched states -> Arrow batches in the gang stage,
+per query."""
+
+from benchmark.metrics import _gang
+
+UNIT, BETTER, SOURCE = "ms", "lower", "program_counter"
+LAYER, MOVES = "gang stage", "query_geomean_s"
+
+
+def read(run):
+    return _gang.per_query(run, "gang_materialize_ns", 1e6)

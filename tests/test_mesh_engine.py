@@ -417,3 +417,80 @@ def test_mesh_gang_highcard_auto_cpu_sequential_fallback(monkeypatch):
     assert m.get("mesh_fallback", 0) >= 1, m
     assert "mesh_keyed" not in m, m
     _assert_tables_close(got.sort_by([("g", "ascending")]), want, rel=1e-6)
+
+
+# ------------------------------------------------- phase self-times (PR 26)
+GANG_PHASES = (
+    "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
+    "gang_assemble_ns", "gang_step_ns", "gang_materialize_ns",
+)
+
+
+def test_local_gang_q1_counts_every_phase_once():
+    """A local gang q1: MeshGangExec carries every phase counter, the counts
+    follow from the input (batches x columns) and the seven self times sum
+    to at most the one wall."""
+    from benchmarks.tpch.queries import QUERIES
+    from arrow_ballista_tpu.exec.operators import TaskContext
+    from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
+
+    from arrow_ballista_tpu.catalog import MemoryTable
+    from benchmarks.tpch.datagen import gen_table
+
+    cfg = _cfg()
+    ctx = SessionContext(cfg)
+    lineitem = gen_table("lineitem", 0.01)
+    per = -(-lineitem.num_rows // 4)
+    ctx.register_table("lineitem", MemoryTable([
+        lineitem.slice(i * per, per).combine_chunks().to_batches(max_chunksize=4096)
+        for i in range(4)
+    ]))
+    plan = ctx.sql(QUERIES[1]).physical_plan()
+    ctx.execute(plan)
+
+    (gang,) = _find(plan, MeshGangExec)
+    m = gang.metrics.to_dict()
+    (tpu,) = _find(gang, TpuStageExec)
+    source = tpu.fused.source
+    n_parts = source.output_partitioning().n
+    batches = [
+        b for p in range(n_parts)
+        for b in source.execute(p, TaskContext(config=cfg)) if b.num_rows
+    ]
+    assert n_parts == 4 and len(batches) > n_parts
+    columns = 2 + len(tpu._flat_names)  # [seg, valid, *flat_names]
+    assert m["gang_partitions"] == n_parts
+    assert m["gang_batches"] == len(batches)
+    assert m["gang_uploads"] == len(batches) * columns
+    assert m["mesh_rows_in"] == sum(b.num_rows for b in batches)
+    for k in GANG_PHASES + ("mesh_stage_time_ns", "gang_cpu_ns"):
+        assert m[k] >= 0, k
+    assert 0 < sum(m[k] for k in GANG_PHASES) <= m["mesh_stage_time_ns"]
+    # the two lumps the older readers know are now sums of phases
+    assert m["bridge_time_ns"] == m["gang_convert_ns"] + m["gang_upload_ns"]
+    assert m["device_time_ns"] == m["gang_assemble_ns"] + m["gang_step_ns"]
+
+
+def test_scan_timer_does_not_count_its_consumer():
+    """scan_time_ns stops at each yield: a consumer that sleeps 20 ms a
+    batch is not counted as scan."""
+    import time
+
+    import numpy as np
+
+    from arrow_ballista_tpu.catalog import MemoryTable
+    from arrow_ballista_tpu.exec.operators import ScanExec, TaskContext
+
+    t = pa.table({"v": pa.array(np.arange(8 * 1024, dtype=np.int64))})
+    scan = ScanExec("t", MemoryTable([t.to_batches(max_chunksize=1024)]))
+    cfg = _cfg()
+    slept = rows = 0
+    for batch in scan.execute(0, TaskContext(config=cfg)):
+        t0 = time.perf_counter_ns()
+        time.sleep(0.02)
+        slept += time.perf_counter_ns() - t0
+        rows += batch.num_rows
+    m = scan.metrics.to_dict()
+    assert rows == m["output_rows"] == t.num_rows
+    assert slept >= 4 * 20_000_000  # several batches were consumed
+    assert 0 <= m["scan_time_ns"] < slept / 2

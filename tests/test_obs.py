@@ -677,3 +677,179 @@ def test_process_registry_tees_fetch_counters():
         == m.values["bytes_fetched"]
     )
     assert m.values["locations_fetched"] == 2
+
+
+# =====================================================================
+# inside the gang task (PR 26): phase counters always, spans only when on
+# =====================================================================
+GANG_SQL = "select g, sum(x) as s, count(x) as n from t group by g"
+GANG_COUNTERS = (
+    "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
+    "gang_uploads", "gang_assemble_ns", "gang_step_ns", "gang_materialize_ns",
+    "mesh_stage_time_ns", "gang_cpu_ns", "gang_batches", "gang_partitions",
+)
+
+
+def _gang_table(partitions: int):
+    from arrow_ballista_tpu.catalog import MemoryTable
+
+    t = pa.table({"g": ["a", "b", "c", "d"] * 3000, "x": [1.0, 2.0, 3.0, 4.0] * 3000})
+    per = t.num_rows // partitions
+    return MemoryTable([
+        t.slice(i * per, per).to_batches(max_chunksize=1000) for i in range(partitions)
+    ])
+
+
+def _run_local_gang(partitions: int = 3) -> dict:
+    """One local gang aggregate; returns MeshGangExec's counters."""
+    from arrow_ballista_tpu import SessionContext
+    from arrow_ballista_tpu.parallel.mesh_stage import MeshGangExec
+
+    ctx = SessionContext(BallistaConfig({
+        "ballista.tpu.min_rows": "0", "ballista.shuffle.partitions": "2",
+    }))
+    ctx.register_table("t", _gang_table(partitions))
+    plan = ctx.sql(GANG_SQL).physical_plan()
+    out = ctx.execute(plan)
+    assert sorted(out.column("s").to_pylist()) == [3000.0, 6000.0, 9000.0, 12000.0]
+    stack, gangs = [plan], []
+    while stack:
+        node = stack.pop()
+        if isinstance(node, MeshGangExec):
+            gangs.append(node)
+        stack.extend(node.children())
+    (gang,) = gangs
+    return gang.metrics.to_dict()
+
+
+def test_gang_spans_nest_inside_the_trace_when_obs_is_on():
+    trace.configure(enabled=True, process="local")
+    trace_id = trace.new_id()
+    with trace.root_span("job", trace_id):
+        counters = _run_local_gang(partitions=3)
+    spans = get_recorder().drain()
+    assert {s["trace"] for s in spans} == {trace_id}
+    by_id = {s["span"]: s for s in spans}
+    by_name: dict = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    for name in ("gang.stage", "gang.assemble", "gang.step", "gang.fetch", "gang.materialize"):
+        assert len(by_name[name]) == 1, name
+    parts = by_name["gang.partition"]
+    assert len(parts) == 3 == counters["gang_partitions"]
+    (stage,) = by_name["gang.stage"]
+    assert stage["parent"] == trace_id and stage["attrs"]["rows"] == 12000
+    assert stage["attrs"]["groups"] == 4 and stage["attrs"]["capacity"] >= 4
+    assert "compiled" in by_name["gang.step"][0]["attrs"]
+    for s in spans:
+        if not s["name"].startswith("gang.") or s is stage:
+            continue
+        parent = by_id[s["parent"]]
+        assert parent is stage, s["name"]
+        # wall-clock anchors: a child starts inside its parent's interval
+        assert parent["ts"] <= s["ts"] <= parent["ts"] + parent["dur"], s["name"]
+    # a partition's span carries the same numbers the counters sum
+    for key, counter in (("scan_ns", "gang_scan_ns"), ("encode_ns", "key_encode_time_ns"),
+                         ("convert_ns", "gang_convert_ns"), ("upload_ns", "gang_upload_ns"),
+                         ("batches", "gang_batches")):
+        assert sum(p["attrs"][key] for p in parts) == counters[counter], key
+    for p in parts:
+        assert {"rows", "device", "cpu_ns", "cpu_start", "cpu_end"} <= set(p["attrs"])
+
+
+def test_gang_stage_makes_no_span_object_when_obs_is_off(monkeypatch):
+    made = []
+
+    class CountingSpan(trace.Span):
+        def __init__(self, *a, **kw):
+            made.append(a[0])
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(trace, "Span", CountingSpan)
+    assert not trace.is_enabled()
+    off = _run_local_gang(partitions=3)
+    assert made == [] and get_recorder().drain() == []
+    # the counters do not depend on the switch
+    trace.configure(enabled=True, process="local")
+    with trace.root_span("job", trace.new_id()):
+        on = _run_local_gang(partitions=3)
+    assert "gang.stage" in made
+    for counters in (off, on):
+        assert set(GANG_COUNTERS) <= set(counters)
+    for k in ("gang_uploads", "gang_batches", "gang_partitions", "mesh_rows_in"):
+        assert off[k] == on[k], k
+
+
+def test_standalone_gang_job_reports_task_run_time_phase_split_and_spans():
+    """Through a real scheduler and executor: every stage's root operator
+    carries task_run_ns within the scheduler's finish - dispatch, the
+    profile's gang stage shows the phase split (from counters), and the
+    exported trace holds the gang.* spans under task.execute."""
+    from arrow_ballista_tpu.client.context import BallistaContext
+    from arrow_ballista_tpu.scheduler.api import ApiServerHandle
+
+    config = dict(OBS_CONFIG)
+    config["ballista.mesh.enable"] = "true"
+    ctx = BallistaContext.standalone(
+        config=BallistaConfig(config), num_executors=1, concurrent_tasks=2,
+    )
+    try:
+        ctx.register_table("t", _gang_table(3))
+        out = ctx.sql(GANG_SQL).collect()
+        assert sorted(out.column("s").to_pylist()) == [3000.0, 6000.0, 9000.0, 12000.0]
+        (job_id,) = ctx._job_ids
+        scheduler, _executors = ctx._standalone_handles
+        scheduler.server.drain()
+        _wait_for_job_span(job_id)
+        api = ApiServerHandle(scheduler.server, "127.0.0.1", 0).start()
+        try:
+            base = f"http://127.0.0.1:{api.port}"
+            detail = json.load(urllib.request.urlopen(f"{base}/api/job/{job_id}"))
+            prof = json.load(urllib.request.urlopen(f"{base}/api/jobs/{job_id}/profile"))
+            tr = json.load(urllib.request.urlopen(f"{base}/api/jobs/{job_id}/trace"))
+        finally:
+            api.stop()
+    finally:
+        ctx.close()
+
+    assert len(detail["stages"]) >= 2
+    gang_stage_ids = []
+    for st in detail["stages"]:
+        ops = {k: v for k, v in st["metrics"].items() if not k.startswith("__")}
+        root = ops["ShuffleWriterExec"]
+        timing = st["timing"]
+        scheduler_ns = sum(
+            (timing["finish_us"][p] - timing["dispatch_us"][p]) * 1000
+            for p in timing["finish_us"]
+        )
+        # task_run_ns sums over the stage's tasks, like every counter; the
+        # scheduler's stamps are whole microseconds
+        assert 0 < root["task_run_ns"] <= scheduler_ns + 1000 * len(timing["finish_us"]), st["stage_id"]
+        assert [op for op, v in ops.items() if "task_run_ns" in v] == ["ShuffleWriterExec"]
+        if "MeshGangExec" in ops:
+            gang_stage_ids.append(st["stage_id"])
+            assert set(GANG_COUNTERS) <= set(ops["MeshGangExec"])
+    (gang_id,) = gang_stage_ids
+
+    (row,) = [s for s in prof["stages"] if s["stage_id"] == gang_id]
+    tpu = row["tpu"]
+    phases = ("gang_scan_ms", "gang_encode_ms", "gang_convert_ms", "gang_upload_ms",
+              "gang_assemble_ms", "gang_step_ms", "gang_materialize_ms")
+    assert all(tpu[k] >= 0 for k in phases) and "compile_ms" in tpu
+    assert sum(tpu[k] for k in phases) <= tpu["gang_stage_ms"] + 0.01
+    assert tpu["gang_partitions"] == 3 and tpu["gang_uploads"] > 0
+    for other in prof["stages"]:
+        if other["stage_id"] != gang_id:
+            assert "gang_stage_ms" not in (other.get("tpu") or {})
+
+    slices = {e["args"]["span_id"]: e for e in tr["traceEvents"] if e["ph"] == "X"}
+    gang = [e for e in slices.values() if e["name"].startswith("gang.")]
+    assert sum(e["name"] == "gang.partition" for e in gang) == 3
+    assert {"gang.stage", "gang.assemble", "gang.step", "gang.fetch",
+            "gang.materialize"} <= {e["name"] for e in gang}
+    for e in gang:
+        cur, names = e, []
+        while cur["args"].get("parent_span_id") in slices:
+            cur = slices[cur["args"]["parent_span_id"]]
+            names.append(cur["name"])
+        assert "task.execute" in names, (e["name"], names)
