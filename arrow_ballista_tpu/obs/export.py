@@ -317,6 +317,7 @@ _GANG_PHASES = (
     ("gang_materialize_ns", "gang_materialize_ms"),
     ("gang_cpu_ns", "gang_cpu_ms"),
     ("gang_uploads", "gang_uploads"),
+    ("gang_upload_bytes", "gang_upload_bytes"),
     ("gang_batches", "gang_batches"),
     ("gang_partitions", "gang_partitions"),
 )

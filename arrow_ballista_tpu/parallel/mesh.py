@@ -428,16 +428,18 @@ def ici_all_to_all_repartition(mesh: Mesh, capacity: int):
 def assemble_shards(
     mesh: Mesh, per_dev_chunks: list, n_cols: int
 ) -> list[jax.Array]:
-    """Device-resident chunks → global row-sharded arrays, no host concat.
+    """Device-resident chunks → global row-sharded arrays.
 
     ``per_dev_chunks[d]`` is a list of chunks already placed on device d,
-    each chunk a list of ``n_cols`` equal-length 1-D arrays (the streaming
-    upload path: partitions transfer as they are scanned).  Shards must
-    share one length, so each device concatenates ITS chunks and pads to
-    the longest device — on device, in shard-size pieces — then the padded
-    per-device arrays stitch into one sharded array per column via
-    ``make_array_from_single_device_arrays``.  Pad rows are zeros, which
-    the kernels' validity column (False-padded) masks out.
+    each chunk a list of ``n_cols`` equal-length 1-D arrays: the gang stage
+    uploads ONE chunk per input partition (its batches concatenated on
+    host), so a device holds as many chunks as it was dealt partitions.
+    Shards must share one length, so each device concatenates ITS chunks
+    (one program a column, in partition order) and pads to the longest
+    device, on device; the padded per-device arrays then stitch into one
+    sharded array per column via ``make_array_from_single_device_arrays``.
+    A device dealt no rows holds zeros.  Pad rows are zeros, which the
+    kernels' validity column (False-padded) masks out.
     """
     devices = list(mesh.devices.flatten())
     assert len(per_dev_chunks) == len(devices)

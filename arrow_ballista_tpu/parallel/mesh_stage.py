@@ -218,16 +218,21 @@ class MeshGangExec(ExecutionPlan):
         group_table = GroupTable(len(fused.group_exprs))
         n_rows = 0
         n_parts = fused.source.output_partitioning().n
-        # Partitions ARE the shards: each partition's arrays transfer to
-        # its device (round-robin) as soon as the partition is scanned, so
-        # peak host memory is ONE partition and source I/O overlaps device
-        # transfer (round-2 weakness #6: the old path np.concatenate'd the
-        # whole stage input on host first).  Column order per device chunk:
-        # [seg, valid, *flat_names].
+        # Partitions ARE the shards, and the partition is the unit of the
+        # host->device bridge: its batches are scanned, encoded and
+        # converted one by one, then each column is concatenated on host
+        # and the partition's columns go to its device (round-robin) in
+        # ONE device_put.  A device_put costs ~250 us whatever it carries,
+        # so a call per batch and column was half a query.  Peak host
+        # memory stays one partition (twice during the concatenate) and
+        # the asynchronous transfer overlaps the next partition's scan.
+        # The arrays handed over are never written again and no buffer is
+        # reused: the CPU backend may alias them and the TPU copies late.
+        # Column order per device chunk: [seg, valid, *flat_names].
         names = ["__seg", "__valid"] + list(tpu._flat_names)
         mesh = M.make_mesh(n_dev)
         devices = list(mesh.devices.flatten())
-        n_dev_chunks: list[list[list]] = [[] for _ in devices]  # [device][chunk][column]
+        n_dev_chunks: list[list[list]] = [[] for _ in devices]  # [device][partition][column]
         for p in range(n_parts):
             dev = devices[p % n_dev]
             part_span = trace.NOOP
@@ -240,7 +245,10 @@ class MeshGangExec(ExecutionPlan):
             # phase times of this partition: local integers, one
             # metrics.add each at its end (no lock, no timer object a batch)
             scan_ns = encode_ns = convert_ns = upload_ns = 0
-            batches = uploads = rows = 0
+            batches = uploads = upload_bytes = rows = 0
+            # the partition's host arrays until its one upload, per batch
+            part_segs: list[np.ndarray] = []
+            part_cols: list[list[np.ndarray]] = []  # [batch][*flat_names]
             with part_span:
                 try:
                     it = iter(fused.source.execute(p, ctx))
@@ -256,9 +264,9 @@ class MeshGangExec(ExecutionPlan):
                             continue
                         n = batch.num_rows
                         if fused.group_exprs:
-                            seg = tpu._encode_groups(
+                            part_segs.append(tpu._encode_groups(
                                 batch, key_encoders, group_table
-                            )
+                            ))
                             if n_rows == 0:
                                 self._check_highcard(
                                     tpu, group_table.n_groups, n, n_dev
@@ -266,22 +274,30 @@ class MeshGangExec(ExecutionPlan):
                             t2 = clock()
                             encode_ns += t2 - t1
                         else:
-                            seg, t2 = np.zeros(n, dtype=np.int32), t1
+                            t2 = t1
                         env = K.build_env(batch, tpu.leaves, n)
-                        cols = [seg, np.ones(n, dtype=bool)] + [
-                            env[nm] for nm in tpu._flat_names
-                        ]
-                        t3 = clock()
-                        convert_ns += t3 - t2
-                        n_dev_chunks[p % n_dev].append(
-                            [jax.device_put(c, dev) for c in cols]
-                        )
-                        upload_ns += clock() - t3
+                        part_cols.append([env[nm] for nm in tpu._flat_names])
+                        convert_ns += clock() - t2
                         batches += 1
-                        uploads += len(cols)
                         rows += n
                         n_rows += n
-                        # host copies die with `env`/`cols` at next iteration
+                    if rows:
+                        t0 = clock()
+                        seg = (
+                            np.concatenate(part_segs) if fused.group_exprs
+                            else np.zeros(rows, dtype=np.int32)
+                        )
+                        host = [seg, np.ones(rows, dtype=bool)] + [
+                            np.concatenate(c) for c in zip(*part_cols)
+                        ]
+                        t1 = clock()
+                        convert_ns += t1 - t0
+                        n_dev_chunks[p % n_dev].append(
+                            jax.device_put(host, dev)
+                        )
+                        upload_ns += clock() - t1
+                        uploads = len(host)
+                        upload_bytes = sum(a.nbytes for a in host)
                 finally:
                     add("gang_scan_ns", scan_ns)
                     add("key_encode_time_ns", encode_ns)
@@ -289,6 +305,7 @@ class MeshGangExec(ExecutionPlan):
                     add("gang_upload_ns", upload_ns)
                     add("bridge_time_ns", convert_ns + upload_ns)
                     add("gang_uploads", uploads)
+                    add("gang_upload_bytes", upload_bytes)
                     add("gang_batches", batches)
                     add("gang_partitions", 1)
                     if traced:
@@ -297,6 +314,7 @@ class MeshGangExec(ExecutionPlan):
                             ("scan_ns", scan_ns), ("encode_ns", encode_ns),
                             ("convert_ns", convert_ns),
                             ("upload_ns", upload_ns),
+                            ("upload_bytes", upload_bytes),
                             ("cpu_ns", time.thread_time_ns() - part_cpu0),
                             ("cpu_end", _sched_cpu()),
                         ):

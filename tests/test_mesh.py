@@ -109,3 +109,38 @@ def test_repartition_with_invalid_rows(mesh8):
         want = np.sort(values[valid & (dest == d)])
         assert len(got) == len(want)
         assert got == pytest.approx(want, rel=1e-12)
+
+
+def test_assemble_shards_one_chunk_per_partition_unequal_devices():
+    """The gang stage's hand-over: one chunk a partition, devices dealt
+    unequal rows (one of them none).  Every shard comes out at the longest
+    device's length, chunks in partition order, pad rows zero."""
+    mesh = M.make_mesh(4)
+    devices = list(mesh.devices.flatten())
+    rng = np.random.default_rng(5)
+    rows = [[5, 3], [4], [], [7, 1, 2]]  # [device][partition]
+    host = [
+        [
+            [rng.integers(1, 9, n).astype(np.int32), rng.uniform(1, 2, n).astype(np.float32)]
+            for n in parts
+        ]
+        for parts in rows
+    ]
+    chunks = [
+        [jax.device_put(cols, dev) for cols in parts]
+        for parts, dev in zip(host, devices)
+    ]
+    out = M.assemble_shards(mesh, chunks, 2)
+    L = 10
+    assert len(out) == 2
+    for c, arr in enumerate(out):
+        assert arr.shape == (4 * L,) and arr.dtype == host[0][0][c].dtype
+        shards = sorted(arr.addressable_shards, key=lambda s: s.index[0].start)
+        assert [s.device for s in shards] == devices
+        for d, shard in enumerate(shards):
+            want = np.zeros(L, dtype=arr.dtype)
+            n = sum(rows[d])
+            if n:
+                want[:n] = np.concatenate([cols[c] for cols in host[d]])
+            assert shard.data.shape == (L,)
+            np.testing.assert_array_equal(np.asarray(shard.data), want)

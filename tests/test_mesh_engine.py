@@ -377,6 +377,8 @@ def test_mesh_gang_highcard_keyed_across_shards(monkeypatch):
     assert m.get("mesh_keyed", 0) >= 1, m
     assert "mesh_fallback" not in m, m
     assert m.get("mesh_devices") == 8, m
+    # the first batch chose the route before its partition uploaded
+    assert m["gang_batches"] == 0 and m["gang_uploads"] == 0, m
     _assert_tables_close(got.sort_by([("g", "ascending")]), want, rel=1e-6)
 
 
@@ -416,6 +418,9 @@ def test_mesh_gang_highcard_auto_cpu_sequential_fallback(monkeypatch):
     m = gangs[0].metrics.to_dict()
     assert m.get("mesh_fallback", 0) >= 1, m
     assert "mesh_keyed" not in m, m
+    # the first batch left the gang path before its partition uploaded
+    assert m["gang_batches"] == 0 and m["gang_uploads"] == 0, m
+    assert m["gang_upload_bytes"] == 0, m
     _assert_tables_close(got.sort_by([("g", "ascending")]), want, rel=1e-6)
 
 
@@ -426,10 +431,29 @@ GANG_PHASES = (
 )
 
 
-def test_local_gang_q1_counts_every_phase_once():
+class _UploadSpy:
+    """Wraps ``jax.device_put`` and keeps the host arrays of every call that
+    hands over a list (the gang stage's one call a partition); calls with a
+    single array (``assemble_shards``' placements) pass through unseen."""
+
+    def __init__(self, monkeypatch):
+        import jax
+
+        self.calls: list[tuple[list, object]] = []
+        real = jax.device_put
+
+        def device_put(x, device=None, **kw):
+            if isinstance(x, list):
+                self.calls.append((list(x), device))
+            return real(x, device, **kw)
+
+        monkeypatch.setattr(jax, "device_put", device_put)
+
+
+def test_local_gang_q1_counts_every_phase_once(monkeypatch):
     """A local gang q1: MeshGangExec carries every phase counter, the counts
-    follow from the input (batches x columns) and the seven self times sum
-    to at most the one wall."""
+    follow from the input (one device_put a non-empty partition, carrying
+    every column) and the seven self times sum to at most the one wall."""
     from benchmarks.tpch.queries import QUERIES
     from arrow_ballista_tpu.exec.operators import TaskContext
     from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
@@ -441,11 +465,14 @@ def test_local_gang_q1_counts_every_phase_once():
     ctx = SessionContext(cfg)
     lineitem = gen_table("lineitem", 0.01)
     per = -(-lineitem.num_rows // 4)
-    ctx.register_table("lineitem", MemoryTable([
+    parts = [
         lineitem.slice(i * per, per).combine_chunks().to_batches(max_chunksize=4096)
         for i in range(4)
-    ]))
+    ]
+    parts.insert(2, [])  # an empty partition uploads nothing
+    ctx.register_table("lineitem", MemoryTable(parts))
     plan = ctx.sql(QUERIES[1]).physical_plan()
+    spy = _UploadSpy(monkeypatch)
     ctx.execute(plan)
 
     (gang,) = _find(plan, MeshGangExec)
@@ -457,11 +484,16 @@ def test_local_gang_q1_counts_every_phase_once():
         b for p in range(n_parts)
         for b in source.execute(p, TaskContext(config=cfg)) if b.num_rows
     ]
-    assert n_parts == 4 and len(batches) > n_parts
+    assert n_parts == 5 and len(batches) > n_parts
     columns = 2 + len(tpu._flat_names)  # [seg, valid, *flat_names]
     assert m["gang_partitions"] == n_parts
     assert m["gang_batches"] == len(batches)
-    assert m["gang_uploads"] == len(batches) * columns
+    assert len(spy.calls) == 4  # the non-empty partitions
+    assert all(len(cols) == columns for cols, _ in spy.calls)
+    assert m["gang_uploads"] == 4 * columns
+    assert m["gang_upload_bytes"] == sum(
+        a.nbytes for cols, _ in spy.calls for a in cols
+    )
     assert m["mesh_rows_in"] == sum(b.num_rows for b in batches)
     for k in GANG_PHASES + ("mesh_stage_time_ns", "gang_cpu_ns"):
         assert m[k] >= 0, k
@@ -469,6 +501,133 @@ def test_local_gang_q1_counts_every_phase_once():
     # the two lumps the older readers know are now sums of phases
     assert m["bridge_time_ns"] == m["gang_convert_ns"] + m["gang_upload_ns"]
     assert m["device_time_ns"] == m["gang_assemble_ns"] + m["gang_step_ns"]
+
+
+# ------------------------------------------ upload per partition (PR 27)
+RAGGED_SQL = (
+    "select g, sum(v) as s, count(*) as c, min(v) as mn, max(v) as mx "
+    "from t group by g order by g"
+)
+
+
+def _ragged_table():
+    """Five partitions: three unequal batches, none at all, only empty
+    batches, one batch, two batches.  Every v is a multiple of 1/4 and every
+    sum stays under 2**24 quarters, so float32 sums are exact in any order
+    and two device paths can be held to the same bits."""
+    import numpy as np
+
+    from arrow_ballista_tpu.catalog import MemoryTable
+
+    rng = np.random.default_rng(27)
+    schema = pa.schema([("g", pa.int64()), ("v", pa.float64())])
+
+    def batch(n):
+        return pa.RecordBatch.from_arrays(
+            [
+                pa.array(rng.integers(0, 7, n), pa.int64()),
+                pa.array(rng.integers(0, 400, n) / 4.0, pa.float64()),
+            ],
+            schema=schema,
+        )
+
+    return MemoryTable(
+        [
+            [batch(700), batch(1), batch(1300)],
+            [],
+            [batch(0), batch(0)],
+            [batch(513)],
+            [batch(0), batch(2048), batch(90)],
+        ],
+        schema,
+    )
+
+
+def _gang_devices(n_dev):
+    from arrow_ballista_tpu.parallel import mesh as M
+
+    return list(M.make_mesh(n_dev).devices.flatten())
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+def test_gang_upload_per_partition_equals_sequential_on_ragged_input(
+    n_dev, monkeypatch
+):
+    """The gang path (one upload a partition) against the sequential device
+    path (one kernel call a batch), bit for bit; and what a partition hands
+    to device_put is its batches in batch order, on device p % n_dev."""
+    import numpy as np
+
+    from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
+
+    table = _ragged_table()
+    seq = SessionContext(_cfg(**{"ballista.mesh.enable": "false"}))
+    seq.register_table("t", table)
+    seq_plan = seq.sql(RAGGED_SQL).physical_plan()
+    want = seq.execute(seq_plan)
+    assert _find(seq_plan, TpuStageExec) and not _find(seq_plan, MeshGangExec)
+
+    ctx = SessionContext(_cfg(**{"ballista.mesh.devices": n_dev}))
+    ctx.register_table("t", table)
+    plan = ctx.sql(RAGGED_SQL).physical_plan()
+    spy = _UploadSpy(monkeypatch)
+    got = ctx.execute(plan)
+
+    (gang,) = _find(plan, MeshGangExec)
+    m = gang.metrics.to_dict()
+    assert "mesh_fallback" not in m and m["mesh_devices"] == n_dev, m
+    assert got.schema == want.schema
+    assert got.to_pydict() == want.to_pydict()
+
+    (tpu,) = _find(gang, TpuStageExec)
+    columns = 2 + len(tpu._flat_names)
+    assert m["gang_partitions"] == 5 and m["gang_batches"] == 6
+    assert m["gang_uploads"] == 3 * columns and m["mesh_rows_in"] == 4652
+    (leaf,) = tpu.leaves  # v, as one column (x64) or a hi/lo pair (x32)
+    flat = list(tpu._flat_names)
+    vi = 2 + flat.index(leaf if leaf in flat else f"{leaf}__hi")
+    non_empty = [p for p, bs in enumerate(table.partitions) if sum(map(len, bs))]
+    assert non_empty == [0, 3, 4] and len(spy.calls) == 3
+    for p, (cols, device) in zip(non_empty, spy.calls):
+        v = np.concatenate([b.column(1).to_numpy() for b in table.partitions[p]])
+        assert device == _gang_devices(n_dev)[p % n_dev]
+        assert [len(c) for c in cols] == [len(v)] * columns
+        assert cols[1].all() and np.array_equal(cols[vi], v.astype(cols[vi].dtype))
+
+
+def test_gang_cancel_between_batches_raises_before_the_partition_uploads(
+    monkeypatch,
+):
+    """Cancellation is still checked at every batch: an event set while the
+    first batch converts stops the task at the second, before the partition
+    (whose upload waits for its last batch) has handed anything over."""
+    import threading
+
+    from arrow_ballista_tpu.errors import Cancelled
+    from arrow_ballista_tpu.exec.operators import TaskContext
+    from arrow_ballista_tpu.ops import kernels as K
+
+    cfg = _cfg()
+    ctx = SessionContext(cfg)
+    ctx.register_table("t", _ragged_table())
+    plan = ctx.sql(RAGGED_SQL).physical_plan()
+    (gang,) = _find(plan, MeshGangExec)
+
+    cancel = threading.Event()
+    real_build_env = K.build_env
+
+    def build_env(*a, **kw):
+        cancel.set()
+        return real_build_env(*a, **kw)
+
+    monkeypatch.setattr(K, "build_env", build_env)
+    spy = _UploadSpy(monkeypatch)
+    with pytest.raises(Cancelled):
+        list(gang.execute(0, TaskContext(config=cfg, cancel_event=cancel)))
+    m = gang.metrics.to_dict()
+    assert spy.calls == []
+    assert m["gang_batches"] == 1 and m["gang_partitions"] == 1
+    assert m["gang_uploads"] == 0 and m["gang_upload_bytes"] == 0
 
 
 def test_scan_timer_does_not_count_its_consumer():

@@ -685,8 +685,9 @@ def test_process_registry_tees_fetch_counters():
 GANG_SQL = "select g, sum(x) as s, count(x) as n from t group by g"
 GANG_COUNTERS = (
     "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
-    "gang_uploads", "gang_assemble_ns", "gang_step_ns", "gang_materialize_ns",
-    "mesh_stage_time_ns", "gang_cpu_ns", "gang_batches", "gang_partitions",
+    "gang_uploads", "gang_upload_bytes", "gang_assemble_ns", "gang_step_ns",
+    "gang_materialize_ns", "mesh_stage_time_ns", "gang_cpu_ns", "gang_batches",
+    "gang_partitions",
 )
 
 
@@ -751,7 +752,7 @@ def test_gang_spans_nest_inside_the_trace_when_obs_is_on():
     # a partition's span carries the same numbers the counters sum
     for key, counter in (("scan_ns", "gang_scan_ns"), ("encode_ns", "key_encode_time_ns"),
                          ("convert_ns", "gang_convert_ns"), ("upload_ns", "gang_upload_ns"),
-                         ("batches", "gang_batches")):
+                         ("upload_bytes", "gang_upload_bytes"), ("batches", "gang_batches")):
         assert sum(p["attrs"][key] for p in parts) == counters[counter], key
     for p in parts:
         assert {"rows", "device", "cpu_ns", "cpu_start", "cpu_end"} <= set(p["attrs"])
@@ -776,7 +777,8 @@ def test_gang_stage_makes_no_span_object_when_obs_is_off(monkeypatch):
     assert "gang.stage" in made
     for counters in (off, on):
         assert set(GANG_COUNTERS) <= set(counters)
-    for k in ("gang_uploads", "gang_batches", "gang_partitions", "mesh_rows_in"):
+    for k in ("gang_uploads", "gang_upload_bytes", "gang_batches", "gang_partitions",
+              "mesh_rows_in"):
         assert off[k] == on[k], k
 
 
@@ -838,6 +840,7 @@ def test_standalone_gang_job_reports_task_run_time_phase_split_and_spans():
     assert all(tpu[k] >= 0 for k in phases) and "compile_ms" in tpu
     assert sum(tpu[k] for k in phases) <= tpu["gang_stage_ms"] + 0.01
     assert tpu["gang_partitions"] == 3 and tpu["gang_uploads"] > 0
+    assert tpu["gang_upload_bytes"] > 0
     for other in prof["stages"]:
         if other["stage_id"] != gang_id:
             assert "gang_stage_ms" not in (other.get("tpu") or {})
