@@ -323,6 +323,40 @@ _GANG_PHASES = (
 )
 
 
+# the join / exchange / per-partition device path's counters -> their names
+# in a profile row's "tpu" block: MeshRepartitionExec's exchange (rows and
+# bytes handed to the exchange program, padding included; encode, device
+# and decode are host timers that sum to the exchange's part of the task),
+# then TpuStageExec's folded join and its padding.
+_EXCHANGE_COUNTERS = (
+    ("mesh_exchange_rows", "exchange_rows"),
+    ("mesh_exchange_padded_rows", "exchange_padded_rows"),
+    ("mesh_exchange_bytes", "exchange_bytes"),
+    ("mesh_exchange_recv_bytes", "exchange_recv_bytes"),
+    ("exchange_encode_ns", "exchange_encode_ms"),
+    ("device_time_ns", "exchange_device_ms"),
+    ("exchange_decode_ns", "exchange_decode_ms"),
+)
+_JOIN_COUNTERS = (
+    ("join_build_ns", "join_build_ms"),
+    ("join_build_rows", "join_build_rows"),
+    ("join_build_capacity", "join_build_capacity"),
+    ("join_probe_rows", "join_probe_rows"),
+    ("stage_pad_rows", "stage_pad_rows"),
+    ("stage_batches", "stage_batches"),
+    ("stage_uploads", "stage_uploads"),
+)
+
+
+def _renamed(counters: dict, names) -> dict:
+    return {
+        name: round(counters[k] / _NS_PER_MS, 3) if k.endswith("_ns")
+        else counters[k]
+        for k, name in names
+        if k in counters
+    }
+
+
 def _stage_of(span: dict) -> Optional[int]:
     st = (span.get("attrs") or {}).get("stage")
     try:
@@ -370,6 +404,7 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
         metrics = r.get("metrics") or {}
         tpu = {}
         gang = {}
+        exchange = {}
         shuffle_bytes = 0
         replica_fetches = 0
         write = {}
@@ -393,6 +428,11 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
                 for k, _ in _GANG_PHASES:
                     if k in vals:
                         gang[k] = gang.get(k, 0) + vals[k]
+            elif op.startswith("MeshRepartition"):
+                if vals.get("mesh_exchange_rows"):
+                    for k, _ in _EXCHANGE_COUNTERS:
+                        if k in vals:
+                            exchange[k] = exchange.get(k, 0) + vals[k]
             shuffle_bytes += vals.get("bytes_fetched", 0)
             replica_fetches += vals.get("replica_fetches", 0)
             for k in fetch_locality:
@@ -553,16 +593,18 @@ def job_profile(detail: dict, spans: List[dict]) -> dict:
             }
             if any(fusion.values()):
                 row["tpu"].update(fusion)
+            # folded join: the build side at its bucket, rows probed, and
+            # the rows of padding this stage sent to the device
+            row["tpu"].update(_renamed(tpu, _JOIN_COUNTERS))
         if gang:
             # mesh gang stage: where its one task's wall (gang_stage_ms)
             # went, phase by phase, from MeshGangExec's always-on counters
+            row.setdefault("tpu", {}).update(_renamed(gang, _GANG_PHASES))
+        if exchange:
+            # device exchange: what the exchange program was handed and
+            # where the exchange's host time went
             row.setdefault("tpu", {}).update(
-                {
-                    name: round(gang[k] / _NS_PER_MS, 3)
-                    if k.endswith("_ns") else gang[k]
-                    for k, name in _GANG_PHASES
-                    if k in gang
-                }
+                _renamed(exchange, _EXCHANGE_COUNTERS)
             )
         stages.append(row)
 

@@ -18,7 +18,6 @@ TPU-first design rules (see /opt/skills/guides/pallas_guide.md):
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
@@ -699,17 +698,26 @@ def make_join_kernel(
     return fn
 
 
-def _pad(x: np.ndarray, n: int) -> np.ndarray:
+def _pad(x: np.ndarray, n: int, fill=0) -> np.ndarray:
+    """``x`` padded to ``n`` rows with ``fill``: the one way rows are padded
+    for the device (batches, join build sides, exchange inputs).  What the
+    pad rows hold never reaches an answer: each caller ships a validity
+    column padded False, or a fill no real row can match."""
     if len(x) == n:
         return x
-    out = np.zeros(n, dtype=x.dtype)
+    # zeros come as untouched pages; only a fill has to be written
+    out = np.full(n, fill, dtype=x.dtype) if fill else np.zeros(n, dtype=x.dtype)
     out[: len(x)] = x
     return out
 
 
 def bucket_rows(n: int, floor: int = 1024) -> int:
-    """Power-of-two bucketing caps distinct XLA shapes at ~log2(max rows)."""
-    return max(floor, 1 << math.ceil(math.log2(max(n, 1))))
+    """The one rule for a row count the data decides (rows of a batch, of
+    a join's build side, of an exchange, group slots fetched): the next
+    power of two, not below ``floor``.  A jitted program's shapes then take
+    ~log2(max rows) values and two data sets of like size share every
+    program; padding costs under 2x the rows."""
+    return max(floor, 1 << (max(int(n), 1) - 1).bit_length())
 
 
 # ------------------------------------------------------------- fused kernel

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
 from typing import Iterator, Optional
 
@@ -22,6 +23,7 @@ import numpy as np
 import pyarrow as pa
 
 # jax is already imported by ops/__init__; .errors adds no backend init
+import jax
 from jax.errors import JaxRuntimeError as _JaxRuntimeError
 
 from ..config import BallistaConfig
@@ -36,6 +38,7 @@ from ..exec.operators import (
     TaskContext,
 )
 from ..exec.planner import RenameSchemaExec
+from ..obs import trace
 from . import kernels as K
 
 log = logging.getLogger(__name__)
@@ -205,10 +208,22 @@ _DENSE_JOIN_SPAN_CAP = 1 << 26
 _FUSED_MAX_ENTRIES = 32
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _dense_join_table(slots, span: int):
+    """[span] i32 table of the dense join: row index + 1 at a build key's
+    slot, 0 where there is no such key.  A slot >= ``span`` (the build
+    side's pad rows) is out of bounds and dropped."""
+    import jax.numpy as jnp
+
+    return jnp.zeros(span, jnp.int32).at[slots].set(
+        jnp.arange(1, slots.shape[0] + 1, dtype=jnp.int32), mode="drop"
+    )
+
+
 def _keep_bucket(n_groups: int) -> int:
     """Pow2 bucket of assigned-group slots a packed fetch moves (shared
     by the streamed and fused fetch paths so their trace keys agree)."""
-    return 1 << max(6, (max(n_groups, 1) - 1).bit_length())
+    return K.bucket_rows(n_groups, floor=64)
 
 
 def keyed_route_wanted(config) -> bool:
@@ -1482,13 +1497,23 @@ class TpuStageExec(ExecutionPlan):
         cap = self.capacity
         dense_join = build is not None and build[0] == "dense"
         _, kernel = self._kernel_for(cap, dense=dense_join)
-        with _closing_on_error(ra), self.metrics.timer("tpu_stage_time_ns"):
+        # join.probe: the batches of this partition through the folded
+        # join and the aggregate (one kernel), to the fetch of the states
+        probe_span = (
+            trace.span("join.probe", partition=partition)
+            if fused.join is not None else trace.NOOP
+        )
+        pad_rows = batches = uploads = 0
+        with _closing_on_error(ra), self.metrics.timer(
+            "tpu_stage_time_ns"
+        ), probe_span:
             for batch in src:
                 if batch.num_rows == 0:
                     continue
                 n = batch.num_rows
                 n_rows_in += n
                 n_pad = K.bucket_rows(n)
+                pad_rows += n_pad - n
 
                 if fused.group_exprs:
                     if acc is None and not entries:
@@ -1595,6 +1620,13 @@ class TpuStageExec(ExecutionPlan):
                     args, trivial_idx = self._kernel_args(
                         batch, n, n_pad, build
                     )
+                # host arrays this batch hands to the device, each its own
+                # transfer (the row mask and the build side are there)
+                batches += 1
+                uploads += (seg is not None) + sum(
+                    isinstance(a, np.ndarray) and i not in trivial_idx
+                    for i, a in enumerate(args)
+                )
                 with self.metrics.timer("device_time_ns"):
                     if ck is None and fusion_retain:
                         # fusion-only retention (whole-stage fusion on a
@@ -1670,7 +1702,14 @@ class TpuStageExec(ExecutionPlan):
                         acc,
                         group_table.n_groups if fused.group_exprs else None,
                     )
+            probe_span.set_attr("rows", n_rows_in)
+            probe_span.set_attr("padded_rows", pad_rows)
 
+        self.metrics.add("stage_pad_rows", pad_rows)
+        self.metrics.add("stage_batches", batches)
+        self.metrics.add("stage_uploads", uploads)
+        if fused.join is not None:
+            self.metrics.add("join_probe_rows", n_rows_in)
         if ck is not None and entries:
             device_cache.put(
                 ck[0], partition, ck[1],
@@ -2193,7 +2232,7 @@ class TpuStageExec(ExecutionPlan):
             n_groups = int(np.asarray(out[-1]))
         if n_groups > self.max_capacity:
             raise _CapacityExceeded()
-        cap = max(64, 1 << (max(n_groups, 1) - 1).bit_length())
+        cap = K.bucket_rows(n_groups, floor=64)
         finish = K.keyed_finish_kernel(
             holder["kinds"], holder["plan"], self.specs, n_keys, cap,
             self._mode,
@@ -2257,7 +2296,7 @@ class TpuStageExec(ExecutionPlan):
         flat_end = len(fields) - n_extras
         flat_cols = fields[1 + n_keys:flat_end]
         extras = fields[flat_end:]
-        cap = max(64, 1 << (max(n_groups, 1) - 1).bit_length())
+        cap = K.bucket_rows(n_groups, floor=64)
         finish = K.keyed_finish_kernel(
             holder["kinds"], holder["plan"], self.specs, n_keys, cap,
             self._mode,
@@ -2396,15 +2435,11 @@ class TpuStageExec(ExecutionPlan):
     def _prepare_build(self, ctx: TaskContext):
         """Collect + sort the build side once: device arrays for the
         kernel's searchsorted/gather, host copies for group resolution.
-        Raises ExecutionError (→ CPU fallback) on non-unique keys or
+        Raises _JoinIneligible (→ join on CPU) on non-unique keys or
         un-shippable key/column ranges."""
-        from .bridge import arrow_to_numpy
-
         with self._build_lock:
             if self._build_state is not None:
                 return self._build_state
-            import jax
-
             spec = self.fused.join
             batches = []
             for p in range(spec.build.output_partitioning().n):
@@ -2412,78 +2447,94 @@ class TpuStageExec(ExecutionPlan):
                     ctx.check_cancelled()
                     if b.num_rows:
                         batches.append(b)
-            if batches:
-                table = pa.Table.from_batches(batches, schema=spec.build.schema)
-            else:
-                table = spec.build.schema.empty_table()
-            key_col = table.column(spec.build_key_index)
-            kv, kvalid = arrow_to_numpy(
-                key_col.combine_chunks()
-                if isinstance(key_col, pa.ChunkedArray)
-                else key_col
-            )
-            kv = kv.astype(np.int64)
-            if kvalid is not None:
-                table = table.filter(pa.array(kvalid))
-                kv = kv[kvalid]  # null build keys never match an inner join
-            order = np.argsort(kv, kind="stable")
-            kv_sorted = kv[order]
-            if len(kv_sorted) > 1 and bool(
-                np.any(kv_sorted[1:] == kv_sorted[:-1])
-            ):
-                raise _JoinIneligible("device join requires unique build keys")
-            table = table.take(pa.array(order))
+            # join_build_ns / join.build: the build itself (sort, pad,
+            # upload, table), not the child's execute that feeds it
+            with self.metrics.timer("join_build_ns"), trace.span(
+                "join.build"
+            ) as span:
+                state, m_pad = self._build_join_state(spec, batches)
+                span.set_attr("rows", len(state[4]) if m_pad else 0)
+                span.set_attr("capacity", m_pad)
+                span.set_attr("dense", state[0] == "dense")
+            self._build_state = state
+            return state
 
-            if len(kv_sorted) == 0:
-                self._build_state = ("empty",)
-                return self._build_state
+    def _build_join_state(self, spec: DeviceJoinSpec, batches: list):
+        """(build state, rows the build side was padded to)."""
+        from .bridge import arrow_to_numpy
 
-            try:
-                bkeys_dev = jax.device_put(K.coerce_host_values(kv_sorted))
-                bvals, bvalids = [], []
-                for ci in self._device_build_cols:
-                    col = table.column(ci).combine_chunks()
-                    vals, validity = arrow_to_numpy(col)
-                    bvals.append(jax.device_put(K.coerce_host_values(vals)))
-                    if validity is None:
-                        validity = np.ones(len(vals), dtype=bool)
-                    bvalids.append(jax.device_put(validity))
-            except ExecutionError as e:
-                # un-shippable key/column ranges or types: join on CPU,
-                # aggregate on device (not a full-CPU fallback)
-                raise _JoinIneligible(str(e)) from e
-            kmin = int(kv_sorted[0])
-            span = int(kv_sorted[-1]) - kmin + 1
-            if span <= _DENSE_JOIN_SPAN_CAP:
-                # Dense-key direct probe (BENCH_SUITE_r05 starjoin row:
-                # searchsorted's log2(m) serial gather passes dominated
-                # 38s of device time): scatter build rows into a
-                # [span]-slot table once, probe with ONE gather.  Built
-                # device-side so only bkeys (already resident) feed the
-                # scatter — the table itself never crosses the bridge.
-                # TPC-H integer keys (orderkey/custkey/partkey) always
-                # qualify at SF<=10; wider spans keep the sorted probe.
-                import jax.numpy as jnp
+        if batches:
+            table = pa.Table.from_batches(batches, schema=spec.build.schema)
+        else:
+            table = spec.build.schema.empty_table()
+        key_col = table.column(spec.build_key_index)
+        kv, kvalid = arrow_to_numpy(
+            key_col.combine_chunks()
+            if isinstance(key_col, pa.ChunkedArray)
+            else key_col
+        )
+        kv = kv.astype(np.int64)
+        if kvalid is not None:
+            table = table.filter(pa.array(kvalid))
+            kv = kv[kvalid]  # null build keys never match an inner join
+        order = np.argsort(kv, kind="stable")
+        kv_sorted = kv[order]
+        if len(kv_sorted) > 1 and bool(
+            np.any(kv_sorted[1:] == kv_sorted[:-1])
+        ):
+            raise _JoinIneligible("device join requires unique build keys")
+        table = table.take(pa.array(order))
 
-                m = len(kv_sorted)
-                span_b = max(16, 1 << (span - 1).bit_length())
-                slots = (
-                    jnp.asarray(bkeys_dev, jnp.int64)
-                    - jnp.int64(kmin)
-                ).astype(jnp.int32)
-                tbl = jnp.zeros(span_b, jnp.int32).at[slots].set(
-                    jnp.arange(1, m + 1, dtype=jnp.int32)
+        if len(kv_sorted) == 0:
+            return ("empty",), 0
+
+        # the build side goes up at its bucket, not at its own length, so
+        # that the probe kernel's shapes do not follow the rows the build
+        # side's filters let through.  A pad row can match no probe key:
+        # the sorted probe sees the LAST key repeated (searchsorted-left
+        # finds the real row first), the dense table gives it no slot; its
+        # columns read invalid.
+        m = len(kv_sorted)
+        m_pad = K.bucket_rows(m)
+        try:
+            keys_host = K.coerce_host_values(kv_sorted)  # range-checked
+            bvals, bvalids = [], []
+            for ci in self._device_build_cols:
+                col = table.column(ci).combine_chunks()
+                vals, validity = arrow_to_numpy(col)
+                bvals.append(
+                    jax.device_put(K._pad(K.coerce_host_values(vals), m_pad))
                 )
-                self._build_state = (
-                    "dense", tbl, bvals, bvalids, kv_sorted, table,
-                    np.int64(kmin),
-                )
-                self.metrics.add("dense_join", 1)
-                return self._build_state
-            self._build_state = (
-                "ok", bkeys_dev, bvals, bvalids, kv_sorted, table
+                if validity is None:
+                    validity = np.ones(len(vals), dtype=bool)
+                bvalids.append(jax.device_put(K._pad(validity, m_pad)))
+        except ExecutionError as e:
+            # un-shippable key/column ranges or types: join on CPU,
+            # aggregate on device (not a full-CPU fallback)
+            raise _JoinIneligible(str(e)) from e
+        self.metrics.add("join_build_rows", m)
+        self.metrics.add("join_build_capacity", m_pad)
+        kmin = int(kv_sorted[0])
+        span = int(kv_sorted[-1]) - kmin + 1
+        if span <= _DENSE_JOIN_SPAN_CAP:
+            # Dense-key direct probe (BENCH_SUITE_r05 starjoin row:
+            # searchsorted's log2(m) serial gather passes dominated 38s of
+            # device time): scatter build rows into a [span]-slot table
+            # once, probe with ONE gather.  Built device-side, so only the
+            # build rows' slot numbers cross the bridge, never the table.
+            # TPC-H integer keys (orderkey/custkey/partkey) always qualify
+            # at SF<=10; wider spans keep the sorted probe.
+            span_b = K.bucket_rows(span, floor=16)
+            slots = K._pad(
+                (kv_sorted - kmin).astype(np.int32), m_pad, fill=span_b
             )
-            return self._build_state
+            self.metrics.add("dense_join", 1)
+            return (
+                "dense", _dense_join_table(slots, span_b), bvals, bvalids,
+                kv_sorted, table, np.int64(kmin),
+            ), m_pad
+        bkeys = jax.device_put(K._pad(keys_host, m_pad, fill=keys_host[-1]))
+        return ("ok", bkeys, bvals, bvalids, kv_sorted, table), m_pad
 
     def _fetch_states(self, acc, n_groups: Optional[int] = None) -> Optional[list]:
         """One packed device→host fetch of the whole state tuple.

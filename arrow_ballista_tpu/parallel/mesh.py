@@ -118,8 +118,9 @@ def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
 
     Generalizes :func:`ici_all_to_all_repartition` (single f64 column) to a
     typed multi-column payload (VERDICT.md round-1 item 4): the routing —
-    stable sort by destination, per-destination staging slots, overflow
-    accounting — is computed ONCE from (dest, valid), then every column
+    a row's rank within its destination's run (row order kept),
+    per-destination staging slots, overflow accounting — is computed
+    ONCE from (dest, valid), then every column
     scatters into its own [n_dev, capacity] staging buffer and rides its
     own ``all_to_all``.  Columns may be any device dtype (f32/f64, i32,
     bool, dictionary codes); validity masks travel as ordinary bool
@@ -143,25 +144,26 @@ def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
         return cached
 
     def local_exchange(dest, valid, *cols):
-        rows = dest.shape[0]
+        # a row's slot in its destination's staging run is its rank among
+        # the valid rows bound for that destination, in row order: a
+        # running count per destination (the mesh is small), no sort and
+        # no gather.  Invalid rows (pad rows among them) take the sentinel
+        # destination, rank nowhere and land in the spill column.
         dest_m = jnp.where(valid, dest, n_dev)
-        order = jnp.argsort(dest_m, stable=True)
-        dest_s = dest_m[order]
-        counts = jax.ops.segment_sum(
-            jnp.ones(rows, jnp.int32), dest_s, num_segments=n_dev + 1
-        )[:n_dev]
-        offsets = jnp.cumsum(counts) - counts
-        safe_dest = jnp.minimum(dest_s, n_dev - 1)
-        idx_within = jnp.arange(rows, dtype=jnp.int32) - offsets[safe_dest]
-        ok = (dest_s < n_dev) & (idx_within >= 0) & (idx_within < capacity)
-        overflow = (dest_s < n_dev) & (idx_within >= capacity)
+        bound = dest_m[:, None] == jnp.arange(n_dev, dtype=dest_m.dtype)
+        rank = jnp.cumsum(bound.astype(jnp.int32), axis=0) - 1
+        idx_within = jnp.sum(jnp.where(bound, rank, 0), axis=1)
+        real = dest_m < n_dev
+        ok = real & (idx_within < capacity)
+        overflow = real & (idx_within >= capacity)
         n_dropped = jax.lax.psum(
             jnp.sum(overflow.astype(jnp.int32)), DATA_AXIS
         )
+        safe_dest = jnp.minimum(dest_m, n_dev - 1)
         slot = jnp.where(ok, idx_within, capacity)
 
         def route(c, fill_ok=False):
-            cs = (ok if fill_ok else c[order])
+            cs = ok if fill_ok else c
             stage = jnp.zeros((n_dev, capacity + 1), cs.dtype)
             stage = stage.at[safe_dest, slot].set(cs, mode="drop")
             stage = stage[:, :capacity]
@@ -284,8 +286,11 @@ class BatchExchanger:
     # ------------------------------------------------------------ exchange
     def exchange(self, dest: np.ndarray, valid: np.ndarray, cols):
         """Run the sharded exchange; returns (recv_cols, recv_valid,
-        n_dropped) as host arrays."""
-        sharded = shard_batch(self.mesh, [dest, valid] + list(cols))
+        n_dropped) as host arrays.  The inputs go up padded to
+        :func:`exchange_rows`; a pad row is ``valid = False``, which the
+        program sends to the sentinel destination and delivers nowhere."""
+        rows = exchange_rows(len(dest), self.mesh.devices.size)
+        sharded = shard_batch(self.mesh, [dest, valid] + list(cols), rows)
         out = self._fn(*sharded)
         host = [np.asarray(o) for o in out[:-1]]
         return host[:-1], host[-1], int(np.asarray(out[-1]))
@@ -476,18 +481,28 @@ def assemble_shards(
     return out
 
 
+def exchange_rows(total: int, n_dev: int) -> int:
+    """Rows an exchange's input arrays are padded to: ``total`` in its
+    bucket (``kernels.bucket_rows``), a multiple of the mesh so that shards
+    are equal.  The program's shapes follow this, never ``total``, so two
+    inputs of like size share one compiled exchange."""
+    from ..ops import kernels as K
+
+    return -(-K.bucket_rows(total) // n_dev) * n_dev
+
+
 def shard_batch(
-    mesh: Mesh, arrays: Sequence[np.ndarray]
+    mesh: Mesh, arrays: Sequence[np.ndarray], rows: Optional[int] = None
 ) -> list[jax.Array]:
-    """Place host arrays onto the mesh sharded along the row axis."""
+    """Place host arrays onto the mesh sharded along the row axis, padded
+    (``kernels._pad``: zeros, so a padded validity column reads False) to
+    ``rows``, or to the next multiple of the mesh."""
+    from ..ops import kernels as K
+
     sharding = NamedSharding(mesh, P(DATA_AXIS))
+    n_dev = mesh.devices.size
     out = []
     for a in arrays:
-        n_dev = mesh.devices.size
-        n = len(a)
-        padded = ((n + n_dev - 1) // n_dev) * n_dev
-        if padded != n:
-            pad = np.zeros(padded - n, dtype=a.dtype)
-            a = np.concatenate([a, pad])
-        out.append(jax.device_put(a, sharding))
+        n = rows if rows is not None else -(-len(a) // n_dev) * n_dev
+        out.append(jax.device_put(K._pad(a, n), sharding))
     return out

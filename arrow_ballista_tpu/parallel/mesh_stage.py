@@ -519,7 +519,7 @@ class MeshGangExec(ExecutionPlan):
                 ]
                 if max(counts, default=0) > tpu.max_capacity:
                     raise _CapacityExceeded()
-                cap = max(64, 1 << (max(max(counts), 1) - 1).bit_length())
+                cap = K.bucket_rows(max(counts), floor=64)
                 fetches = []
                 for so, ng in zip(sort_out, counts):
                     if so is None:
@@ -648,6 +648,7 @@ class MeshRepartitionExec(ExecutionPlan):
         import jax
 
         from ..errors import ExecutionError
+        from ..ops import kernels as K
         from ..shuffle.execution_plans import partition_indices
         from . import mesh as M
 
@@ -655,11 +656,15 @@ class MeshRepartitionExec(ExecutionPlan):
         exprs = list(self.partitioning.exprs)
         n_dev = self.n_devices or ctx.config.mesh_devices or len(jax.devices())
         n_dev = max(1, min(n_dev, len(jax.devices())))
+        add = self.metrics.add
+        clock = time.perf_counter_ns
 
         # the exchange buffers the stage input in host memory (~2x resident
         # plus device staging): a row ceiling keeps huge shuffles on the
         # streaming hash-split path instead of OOMing this task
         max_rows = ctx.config.mesh_exchange_max_rows
+        # the stage's wall stops before the first yield: the writer that
+        # consumes the batches has its own timers
         with self.metrics.timer("mesh_stage_time_ns"):
             batches: list[pa.RecordBatch] = []
             dest_parts: list[np.ndarray] = []
@@ -682,54 +687,57 @@ class MeshRepartitionExec(ExecutionPlan):
             if not batches:
                 return
 
-            # destination column rides the exchange so one device can
-            # carry several output partitions (n_out != n_dev)
-            ext_schema = pa.schema(
-                list(self.input.schema) + [pa.field("__part", pa.int32())]
-            )
-            ext_batches = [
-                pa.RecordBatch.from_arrays(
-                    list(b.columns) + [pa.array(d)], schema=ext_schema
-                )
-                for b, d in zip(batches, dest_parts)
-            ]
-            dest_dev = np.concatenate(dest_parts) % n_dev
-            dest_dev = dest_dev.astype(np.int32)
-            total = len(dest_dev)
-            valid = np.ones(total, dtype=bool)
-
-            # exact per-(source shard, destination) bucket need from the
-            # known contiguous shard layout (shard_batch pads evenly)
-            per_shard = -(-total // n_dev)
-            shard_id = np.arange(total, dtype=np.int64) // per_shard
-            need = int(
-                np.bincount(
-                    shard_id * n_dev + dest_dev, minlength=n_dev * n_dev
-                ).max()
-            )
-            cap = 1 << max(need - 1, 0).bit_length()
-
             mesh = M.make_mesh(n_dev)
-            try:
-                base_ex = None
-                cols = None
-                while True:
-                    ex = M.BatchExchanger(
-                        mesh, ext_schema, cap, share_from=base_ex
-                    )
-                    if cols is None:  # encoding is capacity-independent
-                        base_ex = ex
-                        cols_per_batch = [
-                            ex.to_columns(b) for b in ext_batches
-                        ]
-                        cols = [
-                            np.concatenate(parts)
-                            for parts in zip(*cols_per_batch)
-                        ]
-                    with self.metrics.timer("device_time_ns"):
-                        recv_cols, recv_valid, n_dropped = ex.exchange(
-                            dest_dev, valid, cols
+            t0 = clock()
+            with trace.span("exchange.encode"):
+                # destination column rides the exchange so one device can
+                # carry several output partitions (n_out != n_dev)
+                ext_schema = pa.schema(
+                    list(self.input.schema)
+                    + [pa.field("__part", pa.int32())]
+                )
+                dest_dev = (np.concatenate(dest_parts) % n_dev).astype(np.int32)
+                total = len(dest_dev)
+                valid = np.ones(total, dtype=bool)
+                # exact per-(source shard, destination) bucket need, from
+                # the contiguous shard layout of the PADDED input: the
+                # arrays go up at exchange_rows(total) rows (the pad rows
+                # are invalid and fill the last shards), so that the
+                # program's shapes do not follow the data
+                rows = M.exchange_rows(total, n_dev)
+                shard_id = np.arange(total, dtype=np.int64) // (rows // n_dev)
+                need = int(
+                    np.bincount(
+                        shard_id * n_dev + dest_dev, minlength=n_dev * n_dev
+                    ).max()
+                )
+                cap = K.bucket_rows(need, floor=1)
+                try:
+                    ex = M.BatchExchanger(mesh, ext_schema, cap)
+                    # encoding is capacity-independent
+                    cols_per_batch = [
+                        ex.to_columns(
+                            pa.RecordBatch.from_arrays(
+                                list(b.columns) + [pa.array(d)],
+                                schema=ext_schema,
+                            )
                         )
+                        for b, d in zip(batches, dest_parts)
+                    ]
+                except ExecutionError as e:
+                    # column didn't cross the bridge (dtype slipped past the
+                    # plan-time check): an exchange failure, not a plan failure
+                    raise MeshExchangeError(str(e)) from e
+                cols = [np.concatenate(parts) for parts in zip(*cols_per_batch)]
+            t1 = clock()
+            growths = 0
+            with trace.span(
+                "exchange.device", rows=total, padded_rows=rows - total
+            ) as dev_span:
+                while True:
+                    recv_cols, recv_valid, n_dropped = ex.exchange(
+                        dest_dev, valid, cols
+                    )
                     if n_dropped == 0:
                         break
                     cap *= 2  # grow-or-fallback contract (mesh.py docstring)
@@ -737,39 +745,52 @@ class MeshRepartitionExec(ExecutionPlan):
                         raise MeshExchangeError(
                             "mesh exchange capacity ceiling exceeded"
                         )
-                    self.metrics.add("capacity_growths", 1)
-            except ExecutionError as e:
-                # column didn't cross the bridge (dtype slipped past the
-                # plan-time check): an exchange failure, not a plan failure
-                raise MeshExchangeError(str(e)) from e
-
-            self.metrics.add("mesh_exchange_rows", total)
-            self.metrics.add("mesh_devices", n_dev)
+                    growths += 1
+                    ex = M.BatchExchanger(mesh, ext_schema, cap, share_from=ex)
+                dev_span.set_attr("capacity", cap)
+                dev_span.set_attr("growths", growths)
+            t2 = clock()
+            out: list[tuple[int, pa.RecordBatch]] = []
+            part_col = len(ext_schema) - 1
+            with trace.span("exchange.decode"):
+                for recv in ex.to_batches(recv_cols, recv_valid):
+                    if recv.num_rows == 0:
+                        continue
+                    parts = np.asarray(recv.column(part_col))
+                    core = recv.select(range(part_col))
+                    order = np.argsort(parts, kind="stable")
+                    shuffled = core.take(pa.array(order))
+                    bounds = np.searchsorted(
+                        parts[order], np.arange(n_out + 1)
+                    ).tolist()
+                    for out_p in range(n_out):
+                        lo, hi = bounds[out_p], bounds[out_p + 1]
+                        if hi > lo:
+                            out.append((out_p, shuffled.slice(lo, hi - lo)))
+            add("exchange_encode_ns", t1 - t0)
+            add("device_time_ns", t2 - t1)
+            add("exchange_decode_ns", clock() - t2)
+            if growths:
+                add("capacity_growths", growths)
+            add("mesh_exchange_rows", total)
+            # what the program was handed, padding included (the
+            # destination and validity arrays and every encoded column),
+            # and what it handed back (devices x capacity slots a device)
+            add("mesh_exchange_padded_rows", rows - total)
+            add(
+                "mesh_exchange_bytes",
+                rows * (
+                    dest_dev.itemsize + valid.itemsize
+                    + sum(c.itemsize for c in cols)
+                ),
+            )
+            add(
+                "mesh_exchange_recv_bytes",
+                recv_valid.nbytes + sum(c.nbytes for c in recv_cols),
+            )
+            add("mesh_devices", n_dev)
             MeshRepartitionExec.exchanges_completed += 1
-
-        part_col = len(ext_schema) - 1
-        received = iter(ex.to_batches(recv_cols, recv_valid))
-        while True:
-            # the stage's wall stops at each yield: the writer that
-            # consumes these batches has its own timers
-            with self.metrics.timer("mesh_stage_time_ns"):
-                recv = next(received, None)
-                if recv is None:
-                    return
-                if recv.num_rows == 0:
-                    continue
-                parts = np.asarray(recv.column(part_col))
-                core = recv.select(range(part_col))
-                order = np.argsort(parts, kind="stable")
-                sorted_parts = parts[order]
-                shuffled = core.take(pa.array(order))
-                bounds = np.searchsorted(
-                    sorted_parts, np.arange(n_out + 1)
-                ).tolist()
-            for out_p in range(n_out):
-                lo, hi = bounds[out_p], bounds[out_p + 1]
-                if hi > lo:
-                    yield out_p, shuffled.slice(lo, hi - lo)
+        yield from out
 
 
 def maybe_mesh(plan: ExecutionPlan, config) -> ExecutionPlan:

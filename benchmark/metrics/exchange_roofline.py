@@ -1,0 +1,23 @@
+"""Least time the chip's memory could take to read the exchange programs'
+inputs and write their outputs once (``_exchange.exchange_bytes`` of the
+traced queries, over the cell's chips and the table of peaks' bytes/s), over
+the summed device time of the exchange program (``jit_local_exchange``) in
+the trace.  Bound: memory (an exchange computes nothing; its sort is what
+the share shows)."""
+
+from benchmark.metrics import _exchange
+
+UNIT, BETTER, SOURCE = "%", "higher", "device_trace"
+LAYER, MOVES = "exchange", "scan_rows_rate"
+
+
+def read(run):
+    tr = run.get("trace")
+    if not tr or not tr["queries"] or not run.get("peaks"):
+        return None
+    program_s = sum(s for name, s in tr["device_ops"] if "local_exchange" in name)
+    moved = [_exchange.exchange_bytes(q["job"]) for q in tr["queries"]]
+    if not program_s or None in moved:
+        return None
+    least_s = sum(moved) / run["chips"] / run["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * least_s / program_s

@@ -108,8 +108,12 @@ def test_batch_exchange_overflow_reported():
         ex = M.BatchExchanger(mesh, batch.schema, capacity=16)
         cols = ex.to_columns(batch)
         _, recv_valid, n_dropped = ex.exchange(dest, np.ones(n, bool), cols)
-        # each source device holds 64 rows for dest 0 but capacity is 16
-        assert n_dropped == n - N_DEV * 16
-        assert int(recv_valid.sum()) == N_DEV * 16
+        # the input goes up padded to its bucket, so the n rows fill the
+        # first shards whole: each of those holds more rows for dest 0
+        # than the capacity of 16
+        per_shard = M.exchange_rows(n, N_DEV) // N_DEV
+        sources = -(-n // per_shard)
+        assert per_shard > 16 and n_dropped == n - sources * 16
+        assert int(recv_valid.sum()) == sources * 16
     finally:
         K.set_precision(None)
