@@ -86,6 +86,10 @@ class TaskContext:
     # granularity by the stage driver (the Python analogue of the reference's
     # ``futures::abortable`` wrapper, executor/src/executor.rs:97-134).
     cancel_event: Optional[threading.Event] = None
+    # Task slots of the executor that runs this task: how many tasks may
+    # share the process's cores with it (a stage that fans host work out
+    # over threads sizes its pool by it).  1 for a local session.
+    task_slots: int = 1
 
     @property
     def batch_size(self) -> int:

@@ -270,6 +270,7 @@ class Executor:
                     job_id=pid.job_id,
                     stage_id=pid.stage_id,
                     cancel_event=cancel_event,
+                    task_slots=self.concurrent_tasks,
                 )
                 with trace.span("shuffle.write") as wspan:
                     partitions = writer.execute_shuffle_write(

@@ -304,10 +304,16 @@ def chrome_trace(spans: List[dict], job_id: str = "") -> dict:
 
 
 # MeshGangExec's phase counters -> their names in a profile row's "tpu"
-# block (*_ns become *_ms).  The seven phases are self times: they sum to
-# gang_stage_ms up to loop overhead.
+# block (*_ns become *_ms).  wait, merge, upload, assemble, step and
+# materialize are the task thread's self times: they sum to gang_stage_ms
+# up to loop overhead.  scan, encode and convert are summed over the
+# gang_workers threads that prepare partitions side by side, inside the
+# task thread's wait.
 _GANG_PHASES = (
     ("mesh_stage_time_ns", "gang_stage_ms"),
+    ("gang_workers", "gang_workers"),
+    ("gang_wait_ns", "gang_wait_ms"),
+    ("gang_merge_ns", "gang_merge_ms"),
     ("gang_scan_ns", "gang_scan_ms"),
     ("key_encode_time_ns", "gang_encode_ms"),
     ("gang_convert_ns", "gang_convert_ms"),

@@ -150,26 +150,7 @@ class DictEncoder:
         enc = arr.dictionary_encode()
         local = enc.dictionary  # distinct NON-NULL values, arrow-native
         n_local = len(local)
-        if self._dict is not None and not self._dict.type.equals(local.type):
-            local = local.cast(self._dict.type)
-        if self._dict is None or len(self._dict) == 0:
-            got_np = np.full(n_local, -1, dtype=np.int64)
-        else:
-            got = pc.index_in(local, value_set=self._dict)
-            got_np = np.asarray(got.fill_null(-1)).astype(np.int64)
-        mapping = got_np
-        miss = mapping < 0
-        n_miss = int(miss.sum())
-        if n_miss:
-            new_vals = local.filter(pa.array(miss))
-            base = len(self._dict) if self._dict is not None else 0
-            self._dict = (
-                pa.concat_arrays([self._dict, new_vals])
-                if self._dict is not None
-                else new_vals
-            )
-            mapping = mapping.copy()
-            mapping[miss] = base + np.arange(n_miss)
+        mapping = self._adopt(local)
         idx = enc.indices
         has_null = idx.null_count > 0 or arr.null_count > 0
         codes = np.asarray(idx.fill_null(0))
@@ -196,6 +177,40 @@ class DictEncoder:
             else null1
         )
         return code
+
+    def _adopt(self, vals: pa.Array) -> np.ndarray:
+        """Code here of every entry of ``vals`` (distinct values; a null
+        among them is the NULL key's slot); the ones not seen before are
+        appended in ``vals``' order."""
+        if self._dict is None or len(self._dict) == 0:
+            if len(vals):
+                self._dict = vals
+            return np.arange(len(vals), dtype=np.int64)
+        if not vals.type.equals(self._dict.type):
+            vals = vals.cast(self._dict.type)
+        # a null matches the null slot (skip_nulls=False), so the fill
+        # marks exactly the values this encoder has not seen
+        got = pc.index_in(vals, value_set=self._dict, skip_nulls=False)
+        mapping = np.asarray(got.fill_null(-1)).astype(np.int64)
+        miss = mapping < 0
+        n_miss = int(miss.sum())
+        if n_miss:
+            mapping[miss] = len(self._dict) + np.arange(n_miss)
+            self._dict = pa.concat_arrays(
+                [self._dict, vals.filter(pa.array(miss))]
+            )
+        return mapping
+
+    def merge(self, local: "DictEncoder") -> np.ndarray:
+        """Adopt the values of ``local``, an encoder that saw one partition
+        alone: ``remap[local code]`` is the code here.  Values new to this
+        encoder are appended in ``local``'s order (first appearance in its
+        partition, the NULL slot included), so merging partitions in order
+        builds the dictionary that :meth:`encode` builds when it is fed
+        the partitions one after the other."""
+        if local._dict is None:
+            return np.empty(0, dtype=np.int64)
+        return self._adopt(local._dict)
 
     @property
     def size(self) -> int:
@@ -347,6 +362,15 @@ def make_key_encoder(t: pa.DataType):
     if pa.types.is_boolean(t):
         return BoolKeyEncoder()
     return DictEncoder()
+
+
+def merge_key_codes(into, local) -> Optional[np.ndarray]:
+    """``remap[code of local] = code of into`` for two encoders of one key
+    column (``into`` adopts ``local``'s values); None where the code is
+    pure in the value (identity, bool) and needs no map."""
+    if isinstance(into, DictEncoder):
+        return into.merge(local)
+    return None
 
 
 def device_key_encoder(t: pa.DataType, mode: str):

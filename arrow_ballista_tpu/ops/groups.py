@@ -53,6 +53,20 @@ class GroupTable:
         """Per-key dictionary codes for the given group ids (vectorized)."""
         return self.key_mat[gids, key]
 
+    def key_columns(self, code_maps: list) -> list[np.ndarray]:
+        """Per-key code columns of every group, in gid order, each through
+        its ``code_maps`` entry (``remap[code]``; None keeps the codes).
+        A table that encoded one partition against its own key encoders
+        merges into the stage's with ``stage.encode(local.key_columns(
+        maps))``: the result maps local gids to the stage's, and because
+        local gids are in first-appearance order, merging partitions in
+        order assigns the gids that :meth:`encode` assigns when it is fed
+        the partitions' rows one after the other."""
+        return [
+            self.key_mat[:, k] if m is None else m[self.key_mat[:, k]]
+            for k, m in enumerate(code_maps)
+        ]
+
     # ------------------------------------------------------------ internal
     def _combine(self, code_cols: list[np.ndarray]) -> np.ndarray:
         combined = code_cols[0].astype(np.int64)

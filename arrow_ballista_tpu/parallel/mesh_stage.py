@@ -19,9 +19,16 @@ retries and stats see an ordinary one-task stage.  The reduced
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import functools
+import os
+import threading
 import time
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, field
+from itertools import islice
 from typing import Iterator, Optional
 
 import numpy as np
@@ -60,6 +67,91 @@ def _sched_cpu() -> int:
     platform cannot say.  Only traced gang stages ask."""
     fn = _libc_sched_getcpu()
     return int(fn()) if fn is not None else -1
+
+
+def _usable_cores() -> int:
+    """Cores this process may run on (its affinity mask, which a
+    container or ``taskset`` narrows; the machine's count where the
+    platform has no such call)."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:
+        return os.cpu_count() or 1
+
+
+# The gang stage's worker pool is never wider than this: on the chip host
+# (13 cores; PERF.md, PR 29) q1's stage read 1004, 637, 445, 385, 323 ms
+# at widths 1, 2, 3, 4, 6 and nothing steady past 6 (8 and 12 read inside
+# 6's run-to-run range), q6's was flat from 4 -- the workers' scans, key
+# hashing and copies meet in memory bandwidth and the GIL.
+_MAX_GANG_WIDTH = 6
+
+
+def _gang_width(ctx: TaskContext, n_parts: int) -> int:
+    """How many partitions a gang stage prepares side by side: the cores
+    the process can see less one for each OTHER task slot of the executor
+    (a gang stage is its stage's only task; what shares the process with
+    it is other stages' tasks, a thread each), at most one worker a
+    partition, at least 1 -- and 1 is the same loop run inline.  Four
+    slots each running a pool of 6 at once (24 threads on 13 cores) were
+    no slower than four pools of 3 or four single threads (same sweep),
+    so the rule does not divide the cores by the slots."""
+    spare = _usable_cores() - (max(1, ctx.task_slots) - 1)
+    return max(1, min(spare, n_parts, _MAX_GANG_WIDTH))
+
+
+@dataclass
+class _Prepared:
+    """What a worker hands back for one partition: nothing in it is
+    shared with another partition's."""
+
+    rows: int = 0
+    seg: Optional[np.ndarray] = None  # local segment ids (row -> local gid)
+    cols: list = field(default_factory=list)  # numpy, tpu._flat_names order
+    encoders: list = field(default_factory=list)  # the partition's own
+    table: object = None  # the partition's own GroupTable
+
+
+def _pull(it) -> tuple:
+    """(next batch of a source iterator or None, ns inside the source)."""
+    t0 = time.perf_counter_ns()
+    batch = next(it, None)
+    return batch, time.perf_counter_ns() - t0
+
+
+def _close(it) -> None:
+    """Close a source iterator that may have been left half read (a
+    generator then runs its ``finally``: timers, open files)."""
+    close = getattr(it, "close", None)
+    if close is not None:
+        close()
+
+
+def _in_partition_order(prepare, n_parts: int, width: int, stop):
+    """``prepare(p)`` for p = 0 .. n_parts-1, results in that order, up to
+    ``width`` partitions in the making side by side (in flight or done
+    and waiting their turn), one more being handed over.  An error of
+    ``prepare(p)`` is raised when p's turn comes.  Closing the generator
+    -- after the last result, an error or the consumer's own exit -- sets
+    ``stop`` and joins every worker, so no thread outlives the stage.
+    Width 1 runs ``prepare`` inline on the caller's thread."""
+    if width <= 1:
+        for p in range(n_parts):
+            yield prepare(p)
+        return
+    pool = ThreadPoolExecutor(width, thread_name_prefix="gang")
+    todo = iter(range(n_parts))
+    pending: deque = deque()
+    try:
+        pending.extend(pool.submit(prepare, p) for p in islice(todo, width))
+        while pending:
+            part = pending.popleft().result()
+            # top up before the hand-over, so no worker idles through it
+            pending.extend(pool.submit(prepare, p) for p in islice(todo, 1))
+            yield part
+    finally:
+        stop.set()
+        pool.shutdown(wait=True, cancel_futures=True)
 
 
 def gang_eligible(plan: ExecutionPlan) -> bool:
@@ -175,12 +267,16 @@ class MeshGangExec(ExecutionPlan):
 
         A plain method (the caller materializes the result anyway), so the
         ``gang.*`` spans nest on the thread's span stack.  The stage's wall
-        is ``mesh_stage_time_ns``; each part of it is counted to exactly
-        one phase (``gang_scan_ns``, ``key_encode_time_ns``,
-        ``gang_convert_ns``, ``gang_upload_ns``, ``gang_assemble_ns``,
-        ``gang_step_ns``, ``gang_materialize_ns``), and what is left is
-        loop overhead.  ``gang_cpu_ns`` is this thread's CPU time over the
-        same wall."""
+        is ``mesh_stage_time_ns``; on THIS thread each part of it is
+        counted to exactly one phase (``gang_wait_ns``: until the next
+        partition in order is prepared; ``gang_merge_ns``,
+        ``gang_upload_ns``, ``gang_assemble_ns``, ``gang_step_ns``,
+        ``gang_materialize_ns``), and what is left is loop overhead.
+        ``gang_scan_ns``, ``key_encode_time_ns`` and ``gang_convert_ns``
+        are time inside those phases summed over the ``gang_workers``
+        threads that prepare partitions side by side (at width 1, this
+        thread, inside its wait).  ``gang_cpu_ns`` is this thread's CPU
+        time over the same wall."""
         import jax
 
         clock = time.perf_counter_ns
@@ -203,122 +299,201 @@ class MeshGangExec(ExecutionPlan):
     ) -> list[pa.RecordBatch]:
         import jax
 
+        from ..errors import Cancelled
         from ..ops import kernels as K
-        from ..ops.bridge import make_key_encoder
+        from ..ops.bridge import (
+            _concat_batches, make_key_encoder, merge_key_codes,
+        )
         from ..ops.groups import GroupTable
         from . import mesh as M
 
         clock = time.perf_counter_ns
         add = self.metrics.add
         fused = tpu.fused
-        key_encoders = [
-            make_key_encoder(tpu._schema.field(i).type)
-            for i in range(len(fused.group_exprs))
-        ]
-        group_table = GroupTable(len(fused.group_exprs))
+        n_keys = len(fused.group_exprs)
+
+        def new_key_encoders() -> list:
+            return [
+                make_key_encoder(tpu._schema.field(i).type)
+                for i in range(n_keys)
+            ]
+
+        key_encoders = new_key_encoders()
+        group_table = GroupTable(n_keys)
         n_rows = 0
         n_parts = fused.source.output_partitioning().n
-        # Partitions ARE the shards, and the partition is the unit of the
-        # host->device bridge: its batches are scanned, encoded and
-        # converted one by one, then each column is concatenated on host
-        # and the partition's columns go to its device (round-robin) in
-        # ONE device_put.  A device_put costs ~250 us whatever it carries,
-        # so a call per batch and column was half a query.  Peak host
-        # memory stays one partition (twice during the concatenate) and
-        # the asynchronous transfer overlaps the next partition's scan.
-        # The arrays handed over are never written again and no buffer is
-        # reused: the CPU backend may alias them and the TPU copies late.
+        width = _gang_width(ctx, n_parts)
+        add("gang_workers", width)
+        # the counts read 0, not absent, on a stage that left on its probe
+        for k in ("gang_batches", "gang_uploads", "gang_upload_bytes"):
+            add(k, 0)
+        # Partitions ARE the shards, and the partition is the unit of host
+        # work.  PREPARE (a worker, up to `width` partitions side by side):
+        # pull the partition's batches, coalesce them once at the Arrow
+        # level, encode the group keys against the partition's OWN
+        # encoders and group table, convert the columns to numpy.  Workers
+        # share nothing mutable.  HAND OVER (this thread, in partition
+        # order): map the partition's dictionaries and groups into the
+        # stage's, rewrite its segment ids with one gather, and give its
+        # columns to its device (round-robin) in ONE device_put (~250 us a
+        # call whatever it carries).  Local codes and gids are in
+        # first-appearance order and partitions merge in order, so the
+        # stage's gids are those of one encoder fed every row in order.
+        # Peak host memory is `width` + 1 partitions; the asynchronous
+        # transfer overlaps the next hand-over.  The arrays handed over
+        # are never written again and no buffer is reused: the CPU backend
+        # may alias them and the TPU copies late.
         # Column order per device chunk: [seg, valid, *flat_names].
         names = ["__seg", "__valid"] + list(tpu._flat_names)
         mesh = M.make_mesh(n_dev)
         devices = list(mesh.devices.flatten())
         n_dev_chunks: list[list[list]] = [[] for _ in devices]  # [device][partition][column]
-        for p in range(n_parts):
-            dev = devices[p % n_dev]
+        stage_ctx = trace.current_context() if traced else None
+        stop = threading.Event()
+        # partition -> (its open iterator, its first non-empty batch or
+        # None): what the route probe pulled before any worker started
+        opened: dict = {}
+
+        def prepare(p: int) -> _Prepared:
             part_span = trace.NOOP
             if traced:
                 part_span = trace.span(
-                    "gang.partition", partition=p, device=p % n_dev,
+                    "gang.partition", parent=stage_ctx, partition=p,
+                    device=p % n_dev,
+                    worker=threading.current_thread().name,
                     cpu_start=_sched_cpu(),
                 )
                 part_cpu0 = time.thread_time_ns()
             # phase times of this partition: local integers, one
-            # metrics.add each at its end (no lock, no timer object a batch)
-            scan_ns = encode_ns = convert_ns = upload_ns = 0
-            batches = uploads = upload_bytes = rows = 0
-            # the partition's host arrays until its one upload, per batch
-            part_segs: list[np.ndarray] = []
-            part_cols: list[list[np.ndarray]] = []  # [batch][*flat_names]
+            # metrics.add each at its end (no timer object a batch)
+            scan_ns = encode_ns = convert_ns = 0
+            out = _Prepared()
+            it, head = opened.pop(p, (None, None))
+            batches = [] if head is None else [head]
             with part_span:
                 try:
-                    it = iter(fused.source.execute(p, ctx))
+                    if it is None:
+                        it = iter(fused.source.execute(p, ctx))
                     while True:
-                        t0 = clock()
-                        batch = next(it, None)
-                        t1 = clock()
-                        scan_ns += t1 - t0
+                        batch, ns = _pull(it)
+                        scan_ns += ns
                         if batch is None:
                             break
                         ctx.check_cancelled()
-                        if batch.num_rows == 0:
-                            continue
-                        n = batch.num_rows
-                        if fused.group_exprs:
-                            part_segs.append(tpu._encode_groups(
-                                batch, key_encoders, group_table
-                            ))
-                            if n_rows == 0:
-                                self._check_highcard(
-                                    tpu, group_table.n_groups, n, n_dev
-                                )
-                            t2 = clock()
-                            encode_ns += t2 - t1
-                        else:
-                            t2 = t1
-                        env = K.build_env(batch, tpu.leaves, n)
-                        part_cols.append([env[nm] for nm in tpu._flat_names])
-                        convert_ns += clock() - t2
-                        batches += 1
-                        rows += n
-                        n_rows += n
-                    if rows:
+                        if stop.is_set():
+                            raise Cancelled("gang stage stopped")
+                        if batch.num_rows:
+                            batches.append(batch)
+                    if batches:
                         t0 = clock()
-                        seg = (
-                            np.concatenate(part_segs) if fused.group_exprs
-                            else np.zeros(rows, dtype=np.int32)
-                        )
-                        host = [seg, np.ones(rows, dtype=bool)] + [
-                            np.concatenate(c) for c in zip(*part_cols)
-                        ]
+                        whole = _concat_batches(batches)
+                        out.rows = n = whole.num_rows
                         t1 = clock()
-                        convert_ns += t1 - t0
-                        n_dev_chunks[p % n_dev].append(
-                            jax.device_put(host, dev)
-                        )
-                        upload_ns += clock() - t1
-                        uploads = len(host)
-                        upload_bytes = sum(a.nbytes for a in host)
+                        if n_keys:
+                            out.encoders = new_key_encoders()
+                            out.table = GroupTable(n_keys)
+                            out.seg = tpu._encode_groups(
+                                whole, out.encoders, out.table
+                            )
+                        t2 = clock()
+                        env = K.build_env(whole, tpu.leaves, n)
+                        out.cols = [env[nm] for nm in tpu._flat_names]
+                        encode_ns = t2 - t1
+                        convert_ns = (t1 - t0) + (clock() - t2)
+                    return out
                 finally:
+                    _close(it)
                     add("gang_scan_ns", scan_ns)
                     add("key_encode_time_ns", encode_ns)
                     add("gang_convert_ns", convert_ns)
-                    add("gang_upload_ns", upload_ns)
-                    add("bridge_time_ns", convert_ns + upload_ns)
-                    add("gang_uploads", uploads)
-                    add("gang_upload_bytes", upload_bytes)
-                    add("gang_batches", batches)
-                    add("gang_partitions", 1)
+                    add("bridge_time_ns", convert_ns)
+                    add("gang_batches", len(batches))
                     if traced:
                         for k, v in (
-                            ("rows", rows), ("batches", batches),
+                            ("rows", out.rows), ("batches", len(batches)),
                             ("scan_ns", scan_ns), ("encode_ns", encode_ns),
                             ("convert_ns", convert_ns),
-                            ("upload_ns", upload_ns),
-                            ("upload_bytes", upload_bytes),
                             ("cpu_ns", time.thread_time_ns() - part_cpu0),
                             ("cpu_end", _sched_cpu()),
                         ):
                             part_span.set_attr(k, v)
+
+        def hand_over(p: int, part: _Prepared) -> tuple:
+            """Partition p into the stage's groups and onto its device:
+            (merge ns, upload ns, arrays handed over, their bytes)."""
+            t0 = clock()
+            if n_keys:
+                remap = tpu._assign_gids(
+                    part.table.key_columns([
+                        merge_key_codes(mine, theirs)
+                        for mine, theirs in zip(key_encoders, part.encoders)
+                    ]),
+                    group_table,
+                )
+                seg = remap[part.seg]
+            else:
+                seg = np.zeros(part.rows, dtype=np.int32)
+            host = [seg, np.ones(part.rows, dtype=bool)] + part.cols
+            t1 = clock()
+            n_dev_chunks[p % n_dev].append(
+                jax.device_put(host, devices[p % n_dev])
+            )
+            return t1 - t0, clock() - t1, len(host), sum(a.nbytes for a in host)
+
+        try:
+            if n_keys:
+                # the route decision keeps its input: the stage's first
+                # non-empty batch, encoded alone, before a worker starts.
+                # Time this thread spends before it has a partition to
+                # hand over: the first wait.
+                t0 = clock()
+                try:
+                    self._probe_route(
+                        tpu, ctx, n_dev, opened, new_key_encoders()
+                    )
+                finally:
+                    add("gang_wait_ns", clock() - t0)
+            with contextlib.closing(
+                _in_partition_order(prepare, n_parts, width, stop)
+            ) as prepared:
+                for p in range(n_parts):
+                    hand_span = trace.NOOP
+                    if traced:
+                        hand_span = trace.span(
+                            "gang.handover", partition=p, device=p % n_dev
+                        )
+                    wait_ns = merge_ns = upload_ns = uploads = upload_bytes = 0
+                    with hand_span:
+                        try:
+                            t0 = clock()
+                            part = next(prepared)
+                            wait_ns = clock() - t0
+                            if part.rows:
+                                merge_ns, upload_ns, uploads, upload_bytes = (
+                                    hand_over(p, part)
+                                )
+                                n_rows += part.rows
+                        finally:
+                            add("gang_wait_ns", wait_ns)
+                            add("gang_merge_ns", merge_ns)
+                            add("gang_upload_ns", upload_ns)
+                            add("bridge_time_ns", upload_ns)
+                            add("gang_uploads", uploads)
+                            add("gang_upload_bytes", upload_bytes)
+                            add("gang_partitions", 1)
+                            if traced:
+                                for k, v in (
+                                    ("wait_ns", wait_ns),
+                                    ("merge_ns", merge_ns),
+                                    ("upload_ns", upload_ns),
+                                    ("upload_bytes", upload_bytes),
+                                ):
+                                    hand_span.set_attr(k, v)
+        finally:
+            # workers are joined by now; what the probe opened and no
+            # worker took over (an early exit) is closed here
+            for it, _ in opened.values():
+                _close(it)
 
         if n_rows == 0:
             return self._timed_materialize(
@@ -374,6 +549,46 @@ class MeshGangExec(ExecutionPlan):
         return self._timed_materialize(
             tpu, host_states, key_encoders, group_table, n_rows, ctx
         )
+
+    def _probe_route(
+        self, tpu, ctx: TaskContext, n_dev: int, opened: dict,
+        key_encoders: list,
+    ) -> None:
+        """Open partitions in order up to the stage's first non-empty
+        batch, encode that batch alone (against encoders of its own,
+        thrown away) and let :meth:`_check_highcard` choose the route
+        before anything else is read, encoded or uploaded.  What was
+        opened stays in ``opened`` for the partitions' workers."""
+        from ..ops.groups import GroupTable
+
+        fused = tpu.fused
+        scan_ns = encode_ns = 0
+        try:
+            for p in range(fused.source.output_partitioning().n):
+                it = iter(fused.source.execute(p, ctx))
+                opened[p] = (it, None)
+                while True:
+                    batch, ns = _pull(it)
+                    scan_ns += ns
+                    if batch is None:
+                        break
+                    ctx.check_cancelled()
+                    if batch.num_rows == 0:
+                        continue
+                    opened[p] = (it, batch)
+                    t0 = time.perf_counter_ns()
+                    try:
+                        table = GroupTable(len(key_encoders))
+                        tpu._encode_groups(batch, key_encoders, table)
+                    finally:
+                        encode_ns = time.perf_counter_ns() - t0
+                    self._check_highcard(
+                        tpu, table.n_groups, batch.num_rows, n_dev
+                    )
+                    return
+        finally:
+            self.metrics.add("gang_scan_ns", scan_ns)
+            self.metrics.add("key_encode_time_ns", encode_ns)
 
     def _timed_materialize(
         self, tpu, host_states, key_encoders, group_table, n_rows, ctx

@@ -579,13 +579,16 @@ def test_replica_refuses_service_and_replicates(tmp_path):
         b.put(Keyspace.Sessions, "r1", b"v1")
         b.put_txn([(Keyspace.Slots, "e1", b"4"), (Keyspace.Slots, "e2", b"2")])
         b.delete(Keyspace.Slots, "e2")
+        # a fence: a keyspace's events are applied in order, so once e3 is
+        # there e2's put AND its delete are (e2 also reads None BEFORE its
+        # put arrives, which let the poll below leave too early)
+        b.put(Keyspace.Slots, "e3", b"1")
         # replication is async: poll the backup's LOCAL backend
         deadline = time.time() + 10
         while time.time() < deadline:
             if (
                 backup_backend.get(Keyspace.Sessions, "r1") == b"v1"
-                and backup_backend.get(Keyspace.Slots, "e1") == b"4"
-                and backup_backend.get(Keyspace.Slots, "e2") is None
+                and backup_backend.get(Keyspace.Slots, "e3") == b"1"
             ):
                 break
             time.sleep(0.1)

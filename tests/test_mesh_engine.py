@@ -429,6 +429,13 @@ GANG_PHASES = (
     "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
     "gang_assemble_ns", "gang_step_ns", "gang_materialize_ns",
 )
+# since PR 29: what the task thread's wall splits into, and what is summed
+# over the workers that prepare partitions side by side
+GANG_TASK_PHASES = (
+    "gang_wait_ns", "gang_merge_ns", "gang_upload_ns", "gang_assemble_ns",
+    "gang_step_ns", "gang_materialize_ns",
+)
+GANG_WORKER_PHASES = ("gang_scan_ns", "key_encode_time_ns", "gang_convert_ns")
 
 
 class _UploadSpy:
@@ -453,7 +460,9 @@ class _UploadSpy:
 def test_local_gang_q1_counts_every_phase_once(monkeypatch):
     """A local gang q1: MeshGangExec carries every phase counter, the counts
     follow from the input (one device_put a non-empty partition, carrying
-    every column) and the seven self times sum to at most the one wall."""
+    every column), the task thread's six self times (wait, merge, upload,
+    assemble, step, materialize) sum to at most the one wall, and the
+    workers' three (scan, encode, convert) to at most that wall a worker."""
     from benchmarks.tpch.queries import QUERIES
     from arrow_ballista_tpu.exec.operators import TaskContext
     from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
@@ -495,9 +504,13 @@ def test_local_gang_q1_counts_every_phase_once(monkeypatch):
         a.nbytes for cols, _ in spy.calls for a in cols
     )
     assert m["mesh_rows_in"] == sum(b.num_rows for b in batches)
-    for k in GANG_PHASES + ("mesh_stage_time_ns", "gang_cpu_ns"):
+    for k in GANG_PHASES + GANG_TASK_PHASES + ("mesh_stage_time_ns", "gang_cpu_ns"):
         assert m[k] >= 0, k
-    assert 0 < sum(m[k] for k in GANG_PHASES) <= m["mesh_stage_time_ns"]
+    wall, workers = m["mesh_stage_time_ns"], m["gang_workers"]
+    assert 1 <= workers <= n_parts
+    assert 0 < sum(m[k] for k in GANG_TASK_PHASES) <= wall
+    assert m["gang_wait_ns"] > 0 and m["gang_merge_ns"] > 0
+    assert 0 < sum(m[k] for k in GANG_WORKER_PHASES) <= workers * wall
     # the two lumps the older readers know are now sums of phases
     assert m["bridge_time_ns"] == m["gang_convert_ns"] + m["gang_upload_ns"]
     assert m["device_time_ns"] == m["gang_assemble_ns"] + m["gang_step_ns"]
@@ -599,13 +612,13 @@ def test_gang_cancel_between_batches_raises_before_the_partition_uploads(
     monkeypatch,
 ):
     """Cancellation is still checked at every batch: an event set while the
-    first batch converts stops the task at the second, before the partition
-    (whose upload waits for its last batch) has handed anything over."""
+    stage's first batch is probed for the route stops every partition at its
+    next batch, before any partition (whose upload waits for its last batch)
+    has handed anything over."""
     import threading
 
     from arrow_ballista_tpu.errors import Cancelled
     from arrow_ballista_tpu.exec.operators import TaskContext
-    from arrow_ballista_tpu.ops import kernels as K
 
     cfg = _cfg()
     ctx = SessionContext(cfg)
@@ -614,20 +627,22 @@ def test_gang_cancel_between_batches_raises_before_the_partition_uploads(
     (gang,) = _find(plan, MeshGangExec)
 
     cancel = threading.Event()
-    real_build_env = K.build_env
+    real_check = MeshGangExec._check_highcard
 
-    def build_env(*a, **kw):
+    def check_highcard(*a, **kw):
         cancel.set()
-        return real_build_env(*a, **kw)
+        return real_check(*a, **kw)
 
-    monkeypatch.setattr(K, "build_env", build_env)
+    monkeypatch.setattr(MeshGangExec, "_check_highcard", staticmethod(check_highcard))
     spy = _UploadSpy(monkeypatch)
     with pytest.raises(Cancelled):
         list(gang.execute(0, TaskContext(config=cfg, cancel_event=cancel)))
     m = gang.metrics.to_dict()
     assert spy.calls == []
+    # the probed batch is all that any partition's worker kept
     assert m["gang_batches"] == 1 and m["gang_partitions"] == 1
     assert m["gang_uploads"] == 0 and m["gang_upload_bytes"] == 0
+    assert not [t for t in threading.enumerate() if t.name.startswith("gang")]
 
 
 def test_scan_timer_does_not_count_its_consumer():

@@ -687,7 +687,7 @@ GANG_COUNTERS = (
     "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
     "gang_uploads", "gang_upload_bytes", "gang_assemble_ns", "gang_step_ns",
     "gang_materialize_ns", "mesh_stage_time_ns", "gang_cpu_ns", "gang_batches",
-    "gang_partitions",
+    "gang_partitions", "gang_workers", "gang_wait_ns", "gang_merge_ns",
 )
 
 
@@ -736,8 +736,8 @@ def test_gang_spans_nest_inside_the_trace_when_obs_is_on():
         by_name.setdefault(s["name"], []).append(s)
     for name in ("gang.stage", "gang.assemble", "gang.step", "gang.fetch", "gang.materialize"):
         assert len(by_name[name]) == 1, name
-    parts = by_name["gang.partition"]
-    assert len(parts) == 3 == counters["gang_partitions"]
+    parts, hands = by_name["gang.partition"], by_name["gang.handover"]
+    assert len(parts) == len(hands) == 3 == counters["gang_partitions"]
     (stage,) = by_name["gang.stage"]
     assert stage["parent"] == trace_id and stage["attrs"]["rows"] == 12000
     assert stage["attrs"]["groups"] == 4 and stage["attrs"]["capacity"] >= 4
@@ -749,13 +749,23 @@ def test_gang_spans_nest_inside_the_trace_when_obs_is_on():
         assert parent is stage, s["name"]
         # wall-clock anchors: a child starts inside its parent's interval
         assert parent["ts"] <= s["ts"] <= parent["ts"] + parent["dur"], s["name"]
-    # a partition's span carries the same numbers the counters sum
-    for key, counter in (("scan_ns", "gang_scan_ns"), ("encode_ns", "key_encode_time_ns"),
-                         ("convert_ns", "gang_convert_ns"), ("upload_ns", "gang_upload_ns"),
-                         ("upload_bytes", "gang_upload_bytes"), ("batches", "gang_batches")):
+    # the spans carry the same numbers the counters sum: a partition's
+    # (opened by the worker that prepared it) what the workers count, its
+    # hand-over's (the task thread) what the task thread counts; the route
+    # probe of the stage's first batch is the rest of scan, encode and wait
+    for key, counter in (("convert_ns", "gang_convert_ns"), ("batches", "gang_batches")):
         assert sum(p["attrs"][key] for p in parts) == counters[counter], key
+    for key, counter in (("scan_ns", "gang_scan_ns"), ("encode_ns", "key_encode_time_ns")):
+        assert 0 < sum(p["attrs"][key] for p in parts) <= counters[counter], key
+    for key, counter in (("merge_ns", "gang_merge_ns"), ("upload_ns", "gang_upload_ns"),
+                         ("upload_bytes", "gang_upload_bytes")):
+        assert sum(h["attrs"][key] for h in hands) == counters[counter], key
+    assert 0 < sum(h["attrs"]["wait_ns"] for h in hands) <= counters["gang_wait_ns"]
     for p in parts:
-        assert {"rows", "device", "cpu_ns", "cpu_start", "cpu_end"} <= set(p["attrs"])
+        assert {"rows", "device", "worker", "cpu_ns", "cpu_start", "cpu_end"} <= set(p["attrs"])
+    assert [h["attrs"]["partition"] for h in sorted(hands, key=lambda h: h["ts"])] == [0, 1, 2]
+    if counters["gang_workers"] > 1:
+        assert {p["tid"] for p in parts}.isdisjoint({h["tid"] for h in hands})
 
 
 def test_gang_stage_makes_no_span_object_when_obs_is_off(monkeypatch):
@@ -835,10 +845,13 @@ def test_standalone_gang_job_reports_task_run_time_phase_split_and_spans():
 
     (row,) = [s for s in prof["stages"] if s["stage_id"] == gang_id]
     tpu = row["tpu"]
-    phases = ("gang_scan_ms", "gang_encode_ms", "gang_convert_ms", "gang_upload_ms",
-              "gang_assemble_ms", "gang_step_ms", "gang_materialize_ms")
-    assert all(tpu[k] >= 0 for k in phases) and "compile_ms" in tpu
-    assert sum(tpu[k] for k in phases) <= tpu["gang_stage_ms"] + 0.01
+    task_phases = ("gang_wait_ms", "gang_merge_ms", "gang_upload_ms",
+                   "gang_assemble_ms", "gang_step_ms", "gang_materialize_ms")
+    worker_phases = ("gang_scan_ms", "gang_encode_ms", "gang_convert_ms")
+    assert all(tpu[k] >= 0 for k in task_phases + worker_phases) and "compile_ms" in tpu
+    assert sum(tpu[k] for k in task_phases) <= tpu["gang_stage_ms"] + 0.01
+    assert 1 <= tpu["gang_workers"] <= 3
+    assert sum(tpu[k] for k in worker_phases) <= tpu["gang_workers"] * tpu["gang_stage_ms"] + 0.01
     assert tpu["gang_partitions"] == 3 and tpu["gang_uploads"] > 0
     assert tpu["gang_upload_bytes"] > 0
     for other in prof["stages"]:
@@ -848,6 +861,7 @@ def test_standalone_gang_job_reports_task_run_time_phase_split_and_spans():
     slices = {e["args"]["span_id"]: e for e in tr["traceEvents"] if e["ph"] == "X"}
     gang = [e for e in slices.values() if e["name"].startswith("gang.")]
     assert sum(e["name"] == "gang.partition" for e in gang) == 3
+    assert sum(e["name"] == "gang.handover" for e in gang) == 3
     assert {"gang.stage", "gang.assemble", "gang.step", "gang.fetch",
             "gang.materialize"} <= {e["name"] for e in gang}
     for e in gang:
