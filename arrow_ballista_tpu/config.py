@@ -294,13 +294,13 @@ _ENTRIES: dict[str, ConfigEntry] = {
         ConfigEntry(
             TPU_HIGHCARD_MODE,
             "aggregate routing when the first batch shows groups ~ rows: "
-            "'auto' resolves by platform — accelerator backends run the "
-            "device-KEYED aggregation (group ids assigned by the device "
-            "sort, no host hash encode), the cpu backend hands to the "
-            "C++ hash aggregate (measured winner there: h2o q10 4x); "
-            "'device' pins the keyed path anywhere, 'cpu' pins the hash "
-            "handoff (A/B baseline), 'gid' pins the gid-table device "
-            "path even at high cardinality (A/B: capacity must fit)",
+            "'auto' hands a stage without a folded join to the C++ hash "
+            "aggregate and keeps a folded join on the gid-table device "
+            "path while its capacity can fit; 'cpu' is the same, named "
+            "(A/B baseline); 'device' pins the device-KEYED aggregation "
+            "(group ids assigned by the device sort, no host hash "
+            "encode); 'gid' pins the gid-table device path even at high "
+            "cardinality (A/B: capacity must fit)",
             _parse_highcard_mode,
             "auto",
         ),
@@ -340,8 +340,8 @@ _ENTRIES: dict[str, ConfigEntry] = {
             "compile a fusion-eligible map stage (scan→filter→project→"
             "partial-agg, plus the shuffle partition-id column when a "
             "shuffle hint is installed) into ONE jitted dispatch instead "
-            "of per-operator dispatches; segment boundaries come from the "
-            "measured routing table (fusion_max_ops/fusion_min_rows) and "
+            "of per-operator dispatches; segments are cut at 8 operators, "
+            "inputs under 2048 rows stream per batch, and "
             "any trace failure degrades segment-by-segment to the "
             "per-operator path; off keeps today's dispatch sequence "
             "byte-identical",

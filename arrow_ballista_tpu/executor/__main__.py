@@ -220,7 +220,6 @@ def main(argv=None) -> None:
         task_isolation=cfg["task_isolation"], plugin_dir=cfg["plugin_dir"],
     )
     from .. import native
-    from ..ops import routing
 
     # one machine-readable line: chip_smoke.py (and an operator) reads
     # which backend THIS process holds and what it runs with
@@ -231,7 +230,6 @@ def main(argv=None) -> None:
             {
                 **backend,
                 "native_partitioner": native.status(),
-                "routing_table": routing.current().source,
                 "flight_port": flight.port,
                 "policy": policy.value,
                 "work_dir": work_dir,

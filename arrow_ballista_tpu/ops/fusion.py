@@ -18,9 +18,9 @@ fusion is impossible or unprofitable:
   before producing output (join build, the keyed sort-based agg) cuts
   BEFORE itself: upstream ops still fuse, the breaker starts a fresh
   segment;
-* **capacity** — segments wider than ``fusion_max_ops`` (a measured
-  ``ops/routing_table.json`` entry, not a code constant) are split so
-  the unrolled XLA program stays clear of the compile cliff.
+* **capacity** — segments wider than ``max_ops``
+  (``stage_compiler._FUSION_MAX_OPS``) are split so the unrolled XLA
+  program stays clear of the compile cliff.
 
 The planner is pure bookkeeping — no jax, no device.  ``TpuStageExec``
 maps the plan onto its retained-entry single-dispatch runner: a plan

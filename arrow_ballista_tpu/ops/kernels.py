@@ -653,7 +653,7 @@ def make_join_kernel(
     ``table`` is a [span] array holding row_index+1 at slot key-kmin
     (0 = no such key), probed with ONE gather — searchsorted's log2(m)
     sequential gather passes dominated device time on the chip
-    (BENCH_SUITE_r05 starjoin row).  Either way non-matching probe rows
+    (round 5, star join).  Either way non-matching probe rows
     fold into the global row mask (inner join), so shapes stay static
     and the joined relation is never materialized.
     """
@@ -839,28 +839,10 @@ def _lex_merge(a_hi, a_lo, b_hi, b_lo, is_min: bool):
 # Tests force a strategy via set_agg_algorithm to exercise the matmul path
 # on the CPU-mesh CI host.
 _AGG_ALGO: dict = {"force": None}
-# matmul FLOP bounds come from the generated routing table
-# (ops/routing.py: dev/analyze_grid.py --emit over KERNELBENCH grids;
-# builtin defaults 8192 / 2^36 are the pre-table chip-measured values).
-# A non-None module value overrides the table (tests).
-_MATMUL_MAX_CAP: Optional[int] = None
-_MATMUL_MAX_ELEMS: Optional[int] = None
-
-
-def _matmul_max_cap() -> int:
-    if _MATMUL_MAX_CAP is not None:
-        return _MATMUL_MAX_CAP
-    from . import routing
-
-    return routing.value("matmul_max_cap")
-
-
-def _matmul_max_elems() -> int:
-    if _MATMUL_MAX_ELEMS is not None:
-        return _MATMUL_MAX_ELEMS
-    from . import routing
-
-    return routing.value("matmul_max_elems")
+# matmul FLOP bounds (chip, round 5): the MXU one-hot einsum beats the
+# other reducers while capacity <= 8192 and rows x capacity <= 2^36.
+_MATMUL_MAX_CAP = 8192
+_MATMUL_MAX_ELEMS = 1 << 36
 # Per-block MXU accumulation error grows ~sqrt(block)*eps relative to the
 # block sum; 16K-row blocks measured 9e-8 relative error on q1-scale data
 # (6M rows), an order inside the 1e-6 oracle tolerance.
@@ -892,8 +874,8 @@ def segment_algo(capacity: int, n_rows: Optional[int] = None) -> str:
         return _AGG_ALGO["force"]
     if jax.default_backend() == "cpu":
         return "scatter"
-    if capacity > _matmul_max_cap() or (
-        n_rows is not None and n_rows * capacity > _matmul_max_elems()
+    if capacity > _MATMUL_MAX_CAP or (
+        n_rows is not None and n_rows * capacity > _MATMUL_MAX_ELEMS
     ):
         return "scatter"
     return "matmul"
@@ -902,12 +884,12 @@ def segment_algo(capacity: int, n_rows: Optional[int] = None) -> str:
 def algo_cache_token() -> tuple:
     """Part of any compiled-kernel cache key: the strategy inputs that are
     NOT visible in the kernel signature (forced algorithm, backend,
-    routing-table matmul bounds — tests swap tables mid-process)."""
+    the matmul bounds)."""
     return (
         _AGG_ALGO["force"],
         jax.default_backend(),
-        _matmul_max_cap(),
-        _matmul_max_elems(),
+        _MATMUL_MAX_CAP,
+        _MATMUL_MAX_ELEMS,
     )
 
 
@@ -1519,7 +1501,7 @@ def _emit_scan_outs(plan, totals, presence) -> list:
 # boundaries (cumsum of change flags), and the packed fetch returns the
 # unique key codes alongside the states.  This replaces the host
 # hash-probe/factorize encode (``ops/groups.py``) on the high-cardinality
-# path — 44% of q3 SF10 wall in BENCH_SUITE_r03 — with one astype per key
+# path — 44% of q3 SF10 wall in round 3 — with one astype per key
 # per batch.  Counterpart of the reference's per-batch hash repartition
 # loop (``shuffle_writer.rs:214-256``), redesigned sort-first for a
 # scatter-hostile device.

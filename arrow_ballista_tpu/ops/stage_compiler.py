@@ -15,9 +15,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import enum
 import functools
+import itertools
 import logging
-from typing import Iterator, Optional
+from typing import Callable, Iterator, Optional
 
 import numpy as np
 import pyarrow as pa
@@ -72,42 +74,6 @@ class _JoinIneligible(Exception):
     the aggregate on device (the pre-fold round-2 shape)."""
 
 
-class _SmallInput(Exception):
-    """Control flow: the source peek found fewer rows than tpu.min_rows;
-    carries the already-buffered batches so the CPU path needn't re-scan."""
-
-    def __init__(self, batches: list):
-        super().__init__(f"{sum(b.num_rows for b in batches)} rows")
-        self.batches = batches
-
-
-class _HighCardinality(Exception):
-    """Control flow: the first batch showed groups ~ rows and
-    ``highcard_mode=cpu`` pins the C++ hash aggregate — the stage hands
-    back to the CPU path, replaying the consumed batch and chaining the
-    still-live source iterator (no re-scan)."""
-
-    def __init__(self, batches: list, tail):
-        super().__init__("high-cardinality aggregate")
-        self.batches = batches
-        self.tail = tail
-
-
-class _KeyedRoute(Exception):
-    """Control flow: the first batch showed groups ~ rows — route the
-    stage to the device-KEYED aggregation (raw key codes sort on device,
-    group ids from key-change boundaries; no host hash encode).  Carries
-    the consumed batch (with its already-computed key codes) and the
-    still-live source iterator."""
-
-    def __init__(self, batches: list, tail, key_encoders, ra):
-        super().__init__("keyed high-cardinality aggregate")
-        self.batches = batches  # [(RecordBatch, code_arrays)]
-        self.tail = tail
-        self.key_encoders = key_encoders
-        self.ra = ra
-
-
 class _TrackingIter:
     """Iterator wrapper recording whether any item was actually yielded —
     lets the keyed fallback replay buffered batches + chain the tail when
@@ -139,67 +105,107 @@ class _KeyedGroups:
         return self._codes[key][gids]
 
 
-# High-cardinality routing: below either bound the gid-table device path
-# wins outright (measured on chip, BENCH_r05_dev.json q1 SF10: 35-40x).
-# Above both, 'auto' routes to the C++ hash aggregate on EVERY platform
-# (join-free shapes) or stays on the gid table (fused joins, which pay
-# the join either way).  The measurements behind that:
-#   - chip (BENCH_SUITE_r05.json): q3 SF10 keyed = 0.036x — ~130s/iter
-#     of stream-wide device sort vs the hash aggregate's 14s; the r03
-#     gid/hash route ran the same query at 1.13x;
-#   - CPU platform (KERNELBENCH smoke, 1e5 rows: scatter 166M rows/s vs
-#     keyed sort 2.6M; h2o G1_1e6 A/B: q10 9.9s keyed vs 2.4s hash).
-# 'cpu' pins the hash handoff explicitly; 'device' pins the keyed path
-# (tests, chip A/B, and the r05 packed-sort rework whose chip numbers
-# are still pending — KERNELBENCH sort_operands will say whether the
-# 4.6-9x single-operand speedup moves the routing again).
-# The detector bounds load from the generated routing table
-# (ops/routing.py; regenerate via dev/analyze_grid.py --emit).  A
-# non-None module value overrides the table (tests pin tiny detector
-# bounds to route small fixtures keyed).
-_HIGHCARD_MIN_GROUPS: Optional[int] = None
-_HIGHCARD_RATIO: Optional[float] = None
+class Route(enum.Enum):
+    """How an aggregate stage runs, as :func:`choose_route` says."""
+
+    GID = "gid"  # host-assigned group ids into the device segment table
+    KEYED = "keyed"  # raw key codes sort on the device (``_run_keyed``)
+    CPU_HASH = "cpu_hash"  # the C++ hash aggregate, pulled batches replayed
+    CPU_SMALL = "cpu_small"  # source under tpu.min_rows: CPU operators
+    NOJOIN = "nojoin"  # join on CPU, only the aggregate on the device
 
 
-def _highcard_min_groups() -> int:
-    if _HIGHCARD_MIN_GROUPS is not None:
-        return _HIGHCARD_MIN_GROUPS
-    from . import routing
+@dataclasses.dataclass(frozen=True)
+class FirstBatch:
+    """What :func:`choose_route` may ask about a stage's first non-empty
+    batch.  The three questions cost host work (a range check, the key
+    encode, the group-id assignment), so each is a call, made only where
+    the route hangs on its answer."""
 
-    return routing.value("highcard_min_groups")
-
-
-def _highcard_ratio() -> float:
-    if _HIGHCARD_RATIO is not None:
-        return _HIGHCARD_RATIO
-    from . import routing
-
-    return routing.value("highcard_ratio")
-
-
-# Whole-stage fusion bounds (ballista.tpu.whole_stage_fusion) load from
-# the same measured table; non-None module values override (tests).
-_FUSION_MAX_OPS: Optional[int] = None
-_FUSION_MIN_ROWS: Optional[int] = None
+    rows: int
+    # raw key columns the device encodes itself, with identity keys in range
+    fast_encoders: Callable[[], bool]
+    # the host-encoded key codes are i32 (or the stage computes in x64)
+    keys_fit: Callable[[], bool]
+    # groups in the gid table after this batch; None: the table refused it
+    groups: Callable[[], Optional[int]]
 
 
-def _fusion_max_ops() -> int:
-    if _FUSION_MAX_OPS is not None:
-        return _FUSION_MAX_OPS
-    from . import routing
+# "groups ~ rows": a first batch with more groups than both bounds.  Below
+# either, the gid table wins outright (chip, round 5: q1 SF10 35-40x the
+# CPU operators).  Above both, the keyed device sort has won on no captured
+# shape (chip, round 5: q3 SF10 keyed ran 0.036x CPU, ~130 s an iteration
+# of stream-wide sort against the hash aggregate's 14 s, and 1.13x on the
+# gid table in round 3; CPU platform, 1e5 rows: scatter 166M rows/s against
+# the keyed sort's 2.6M), so only ``highcard_mode=device`` asks for it.
+# Tests set small bounds to route small fixtures.
+_HIGHCARD_MIN_GROUPS = 1 << 16
+_HIGHCARD_RATIO = 0.05
 
-    return routing.value("fusion_max_ops")
+
+def choose_route(
+    *,
+    highcard_mode: str,
+    device_encode: bool,
+    grouped: bool,
+    needs_keyed: bool,
+    folded_join: bool,
+    max_capacity: int,
+    small_input: bool = False,
+    first: Optional[FirstBatch] = None,
+) -> Optional[Route]:
+    """The one place that says how an aggregate stage runs.
+
+    Asked when the peek of the source ends (``small_input``: it ended
+    under ``tpu.min_rows``) and, if that gave None, with the stage's
+    ``first`` non-empty batch.  Pure: it reads its arguments and the two
+    bounds above, and calls ``first``'s questions in the order of their
+    cost.  ``highcard_mode`` is ``ballista.tpu.highcard_mode``; ``auto``
+    is ``cpu`` for a stage without a folded join.  A gang stage asks with
+    what its probe knows (no join, keys taken as fitting:
+    ``_execute_mesh_keyed`` checks them batch by batch)."""
+    if small_input:
+        return Route.CPU_SMALL
+    if not grouped:
+        return Route.GID  # every row into group 0: nothing to decide
+    if first is None:
+        return None
+    pinned_keyed = needs_keyed or highcard_mode == "device"
+    if device_encode and pinned_keyed and first.fast_encoders():
+        return Route.KEYED  # before any host encode
+    if needs_keyed:
+        # median/corr live on the keyed path at any cardinality
+        return Route.KEYED if first.keys_fit() else Route.CPU_HASH
+    groups = first.groups()
+    if groups is not None and not (
+        groups > _HIGHCARD_MIN_GROUPS and groups > _HIGHCARD_RATIO * first.rows
+    ):
+        return Route.GID
+    if highcard_mode == "device" and first.keys_fit():
+        return Route.KEYED
+    if highcard_mode == "gid" and groups is not None:
+        return Route.GID  # pinned (A/B)
+    if not folded_join:
+        return Route.CPU_HASH
+    # A folded join pays the join on either route, so it stays on the gid
+    # table while that can hold the stage -- but the table keys on every
+    # distinct PROBE key before the join filters, so a first batch that
+    # alone fills half the ceiling says the stream will overflow it after
+    # the host has paid the encode (q3 SF10: 15M order keys against the 2M
+    # ceiling): leave for the join-on-CPU shape now.
+    if groups is None or groups > max_capacity // 2:
+        return Route.NOJOIN
+    return Route.GID
 
 
-def _fusion_min_rows() -> int:
-    if _FUSION_MIN_ROWS is not None:
-        return _FUSION_MIN_ROWS
-    from . import routing
-
-    return routing.value("fusion_min_rows")
+# Whole-stage fusion (ballista.tpu.whole_stage_fusion): the widest operator
+# run the planner packs into one traced segment, and the stage input rows
+# under which a fused dispatch does not amortize its trace and launch.
+_FUSION_MAX_OPS = 8
+_FUSION_MIN_ROWS = 2048
 # Build-key spans up to this many slots use the dense direct-probe join
 # table ([span] i32 = 256 MiB HBM at the cap) instead of searchsorted's
-# log2(m) sequential gather passes (BENCH_SUITE_r05 starjoin row).
+# log2(m) sequential gather passes (chip, round 5, star join).
 _DENSE_JOIN_SPAN_CAP = 1 << 26
 # The fused single-dispatch runner unrolls one kernel body per retained
 # batch; past this many entries the per-batch dispatch loop runs instead
@@ -226,40 +232,6 @@ def _keep_bucket(n_groups: int) -> int:
     return K.bucket_rows(n_groups, floor=64)
 
 
-def keyed_route_wanted(config) -> bool:
-    """Does groups~rows route to the device-KEYED path in this config
-    on this platform?  (See the routing comment above.)
-
-    MEASURED r05 revision: the first chip capture of the keyed path
-    (BENCH_SUITE_r05 q3 SF10) ran 0.036x CPU — the stream-wide
-    multi-operand device sort is the cost center, and the same query's
-    gid/hash route measured 1.13x in r03.  No captured shape has the
-    keyed sort winning on real silicon, so ``auto`` now routes
-    groups~rows to the gid table (fused joins) or the C++ hash handoff
-    on EVERY platform; the keyed path is an explicit
-    ``highcard_mode=device`` pin (and remains mandatory for median/corr
-    stages, which need the device sort anyway)."""
-    mode = config.tpu_highcard_mode
-    if mode == "cpu":
-        return False
-    if mode == "device":
-        return True
-    from . import routing
-
-    # 'auto' follows the measured routing table: True only on platforms
-    # whose KERNELBENCH grid shows the keyed reduction winning the
-    # high-cardinality cells (dev/analyze_grid.py --emit)
-    return bool(routing.value("keyed_route_auto"))
-
-
-def _highcard_detect(n_groups: int, n_rows: int) -> bool:
-    """Raw groups~rows detector (first data batch), mode-independent."""
-    return (
-        n_groups > _highcard_min_groups()
-        and n_groups > _highcard_ratio() * n_rows
-    )
-
-
 class _ReadAhead:
     """Bounded background prefetch of source batches.
 
@@ -268,7 +240,7 @@ class _ReadAhead:
     the source's IO (pyarrow readers release the GIL in C++) with the
     current batch's device work.  The iterator is transparent: batches
     arrive in order, source exceptions re-raise at the consumer, and
-    fallback replay (``_HighCardinality.tail``) can keep consuming it —
+    a CPU replay (``TpuStageExec._cpu_replay``) can keep consuming it —
     queued batches are still inside and will be yielded.
 
     ``close()`` stops the pump before a fallback re-runs the stage on
@@ -372,12 +344,9 @@ def _closing_on_error(ra: Optional[_ReadAhead]):
     """Stop the prefetch pump when the device stage aborts into a CPU
     re-run (_CapacityExceeded / ExecutionError): the re-run opens a
     FRESH source iterator, so the old pump must not keep reading the
-    abandoned one.  _HighCardinality / _KeyedRoute pass through untouched
-    — their replay paths keep consuming this same iterator."""
+    abandoned one."""
     try:
         yield
-    except (_HighCardinality, _KeyedRoute):
-        raise
     except BaseException:
         if ra is not None:
             ra.close()
@@ -1236,122 +1205,65 @@ class TpuStageExec(ExecutionPlan):
 
     # ------------------------------------------------------------ execute
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[pa.RecordBatch]:
+        # _execute_device yields the stage's output, or returns the plan
+        # that answers in its place where the route or a failure left the
+        # device.  That plan runs OUTSIDE this try, so that a real CPU
+        # error propagates and is not mistaken for a device failure.
         try:
-            yield from self._execute_device(partition, ctx)
-            return
+            instead = yield from self._execute_device(partition, ctx)
         except _JoinIneligible:
             # non-unique or unrepresentable build keys: run the join on
             # CPU and keep ONLY the aggregate on device (round-2 shape)
             self.metrics.add("join_fallback", 1)
-            yield from self._nojoin_stage().execute(partition, ctx)
-            return
-        except _SmallInput as si:
-            # partition under tpu.min_rows: run the CPU operator path over
-            # the batches the peek already pulled (no source re-scan), and
-            # OUTSIDE this try so real CPU errors propagate instead of
-            # being mistaken for device failures
-            self.metrics.add("cpu_fallback", 1)
-            cpu_plan = self.original.with_new_children(
-                [
-                    _replace_leaf(
-                        self.original.input,
-                        self.fused.source,
-                        _BufferedExec(self.fused.source, si.batches),
-                    )
-                ]
-            )
-        except _KeyedRoute as kr:
-            # groups ~ rows: device-keyed aggregation (group ids assigned
-            # by the device sort, no host hash encode); late key overflow,
-            # cardinality past the segment ceiling, or device OOM (the
-            # keyed path buffers the stage input in HBM) drop to the CPU
-            # operator path below
-            self.metrics.add("keyed_path", 1)
-            tail = _TrackingIter(kr.tail)
-            try:
-                host_states, groups, n_rows_in, aux = (
-                    self._run_keyed(kr.batches, tail, kr.key_encoders, ctx)
-                )
-                out_batches = list(
-                    self._materialize(
-                        host_states, kr.key_encoders, groups, n_rows_in,
-                        ctx, partition, aux=aux,
-                    )
-                )
-            except (_CapacityExceeded, ExecutionError, _JaxRuntimeError) as e:
-                if isinstance(e, _JaxRuntimeError):
-                    note_device_error(self.metrics, f"{self} (keyed)", e)
-                else:
-                    self.metrics.add("tpu_fallback", 1)
-                if not tail.consumed:
-                    # failed before touching the live source: replay the
-                    # already-buffered batches + chain the tail (no
-                    # re-scan, _HighCardinality-style)
-                    cpu_plan = self.original.with_new_children(
-                        [
-                            _replace_leaf(
-                                self.original.input,
-                                self.fused.source,
-                                _BufferedExec(
-                                    self.fused.source,
-                                    [b for b, _ in kr.batches],
-                                    tail,
-                                ),
-                            )
-                        ]
-                    )
-                else:
-                    if kr.ra is not None:
-                        kr.ra.close()
-                    cpu_plan = self.original
-                yield from cpu_plan.execute(partition, ctx)
-                return
-            yield from out_batches
-            return
-        except _HighCardinality as hc:
-            # groups ~ rows with highcard_mode=cpu: hand the stage to the
-            # C++ hash aggregate, replaying the consumed batch + chaining
-            # the live source
-            self.metrics.add("highcard_fallback", 1)
-            cpu_plan = self.original.with_new_children(
-                [
-                    _replace_leaf(
-                        self.original.input,
-                        self.fused.source,
-                        _BufferedExec(self.fused.source, hc.batches, hc.tail),
-                    )
-                ]
-            )
+            instead = self._nojoin_stage()
         except _CapacityExceeded:
-            self.metrics.add("tpu_fallback", 1)
-            if self.fused.join is not None:
-                # a join-fused stage's gid table holds every distinct
-                # PROBE key, pre-filter — q3 SF10 has 15M orderkeys
-                # against the 2M ceiling even though only 1.26M groups
-                # survive the join.  The round-2 shape (join on CPU,
-                # aggregate on device over POST-join rows) keys the gid
-                # table on surviving groups instead, which is how r03
-                # captured q3 at 1.13x; its own execute() still falls to
-                # full CPU if even that overflows.
-                self.metrics.add("join_fallback", 1)
-                yield from self._nojoin_stage().execute(partition, ctx)
-                return
-            cpu_plan = self.original
+            instead = self._after_overflow()
         except ExecutionError:
             # a column type slipped past plan-time lowering checks
             # (Cancelled is a BallistaError sibling and still propagates)
             self.metrics.add("tpu_fallback", 1)
-            cpu_plan = self.original
+            instead = self.original
         except _JaxRuntimeError as e:
-            # the device/compiler failed mid-stage (BENCH_SUITE_r05 h2o:
+            # the device/compiler failed mid-stage (chip, round 5, h2o:
             # a SIGKILLed tpu_compile_helper surfaced as JaxRuntimeError
             # and killed the query instead of degrading) — re-run this
             # partition on the CPU operator path.  Only jax's runtime
             # error is caught: a blanket RuntimeError would silently
             # convert genuine bugs into fallbacks.
             note_device_error(self.metrics, str(self), e)
-            cpu_plan = self.original
-        yield from cpu_plan.execute(partition, ctx)
+            instead = self.original
+        if instead is not None:
+            yield from instead.execute(partition, ctx)
+
+    def _cpu_replay(self, batches: list, tail=None) -> ExecutionPlan:
+        """The CPU operator plan over the batches a device run already
+        pulled from the source, then what is still in the source
+        (``tail``): whatever left the device, nothing is scanned twice."""
+        return self.original.with_new_children(
+            [
+                _replace_leaf(
+                    self.original.input,
+                    self.fused.source,
+                    _BufferedExec(self.fused.source, batches, tail),
+                )
+            ]
+        )
+
+    def _after_overflow(self) -> ExecutionPlan:
+        """The plan that answers when the gid table cannot hold the
+        stage, as the first batch foretold or as the stream showed."""
+        self.metrics.add("tpu_fallback", 1)
+        if self.fused.join is None:
+            return self.original
+        # a join-fused stage's gid table holds every distinct PROBE key,
+        # pre-filter — q3 SF10 has 15M orderkeys against the 2M ceiling
+        # even though only 1.26M groups survive the join.  The round-2
+        # shape (join on CPU, aggregate on device over POST-join rows)
+        # keys the gid table on surviving groups instead, which is how
+        # round 3 captured q3 at 1.13x; its own execute() still falls to
+        # full CPU if even that overflows.
+        self.metrics.add("join_fallback", 1)
+        return self._nojoin_stage()
 
     def _cache_key(self, ctx: TaskContext):
         """(provider, signature) when the stage source is a cacheable scan."""
@@ -1381,6 +1293,10 @@ class TpuStageExec(ExecutionPlan):
     def _execute_device(
         self, partition: int, ctx: TaskContext
     ) -> Iterator[pa.RecordBatch]:
+        """Yields the stage's output, or returns the plan that answers in
+        its place (None where the output was yielded): prepare the build
+        side, peek, take the first batch, ask :func:`choose_route`, and
+        call the runner it names."""
         from . import device_cache
 
         fused = self.fused
@@ -1416,7 +1332,7 @@ class TpuStageExec(ExecutionPlan):
         ):
             from .fusion import plan_segments, stage_ops
 
-            fplan = plan_segments(stage_ops(self), _fusion_max_ops())
+            fplan = plan_segments(stage_ops(self), _FUSION_MAX_OPS)
             self.metrics.add("fused_segments", len(fplan.segments))
             self.metrics.add(
                 "fused_ops_per_dispatch", fplan.max_segment_ops
@@ -1451,25 +1367,34 @@ class TpuStageExec(ExecutionPlan):
             from .bridge import coalesce_batches
 
             src = coalesce_batches(src, coalesce, self.metrics)
+        facts = dict(
+            highcard_mode=self.config.tpu_highcard_mode,
+            device_encode=self.config.tpu_device_encode,
+            grouped=bool(fused.group_exprs),
+            needs_keyed=self._needs_keyed,
+            folded_join=fused.join is not None,
+            max_capacity=self.max_capacity,
+        )
         min_rows = self.config.tpu_min_rows
+        buffered: list[pa.RecordBatch] = []
+        small = False
         if min_rows > 0:
             # peek: kernel-launch/compile latency dominates tiny inputs, so
-            # partitions under the threshold run the CPU operator path
-            # (signalled to execute() with the buffered batches)
-            import itertools
-
-            buffered: list[pa.RecordBatch] = []
+            # a source that ends under the threshold runs the CPU operator
+            # path over the batches the peek pulled
             total = 0
-            exhausted = True
             for b in src:
                 buffered.append(b)
                 total += b.num_rows
                 if total >= min_rows:
-                    exhausted = False
                     break
-            if exhausted and total < min_rows:
-                raise _SmallInput(buffered)
+            else:
+                small = True
             src = itertools.chain(buffered, src)
+        route = choose_route(**facts, small_input=small)
+        if route is Route.CPU_SMALL:
+            self.metrics.add("cpu_fallback", 1)
+            return self._cpu_replay(buffered)
 
         depth = self.config.tpu_readahead
         ra: Optional[_ReadAhead] = None
@@ -1487,224 +1412,244 @@ class TpuStageExec(ExecutionPlan):
             if kind == "enc"
         ]
         group_table = GroupTable(max(self._n_encoded_groups, 1))
-        entries = []
-
-        import jax
-        import jax.numpy as jnp
-
-        acc = None
-        n_rows_in = 0
-        cap = self.capacity
         dense_join = build is not None and build[0] == "dense"
-        _, kernel = self._kernel_for(cap, dense=dense_join)
+        _, kernel = self._kernel_for(self.capacity, dense=dense_join)
         # join.probe: the batches of this partition through the folded
         # join and the aggregate (one kernel), to the fetch of the states
         probe_span = (
             trace.span("join.probe", partition=partition)
             if fused.join is not None else trace.NOOP
         )
-        pad_rows = batches = uploads = 0
+        codes = seg = None
         with _closing_on_error(ra), self.metrics.timer(
             "tpu_stage_time_ns"
         ), probe_span:
-            for batch in src:
-                if batch.num_rows == 0:
-                    continue
-                n = batch.num_rows
-                n_rows_in += n
-                n_pad = K.bucket_rows(n)
-                pad_rows += n_pad - n
-
-                if fused.group_exprs:
-                    if acc is None and not entries:
-                        # pre-encode fast path: keyed-pinned stages with
-                        # device-encodable keys route to _run_keyed
-                        # BEFORE any host group encode — the raw key
-                        # columns cross the bridge inside the fused
-                        # dispatch and key_encode_time_ns stays ~0
-                        fast = self._keyed_fast_encoders(batch)
-                        if fast is not None:
-                            raise _KeyedRoute([(batch, None)], src, fast, ra)
-                    with self.metrics.timer("key_encode_time_ns"):
-                        codes = self._encode_codes(batch, key_encoders)
-                    if acc is None and not entries:
-                        # keys the device can't take raw (i32 overflow
-                        # in x32) disqualify the keyed path up front:
-                        # host-assigned gids are always dense i32, so
-                        # the gid-table path stays available
-                        keyed_ok = self._mode != "x32" or all(
-                            len(c) == 0
-                            or (
-                                c.min() >= -(1 << 31)
-                                and c.max() < (1 << 31)
-                            )
-                            for c in codes
-                        )
-                        if self._needs_keyed:
-                            # median stages live on the keyed path at any
-                            # cardinality; unshippable keys → CPU (replay)
-                            if keyed_ok:
-                                raise _KeyedRoute(
-                                    [(batch, codes)], src, key_encoders, ra
-                                )
-                            raise _HighCardinality([batch], src)
-                        try:
-                            with self.metrics.timer("key_encode_time_ns"):
-                                seg = self._assign_gids(codes, group_table)
-                            first_groups = group_table.n_groups
-                        except _CapacityExceeded:
-                            # ONE batch outran the gid table / key radix:
-                            # definitionally high-cardinality
-                            first_groups = None
-                        if first_groups is None or _highcard_detect(
-                            first_groups, n
-                        ):
-                            if keyed_route_wanted(self.config) and keyed_ok:
-                                raise _KeyedRoute(
-                                    [(batch, codes)], src, key_encoders, ra
-                                )
-                            if (
-                                self.config.tpu_highcard_mode == "gid"
-                                and first_groups is not None
-                            ):
-                                pass  # pinned gid-table path (A/B)
-                            elif fused.join is None:
-                                raise _HighCardinality([batch], src)
-                            # fused device join at high cardinality:
-                            # stay on the gid-table path while it can
-                            # fit — but the table keys on every distinct
-                            # PROBE key pre-filter, so when batch 1
-                            # alone fills half the ceiling the stream
-                            # total will overflow it after the host has
-                            # paid the encode (q3 SF10: 15M orderkeys vs
-                            # the 2M cap, overflow discovered mid-stream)
-                            # — bail to the round-2 shape NOW
-                            if first_groups is None or (
-                                first_groups > self.max_capacity // 2
-                            ):
-                                raise _CapacityExceeded()
-                        # first batch: shrink the segment table to the
-                        # OBSERVED cardinality (2x headroom) — matmul-path
-                        # FLOPs scale with capacity, so a 6-group q1 must
-                        # not pay for the 1024-slot default table
-                        tight = 64
-                        while tight < 2 * max(1, group_table.n_groups):
-                            tight *= 4
-                        if tight < cap:
-                            cap = min(tight, self.max_capacity)
-                            _, kernel = self._kernel_for(
-                                cap, dense=dense_join
-                            )
-                    else:
-                        with self.metrics.timer("key_encode_time_ns"):
-                            seg = self._assign_gids(codes, group_table)
-                    # adaptive capacity: grow the segment table in 4x
-                    # buckets when the data's cardinality outruns it,
-                    # padding accumulated states (VERDICT round-1: fixed
-                    # 4096 caps fell back to CPU on q3/h2o shapes)
-                    if group_table.n_groups > cap:
-                        while cap < group_table.n_groups:
-                            cap *= 4
-                        cap = min(cap, self.max_capacity)
-                        acc = K.pad_states(self.specs, acc, cap, self._mode)
-                        _, kernel = self._kernel_for(
-                            cap, dense=dense_join
-                        )
-                        self.metrics.add("capacity_growths", 1)
-                else:
-                    seg = None  # all rows → group 0, synthesized on device
-                if seg is not None:
-                    seg = K._pad(seg, n_pad)
-
-                with self.metrics.timer("bridge_time_ns"):
-                    args, trivial_idx = self._kernel_args(
-                        batch, n, n_pad, build
+            first = next((b for b in src if b.num_rows), None)
+            if route is None:  # grouped: the first batch says
+                route = Route.GID
+                if first is not None:
+                    route, key_encoders, codes, seg = self._route_first_batch(
+                        facts, first, key_encoders, group_table
                     )
-                # host arrays this batch hands to the device, each its own
-                # transfer (the row mask and the build side are there)
-                batches += 1
-                uploads += (seg is not None) + sum(
-                    isinstance(a, np.ndarray) and i not in trivial_idx
-                    for i, a in enumerate(args)
+            if route is Route.GID:
+                host_states, n_rows_in = self._run_gid(
+                    first, seg, src, kernel, build, ck, fusion_retain,
+                    key_encoders, group_table, probe_span, partition,
                 )
-                with self.metrics.timer("device_time_ns"):
-                    if ck is None and fusion_retain:
-                        # fusion-only retention (whole-stage fusion on a
-                        # non-cache-eligible stage): the entries are
-                        # consumed ONCE by the fused dispatch right
-                        # after this loop, so everything stays on host —
-                        # no per-batch eager device op at all; the one
-                        # jitted call transfers its operands in bulk
-                        tail = np.arange(n_pad, dtype=np.int32) < n
-                        args = [
-                            tail if i in trivial_idx else a
-                            for i, a in enumerate(args)
-                        ]
-                        seg_h = (
-                            np.zeros(n_pad, dtype=np.int32)
-                            if seg is None
-                            else seg
+        if route is Route.KEYED:
+            return (
+                yield from self._execute_keyed(
+                    first, codes, src, ra, key_encoders, ctx, partition
+                )
+            )
+        if route is Route.CPU_HASH:
+            # groups ~ rows: hand the stage to the C++ hash aggregate,
+            # replaying the consumed batch + chaining the live source
+            self.metrics.add("highcard_fallback", 1)
+            return self._cpu_replay([first], src)
+        if route is Route.NOJOIN:
+            if ra is not None:
+                ra.close()  # the join-on-CPU shape scans for itself
+            return self._after_overflow()
+        yield from self._materialize(
+            host_states, key_encoders, group_table, n_rows_in, ctx, partition
+        )
+
+    def _route_first_batch(
+        self, facts: dict, batch, key_encoders: list, group_table
+    ) -> tuple:
+        """``(route, key encoders, key codes, segment ids)`` of a grouped
+        stage's first batch.  The last three are what answering
+        :func:`choose_route`'s questions worked out on the way (None where
+        it did not ask): the runner is handed them, it does not compute
+        them again."""
+        encoders, codes, seg = key_encoders, None, None
+
+        def fast_encoders() -> bool:
+            nonlocal encoders
+            fast = self._keyed_fast_encoders(batch)
+            if fast is not None:
+                encoders = fast
+            return fast is not None
+
+        def encoded() -> list:
+            nonlocal codes
+            if codes is None:
+                with self.metrics.timer("key_encode_time_ns"):
+                    codes = self._encode_codes(batch, key_encoders)
+            return codes
+
+        def keys_fit() -> bool:
+            # keys the device can't take raw (i32 overflow in x32) rule
+            # the keyed path out; host-assigned gids are always dense
+            # i32, so the gid table stays available
+            return self._mode != "x32" or all(
+                len(c) == 0
+                or (c.min() >= -(1 << 31) and c.max() < (1 << 31))
+                for c in encoded()
+            )
+
+        def groups() -> Optional[int]:
+            nonlocal seg
+            try:
+                with self.metrics.timer("key_encode_time_ns"):
+                    seg = self._assign_gids(encoded(), group_table)
+            except _CapacityExceeded:
+                # ONE batch outran the gid table / key radix
+                return None
+            return group_table.n_groups
+
+        route = choose_route(
+            **facts,
+            first=FirstBatch(batch.num_rows, fast_encoders, keys_fit, groups),
+        )
+        return route, encoders, codes, seg
+
+    def _run_gid(
+        self, first, seg, src, kernel, build, ck, fusion_retain: bool,
+        key_encoders: list, group_table, probe_span, partition: int,
+    ) -> tuple:
+        """The gid-table route: ``first`` (None: the source held no row)
+        with its segment ids, then every batch left in ``src``, through
+        the stage kernel at the initial capacity ``kernel`` was built
+        for.  Returns ``(host states, rows in)``."""
+        import jax
+        import jax.numpy as jnp
+
+        from . import device_cache
+
+        fused = self.fused
+        grouped = bool(fused.group_exprs)
+        dense_join = build is not None and build[0] == "dense"
+        entries = []
+        acc = None
+        cap = self.capacity
+        if grouped and first is not None:
+            # first batch: shrink the segment table to the OBSERVED
+            # cardinality (2x headroom) — matmul-path FLOPs scale with
+            # capacity, so a 6-group q1 must not pay for the 1024-slot
+            # default table
+            tight = 64
+            while tight < 2 * max(1, group_table.n_groups):
+                tight *= 4
+            if tight < cap:
+                cap = min(tight, self.max_capacity)
+                _, kernel = self._kernel_for(cap, dense=dense_join)
+        n_rows_in = pad_rows = batches = uploads = 0
+        stream = itertools.chain(
+            [] if first is None else [first], (b for b in src if b.num_rows)
+        )
+        for batch in stream:
+            n = batch.num_rows
+            n_rows_in += n
+            n_pad = K.bucket_rows(n)
+            pad_rows += n_pad - n
+
+            if grouped:
+                if batch is not first:
+                    with self.metrics.timer("key_encode_time_ns"):
+                        seg = self._encode_groups(
+                            batch, key_encoders, group_table
                         )
-                        entries.append((seg_h, tail, args))
-                        continue
-                    # device-built row tail mask, shared by the global
-                    # valid slot and every all-true leaf companion: two
-                    # eager ops replace n_pad*(1+n_trivial) host→HBM
-                    # bytes
-                    tail = jnp.arange(n_pad, dtype=jnp.int32) < n
+                # adaptive capacity: grow the segment table in 4x
+                # buckets when the data's cardinality outruns it,
+                # padding accumulated states (VERDICT round-1: fixed
+                # 4096 caps fell back to CPU on q3/h2o shapes)
+                if group_table.n_groups > cap:
+                    while cap < group_table.n_groups:
+                        cap *= 4
+                    cap = min(cap, self.max_capacity)
+                    acc = K.pad_states(self.specs, acc, cap, self._mode)
+                    _, kernel = self._kernel_for(cap, dense=dense_join)
+                    self.metrics.add("capacity_growths", 1)
+                seg = K._pad(seg, n_pad)
+            else:
+                seg = None  # all rows → group 0, synthesized on device
+
+            with self.metrics.timer("bridge_time_ns"):
+                args, trivial_idx = self._kernel_args(
+                    batch, n, n_pad, build
+                )
+            # host arrays this batch hands to the device, each its own
+            # transfer (the row mask and the build side are there)
+            batches += 1
+            uploads += (seg is not None) + sum(
+                isinstance(a, np.ndarray) and i not in trivial_idx
+                for i, a in enumerate(args)
+            )
+            with self.metrics.timer("device_time_ns"):
+                if ck is None and fusion_retain:
+                    # fusion-only retention (whole-stage fusion on a
+                    # non-cache-eligible stage): the entries are
+                    # consumed ONCE by the fused dispatch right
+                    # after this loop, so everything stays on host —
+                    # no per-batch eager device op at all; the one
+                    # jitted call transfers its operands in bulk
+                    tail = np.arange(n_pad, dtype=np.int32) < n
                     args = [
                         tail if i in trivial_idx else a
                         for i, a in enumerate(args)
                     ]
-                    seg_d = (
-                        jnp.zeros(n_pad, dtype=jnp.int32)
+                    seg_h = (
+                        np.zeros(n_pad, dtype=np.int32)
                         if seg is None
-                        else jax.device_put(seg)
+                        else seg
                     )
-                    if ck is not None:
-                        # retained for the device cache (and the fused
-                        # single-dispatch run after the loop): each arg
-                        # pins on device because the entries outlive
-                        # this query
-                        args = [
-                            a if a is tail else jax.device_put(a)
-                            for a in args
-                        ]
-                        entries.append((seg_d, tail, args))
-                    else:
-                        out = kernel(seg_d, tail, *args)
-                        acc = K.combine_states(
-                            self.specs, acc, out, self._mode
-                        )
-
-            # Cache-eligible stages dispatch ONCE per query: a single
-            # jitted call runs every entry's kernel, combines, and packs.
-            # The packed fetch is the device sync, so it lives INSIDE
-            # the device timer: device_time_ns covers queue + compute +
-            # result fetch
-            with self.metrics.timer("device_time_ns"):
-                if (ck is not None or fusion_retain) and entries:
-                    host_states = self._run_fused(
-                        entries, cap,
-                        group_table if fused.group_exprs else None,
-                        key_encoders,
-                        # below the measured amortization floor a fused
-                        # dispatch costs more than it saves: stream the
-                        # retained entries per-batch instead (the cache
-                        # path keeps its unconditional fused call)
-                        stream=(
-                            ck is None
-                            and n_rows_in < _fusion_min_rows()
-                        ),
-                    )
+                    entries.append((seg_h, tail, args))
+                    continue
+                # device-built row tail mask, shared by the global
+                # valid slot and every all-true leaf companion: two
+                # eager ops replace n_pad*(1+n_trivial) host→HBM
+                # bytes
+                tail = jnp.arange(n_pad, dtype=jnp.int32) < n
+                args = [
+                    tail if i in trivial_idx else a
+                    for i, a in enumerate(args)
+                ]
+                seg_d = (
+                    jnp.zeros(n_pad, dtype=jnp.int32)
+                    if seg is None
+                    else jax.device_put(seg)
+                )
+                if ck is not None:
+                    # retained for the device cache (and the fused
+                    # single-dispatch run after the loop): each arg
+                    # pins on device because the entries outlive
+                    # this query
+                    args = [
+                        a if a is tail else jax.device_put(a)
+                        for a in args
+                    ]
+                    entries.append((seg_d, tail, args))
                 else:
-                    host_states = self._fetch_states(
-                        acc,
-                        group_table.n_groups if fused.group_exprs else None,
+                    out = kernel(seg_d, tail, *args)
+                    acc = K.combine_states(
+                        self.specs, acc, out, self._mode
                     )
-            probe_span.set_attr("rows", n_rows_in)
-            probe_span.set_attr("padded_rows", pad_rows)
 
+        # Cache-eligible stages dispatch ONCE per query: a single
+        # jitted call runs every entry's kernel, combines, and packs.
+        # The packed fetch is the device sync, so it lives INSIDE
+        # the device timer: device_time_ns covers queue + compute +
+        # result fetch
+        with self.metrics.timer("device_time_ns"):
+            if (ck is not None or fusion_retain) and entries:
+                host_states = self._run_fused(
+                    entries, cap,
+                    group_table if grouped else None,
+                    key_encoders,
+                    # below the measured amortization floor a fused
+                    # dispatch costs more than it saves: stream the
+                    # retained entries per-batch instead (the cache
+                    # path keeps its unconditional fused call)
+                    stream=ck is None and n_rows_in < _FUSION_MIN_ROWS,
+                )
+            else:
+                host_states = self._fetch_states(
+                    acc, group_table.n_groups if grouped else None
+                )
+        probe_span.set_attr("rows", n_rows_in)
+        probe_span.set_attr("padded_rows", pad_rows)
         self.metrics.add("stage_pad_rows", pad_rows)
         self.metrics.add("stage_batches", batches)
         self.metrics.add("stage_uploads", uploads)
@@ -1715,9 +1660,44 @@ class TpuStageExec(ExecutionPlan):
                 ck[0], partition, ck[1],
                 (entries, key_encoders, group_table, n_rows_in, cap),
             )
-        yield from self._materialize(
-            host_states, key_encoders, group_table, n_rows_in, ctx, partition
-        )
+        return host_states, n_rows_in
+
+    def _execute_keyed(
+        self, first, codes, src, ra, key_encoders: list,
+        ctx: TaskContext, partition: int,
+    ) -> Iterator[pa.RecordBatch]:
+        """The keyed route, with :meth:`_execute_device`'s contract:
+        device-keyed aggregation (group ids assigned by the device sort,
+        no host hash encode) of ``first`` (``codes``: its key codes where
+        the route encoded them) and what is left in ``src``.  Late key
+        overflow, cardinality past the segment ceiling, or device OOM (the
+        keyed path buffers the stage input in HBM) leave to the CPU
+        operators."""
+        self.metrics.add("keyed_path", 1)
+        tail = _TrackingIter(src)
+        try:
+            host_states, groups, n_rows_in, aux = self._run_keyed(
+                [(first, codes)], tail, key_encoders, ctx
+            )
+            out_batches = list(
+                self._materialize(
+                    host_states, key_encoders, groups, n_rows_in,
+                    ctx, partition, aux=aux,
+                )
+            )
+        except (_CapacityExceeded, ExecutionError, _JaxRuntimeError) as e:
+            if isinstance(e, _JaxRuntimeError):
+                note_device_error(self.metrics, f"{self} (keyed)", e)
+            else:
+                self.metrics.add("tpu_fallback", 1)
+            if not tail.consumed:
+                # failed before touching the live source: replay the
+                # first batch + chain the tail (no re-scan)
+                return self._cpu_replay([first], tail)
+            if ra is not None:
+                ra.close()
+            return self.original
+        yield from out_batches
 
     def _kernel_args(
         self, batch, n: int, n_pad: int, build
@@ -1827,22 +1807,17 @@ class TpuStageExec(ExecutionPlan):
 
     def _keyed_fast_encoders(self, batch) -> Optional[list]:
         """Encoder set for the PRE-ENCODE keyed fast path, or None when
-        this stage/batch must take the legacy host-encode routing.
+        this stage/batch must take the host-encode routing.
 
-        The fast path fires when the stage is pinned keyed (median/corr
-        stages, or ``highcard_mode=device``), device encode is enabled,
-        and at least one key has a device encoding — the batch then
-        routes to :meth:`_run_keyed` with NO host group encode at all
+        :func:`choose_route` asks for a stage pinned keyed (median/corr
+        stages, or ``highcard_mode=device``) with device encode enabled;
+        where at least one key has a device encoding the batch then goes
+        to :meth:`_run_keyed` with NO host group encode at all
         (``key_encode_time_ns`` stays ~0; only dictionary keys still pay
         the host handoff per batch).  A first-batch range precheck sends
         identity keys the device cannot represent (negative values, or
-        past-i32 in x32 mode) back to the legacy routing, which lands on
-        the measured host fallbacks."""
-        cfg = self.config
-        if not cfg.tpu_device_encode:
-            return None
-        if not (self._needs_keyed or cfg.tpu_highcard_mode == "device"):
-            return None
+        past-i32 in x32 mode) back to the host-encode routing, which
+        lands on the measured host fallbacks."""
         from .bridge import arrow_to_numpy, device_key_encoder
 
         encs: list = []
@@ -2011,7 +1986,7 @@ class TpuStageExec(ExecutionPlan):
         fused = self.fused
         build = None
         if fused.join is not None:
-            # cached by the _execute_device run that raised _KeyedRoute
+            # cached by the _execute_device run that chose this route
             # (an empty build side returns there, before any routing)
             build = self._prepare_build(ctx)
         dense_join = build is not None and build[0] == "dense"
@@ -2517,7 +2492,7 @@ class TpuStageExec(ExecutionPlan):
         kmin = int(kv_sorted[0])
         span = int(kv_sorted[-1]) - kmin + 1
         if span <= _DENSE_JOIN_SPAN_CAP:
-            # Dense-key direct probe (BENCH_SUITE_r05 starjoin row:
+            # Dense-key direct probe (chip, round 5, star join:
             # searchsorted's log2(m) serial gather passes dominated 38s of
             # device time): scatter build rows into a [span]-slot table
             # once, probe with ONE gather.  Built device-side, so only the

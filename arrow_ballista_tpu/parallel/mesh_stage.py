@@ -42,17 +42,6 @@ from ..obs import trace
 _MESH_STEP_CACHE: dict = {}
 
 
-class _MeshKeyedRoute(Exception):
-    """Control flow: the gang's first batch showed groups ~ rows — run
-    the KEYED reduction per shard (every device concurrently) and merge
-    the [distinct]-sized results on host, instead of abandoning the
-    mesh for the sequential fallback."""
-
-    def __init__(self, n_dev: int):
-        super().__init__("mesh keyed high-cardinality")
-        self.n_dev = n_dev
-
-
 @functools.lru_cache(maxsize=1)
 def _libc_sched_getcpu():
     try:
@@ -154,6 +143,15 @@ def _in_partition_order(prepare, n_parts: int, width: int, stop):
         pool.shutdown(wait=True, cancel_futures=True)
 
 
+def _mesh_width(n_devices: int, ctx: TaskContext) -> int:
+    """Devices a mesh stage spreads over: the plan's count, else
+    ``ballista.mesh.devices``, else all; never more than there are."""
+    import jax
+
+    n_dev = n_devices or ctx.config.mesh_devices or len(jax.devices())
+    return max(1, min(n_dev, len(jax.devices())))
+
+
 def gang_eligible(plan: ExecutionPlan) -> bool:
     """Structural check (no kernel build, no device touch — safe on the
     scheduler): does this stage subtree fuse into a partial-aggregate
@@ -213,48 +211,53 @@ class MeshGangExec(ExecutionPlan):
         self, partition: int, ctx: TaskContext
     ) -> Iterator[pa.RecordBatch]:
         assert partition == 0, "gang stages are single-task"
-        from ..ops.stage_compiler import TpuStageExec, maybe_accelerate
-
         from ..errors import ExecutionError
         from ..ops.stage_compiler import (
+            Route,
+            TpuStageExec,
             _CapacityExceeded,
             _JaxRuntimeError,
+            maybe_accelerate,
             note_device_error,
         )
 
         inner = self.input
         if not isinstance(inner, TpuStageExec):
             inner = maybe_accelerate(inner, ctx.config)
+        # fully materialized before yielding: a capacity fallback must
+        # never follow already-emitted rows with a re-run
+        batches = None
         if (
             isinstance(inner, TpuStageExec)
             and ctx.config.tpu_enable
             and inner.fused.join is None
         ):
             try:
-                # fully materialized before yielding: a capacity fallback
-                # must never follow already-emitted rows with a re-run
-                try:
-                    batches = list(self._execute_mesh(inner, ctx))
-                except _MeshKeyedRoute as route:
-                    batches = list(
-                        self._execute_mesh_keyed(inner, ctx, route.n_dev)
-                    )
-                yield from batches
-                return
+                route, batches = self._execute_mesh(inner, ctx)
+                if route is Route.KEYED:
+                    # groups ~ rows: the KEYED reduction per shard (every
+                    # device concurrently), merged on host, keeps the mesh
+                    batches = list(self._execute_mesh_keyed(inner, ctx))
+                elif route is Route.CPU_HASH:
+                    # the sequential run hands each partition to the C++
+                    # hash aggregate
+                    self.metrics.add("mesh_fallback", 1)
             except (_CapacityExceeded, ExecutionError):
                 # group capacity overflow or a type that slipped past
                 # plan-time lowering: re-run sequentially (Cancelled is a
                 # BallistaError sibling and still propagates)
                 self.metrics.add("mesh_fallback", 1)
             except _JaxRuntimeError as e:
-                # a DEVICE/COMPILE failure (BENCH_SUITE_r05 h2o: the
+                # a DEVICE/COMPILE failure (chip, round 5, h2o: the
                 # gang's shard_map compile got its tpu_compile_helper
                 # SIGKILLed and the uncaught JaxRuntimeError killed the
                 # whole query): a gang stage degrades to the sequential
                 # path, loudly.  Only jax's runtime error is caught —
                 # blanket RuntimeError would hide real bugs.
                 note_device_error(self.metrics, str(self), e)
-        yield from self._execute_sequential(inner, ctx)
+        if batches is None:
+            batches = self._execute_sequential(inner, ctx)
+        yield from batches
 
     def _execute_sequential(
         self, inner: ExecutionPlan, ctx: TaskContext
@@ -262,8 +265,10 @@ class MeshGangExec(ExecutionPlan):
         for p in range(self.input.output_partitioning().n):
             yield from inner.execute(p, ctx)
 
-    def _execute_mesh(self, tpu, ctx: TaskContext) -> list[pa.RecordBatch]:
-        """All input partitions → one sharded fused kernel + ICI reduce.
+    def _execute_mesh(self, tpu, ctx: TaskContext) -> tuple:
+        """All input partitions → one sharded fused kernel + ICI reduce:
+        ``(Route.GID, the output)``, or ``(another route, None)`` where
+        the probe of the stage's first batch says to leave this path.
 
         A plain method (the caller materializes the result anyway), so the
         ``gang.*`` spans nest on the thread's span stack.  The stage's wall
@@ -277,12 +282,9 @@ class MeshGangExec(ExecutionPlan):
         threads that prepare partitions side by side (at width 1, this
         thread, inside its wait).  ``gang_cpu_ns`` is this thread's CPU
         time over the same wall."""
-        import jax
-
         clock = time.perf_counter_ns
         wall0, cpu0 = clock(), time.thread_time_ns()
-        n_dev = self.n_devices or ctx.config.mesh_devices or len(jax.devices())
-        n_dev = max(1, min(n_dev, len(jax.devices())))
+        n_dev = _mesh_width(self.n_devices, ctx)
         stage_span = trace.span("gang.stage", n_dev=n_dev)
         # one check a stage: no per-partition clock, cpu or attr work and
         # no span object when obs is off or the task is unsampled
@@ -296,7 +298,7 @@ class MeshGangExec(ExecutionPlan):
 
     def _mesh_phases(
         self, tpu, ctx: TaskContext, n_dev: int, stage_span, traced: bool
-    ) -> list[pa.RecordBatch]:
+    ) -> tuple:
         import jax
 
         from ..errors import Cancelled
@@ -305,6 +307,7 @@ class MeshGangExec(ExecutionPlan):
             _concat_batches, make_key_encoder, merge_key_codes,
         )
         from ..ops.groups import GroupTable
+        from ..ops.stage_compiler import Route
         from . import mesh as M
 
         clock = time.perf_counter_ns
@@ -448,11 +451,13 @@ class MeshGangExec(ExecutionPlan):
                 # hand over: the first wait.
                 t0 = clock()
                 try:
-                    self._probe_route(
-                        tpu, ctx, n_dev, opened, new_key_encoders()
+                    route = self._probe_route(
+                        tpu, ctx, opened, new_key_encoders()
                     )
                 finally:
                     add("gang_wait_ns", clock() - t0)
+                if route is not Route.GID:
+                    return route, None
             with contextlib.closing(
                 _in_partition_order(prepare, n_parts, width, stop)
             ) as prepared:
@@ -496,7 +501,7 @@ class MeshGangExec(ExecutionPlan):
                 _close(it)
 
         if n_rows == 0:
-            return self._timed_materialize(
+            return Route.GID, self._timed_materialize(
                 tpu, None, key_encoders, group_table, 0, ctx
             )
 
@@ -546,20 +551,21 @@ class MeshGangExec(ExecutionPlan):
         add("device_time_ns", t2 - t0)
         add("mesh_rows_in", n_rows)
         add("mesh_devices", n_dev)
-        return self._timed_materialize(
+        return Route.GID, self._timed_materialize(
             tpu, host_states, key_encoders, group_table, n_rows, ctx
         )
 
     def _probe_route(
-        self, tpu, ctx: TaskContext, n_dev: int, opened: dict,
-        key_encoders: list,
-    ) -> None:
+        self, tpu, ctx: TaskContext, opened: dict, key_encoders: list
+    ):
         """Open partitions in order up to the stage's first non-empty
         batch, encode that batch alone (against encoders of its own,
-        thrown away) and let :meth:`_check_highcard` choose the route
-        before anything else is read, encoded or uploaded.  What was
-        opened stays in ``opened`` for the partitions' workers."""
+        thrown away) and have ``choose_route`` say, before anything else
+        is read, encoded or uploaded, whether the stage stays on this
+        path (``Route.GID``; also where no partition holds a row).  What
+        was opened stays in ``opened`` for the partitions' workers."""
         from ..ops.groups import GroupTable
+        from ..ops.stage_compiler import FirstBatch, Route, choose_route
 
         fused = tpu.fused
         scan_ns = encode_ns = 0
@@ -582,10 +588,25 @@ class MeshGangExec(ExecutionPlan):
                         tpu._encode_groups(batch, key_encoders, table)
                     finally:
                         encode_ns = time.perf_counter_ns() - t0
-                    self._check_highcard(
-                        tpu, table.n_groups, batch.num_rows, n_dev
+                    # no join in a gang stage, and a stage that needs the
+                    # keyed path runs these phases like any other; the
+                    # keyed gang encodes its keys on the host and checks
+                    # batch by batch that they fit
+                    return choose_route(
+                        highcard_mode=tpu.config.tpu_highcard_mode,
+                        device_encode=False,
+                        grouped=True,
+                        needs_keyed=False,
+                        folded_join=False,
+                        max_capacity=tpu.max_capacity,
+                        first=FirstBatch(
+                            batch.num_rows,
+                            fast_encoders=lambda: False,
+                            keys_fit=lambda: True,
+                            groups=lambda: table.n_groups,
+                        ),
                     )
-                    return
+            return Route.GID
         finally:
             self.metrics.add("gang_scan_ns", scan_ns)
             self.metrics.add("key_encode_time_ns", encode_ns)
@@ -603,27 +624,8 @@ class MeshGangExec(ExecutionPlan):
         self.metrics.add("gang_materialize_ns", time.perf_counter_ns() - t0)
         return out
 
-    @staticmethod
-    def _check_highcard(tpu, n_groups: int, n: int, n_dev: int) -> None:
-        """The gang's first batch showed groups ~ rows: leave this path."""
-        from ..ops.stage_compiler import _highcard_detect, keyed_route_wanted
-
-        if not _highcard_detect(n_groups, n):
-            return
-        if keyed_route_wanted(tpu.config):
-            # per-shard KEYED reduction keeps the whole mesh busy
-            raise _MeshKeyedRoute(n_dev)
-        if tpu.config.tpu_highcard_mode != "gid":
-            # cpu platform / highcard_mode=cpu: the sequential fallback
-            # routes each partition to the C++ hash aggregate (the measured
-            # winner off-accelerator); 'gid' pins the gid-table gang path
-            # (capacity must fit)
-            from ..errors import ExecutionError
-
-            raise ExecutionError("high-cardinality gang stage")
-
     def _execute_mesh_keyed(
-        self, tpu, ctx: TaskContext, n_dev: int
+        self, tpu, ctx: TaskContext
     ) -> Iterator[pa.RecordBatch]:
         """High-cardinality gang: per-shard KEYED reduction on every
         device CONCURRENTLY (async dispatch of the single-chip keyed
@@ -650,6 +652,7 @@ class MeshGangExec(ExecutionPlan):
             if kind == "enc"
         ]
         n_keys = tpu._n_encoded_groups
+        n_dev = _mesh_width(self.n_devices, ctx)
         mesh = M.make_mesh(n_dev)
         devices = list(mesh.devices.flatten())
         per_dev_buf: list[list] = [[] for _ in devices]
@@ -860,8 +863,6 @@ class MeshRepartitionExec(ExecutionPlan):
         self, ctx: TaskContext
     ) -> Iterator[tuple[int, pa.RecordBatch]]:
         """Yield (output_partition, batch) pairs after the mesh exchange."""
-        import jax
-
         from ..errors import ExecutionError
         from ..ops import kernels as K
         from ..shuffle.execution_plans import partition_indices
@@ -869,8 +870,7 @@ class MeshRepartitionExec(ExecutionPlan):
 
         n_out = self.partitioning.n
         exprs = list(self.partitioning.exprs)
-        n_dev = self.n_devices or ctx.config.mesh_devices or len(jax.devices())
-        n_dev = max(1, min(n_dev, len(jax.devices())))
+        n_dev = _mesh_width(self.n_devices, ctx)
         add = self.metrics.add
         clock = time.perf_counter_ns
 

@@ -9,7 +9,7 @@ data refreshes) together with the doctor's findings and the measured
 latency; on the next submit of a matching plan it merges the learned knob
 overrides *beneath* the session's explicit settings.
 
-Safety rails, routing_table.json style — measured, never assumed:
+Safety rails — measured, never assumed:
 
 * a ``shadow_fraction`` of submits (deterministic per job id) runs at
   baseline so there is always a live control population;

@@ -11,7 +11,8 @@ Runs the measured configs beyond bench.py's default (q1 SF10 = config #2):
   showcase (ranking + running sum + lag on TpuWindowExec)
 
 Each config emits one JSON line (same shape as bench.py) and everything
-is appended to BENCH_SUITE_r05.json so the results ship with the repo.
+is appended to BENCH_SUITE_r05.json (or ``BENCH_SUITE_OUT``), which git
+ignores: the repo's record of speed is PERF_LEDGER.jsonl.
 
   plus shuffle data-plane micro-benches: shuffle_fetch_mb_per_sec
   (pipelined vs sequential reduce-side read), shuffle_write_mb_per_sec

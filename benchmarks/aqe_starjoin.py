@@ -22,8 +22,7 @@ emitted ``vs_baseline`` isolates exactly the re-planning effect:
 
 Both verify bit-identical results between the two runs (multiset of
 rows) and report the before/after reduce-task counts read from the
-job's AQE stage summary, so ``dev/bench_report.py`` can render the
-plan-shape trajectory.
+job's AQE stage summary.
 
 Usage: via ``bench_suite.py aqe`` (measurement) or ``dev/tier1.sh
 --bench-smoke`` (tiny-input compile/regression smoke via

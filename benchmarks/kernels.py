@@ -6,14 +6,12 @@ Counterpart of the reference's criterion→conbench micro-bench bridge
 kernels via cargo-criterion, this grids the TPU segment-reduction
 strategies directly — strategy × capacity × rows — plus the host-side
 group-encode paths they compete against, emitting one JSON line per
-cell.  This is the tuning tool for the ROUTING TABLE
-(``dev/analyze_grid.py --emit`` → ``ops/routing_table.json``: the
-high-cardinality detector, ``keyed_route_auto``, and the
-segment-algorithm bounds ``kernels.segment_algo`` reads).
+cell.  This is the tuning tool for the routing constants: the
+high-cardinality bounds ``stage_compiler.choose_route`` reads and the
+segment-algorithm bounds ``kernels.segment_algo`` reads.
 
 ``keyed_fused`` is the ISSUE-9 production shape — prep (with in-kernel
-key encode) and the packed-u64 sort in ONE jitted dispatch — and is
-what ``keyed_route_auto`` evidence should come from on a chip capture;
+key encode) and the packed-u64 sort in ONE jitted dispatch;
 ``keyed`` keeps the pre-fusion 3-dispatch form for comparison.
 
 Usage:
@@ -287,8 +285,8 @@ def main() -> None:
             for algo in algos:
                 if (
                     algo == "matmul"
-                    and (cap > K._matmul_max_cap()
-                         or rows * cap > K._matmul_max_elems())
+                    and (cap > K._MATMUL_MAX_CAP
+                         or rows * cap > K._MATMUL_MAX_ELEMS)
                 ):
                     continue  # outside the strategy's own applicability
                 try:

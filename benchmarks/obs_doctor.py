@@ -187,9 +187,8 @@ def run_obs_bench() -> dict:
         "attribution_pct_of_shuffle_leg": round(attribution_pct, 3),
         "job_wall_clock_ms": cp.get("wall_clock_ms"),
         "coverage": cp.get("coverage"),
-        # the measured job's category breakdown rides the record: the
-        # trajectory report (dev/bench_report.py) renders its dominant
-        # categories next to the overhead number
+        # the measured job's category breakdown rides the record, to be
+        # read next to the overhead number
         "breakdown": cp.get("breakdown"),
     }
 

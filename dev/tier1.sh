@@ -155,8 +155,6 @@ print(json.dumps({"bench_smoke": "whole_stage_fusion",
 EOF
   smoke_rc=$?
   [ $rc -eq 0 ] && rc=$smoke_rc
-  echo "--- benchmark trajectory (root BENCH_*.json snapshots) ---"
-  timeout -k 10 60 python dev/bench_report.py || true
 fi
 if [ "$CHAOS_SMOKE" = "1" ]; then
   echo "--- chaos smoke (bounded kill/drain + scheduler-kill soaks) ---"

@@ -1,7 +1,7 @@
 """Dense-key direct-probe device join: build keys whose span fits the
 slot cap are probed with ONE gather into a [span] table instead of
 searchsorted's log2(m) sequential gather passes (measured dominant on
-chip: BENCH_SUITE_r05 starjoin row).
+chip: round 5, star join).
 
 Results must match the CPU join oracle exactly for dense, offset,
 gappy, and wide-span (sorted-probe fallback) build keys.

@@ -1,6 +1,6 @@
 """Device/compiler failures must degrade, never kill a query.
 
-BENCH_SUITE_r05 h2o: the mesh gang's shard_map compile got its
+Round 5, h2o on the chip: the mesh gang's shard_map compile got its
 tpu_compile_helper SIGKILLed and the uncaught JaxRuntimeError destroyed
 the whole run.  These tests inject JaxRuntimeError into the device
 stage and the mesh gang and assert the query still returns the CPU

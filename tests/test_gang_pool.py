@@ -462,8 +462,8 @@ def test_highcard_exit_reads_one_batch_and_starts_no_worker(monkeypatch, width):
     ctx.register_table("t", table)
     plan = ctx.sql("select g, sum(v) as s from t group by g").physical_plan()
     (gang,) = _find(plan, MeshGangExec)
-    with pytest.raises(mesh_stage._MeshKeyedRoute):
-        gang._execute_mesh(gang.input, TaskContext(config=cfg))
+    route, out = gang._execute_mesh(gang.input, TaskContext(config=cfg))
+    assert route is SC.Route.KEYED and out is None
     # the empty partition's batch, then the stage's first non-empty one
     assert pulled == [(0, 0), (1, 0)]
     m = gang.metrics.to_dict()

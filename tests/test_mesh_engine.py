@@ -626,14 +626,16 @@ def test_gang_cancel_between_batches_raises_before_the_partition_uploads(
     plan = ctx.sql(RAGGED_SQL).physical_plan()
     (gang,) = _find(plan, MeshGangExec)
 
+    from arrow_ballista_tpu.ops import stage_compiler as SC
+
     cancel = threading.Event()
-    real_check = MeshGangExec._check_highcard
+    real_choose = SC.choose_route
 
-    def check_highcard(*a, **kw):
+    def choose_route(**kw):
         cancel.set()
-        return real_check(*a, **kw)
+        return real_choose(**kw)
 
-    monkeypatch.setattr(MeshGangExec, "_check_highcard", staticmethod(check_highcard))
+    monkeypatch.setattr(SC, "choose_route", choose_route)
     spy = _UploadSpy(monkeypatch)
     with pytest.raises(Cancelled):
         list(gang.execute(0, TaskContext(config=cfg, cancel_event=cancel)))

@@ -1,173 +1,129 @@
-"""Generated routing table: emit schema pin + loader semantics (ISSUE 9).
+"""How an aggregate stage runs: the routing constants and the one function
+that reads them (``stage_compiler.choose_route``).
 
-Routing constants in ``ops/`` must cite a measured artifact: the table
-``dev/analyze_grid.py --emit`` writes and ``ops/routing.py`` loads.  The
-emit SCHEMA is pinned here so regenerating from a new KERNELBENCH grid
-cannot silently change shape, and the no-artifact defaults are pinned to
-the exact constants that used to live in the code — behavior with no
-artifact present must be unchanged.
+The constants are pinned to the values the device paths were measured
+with.  The decision is a table: each row gives the facts of a stage and
+of its first batch, the route they have to give, and the questions about
+the first batch (each costs host work) that may be asked on the way.  No
+row touches a device.
 """
-
-import json
 
 import pytest
 
-from arrow_ballista_tpu.ops import routing
-
-from dev.analyze_grid import emit_routing_table
-
-
-GRID_ROWS = [
-    # matmul wins this cell → crossover evidence
-    {"device_platform": "tpu", "bench": "segment_reduce", "algo": "matmul",
-     "rows": 1_000_000, "capacity": 4096, "rows_per_sec": 300e6},
-    {"device_platform": "tpu", "bench": "segment_reduce", "algo": "sort",
-     "rows": 1_000_000, "capacity": 4096, "rows_per_sec": 50e6},
-    {"device_platform": "tpu", "bench": "segment_reduce", "algo": "scatter",
-     "rows": 1_000_000, "capacity": 4096, "rows_per_sec": 40e6},
-    # high-cardinality cell where keyed WINS → keyed_route_auto evidence
-    {"device_platform": "tpu", "bench": "segment_reduce", "algo": "keyed",
-     "rows": 1_000_000, "capacity": 1 << 20, "rows_per_sec": 80e6},
-    {"device_platform": "tpu", "bench": "segment_reduce", "algo": "sort",
-     "rows": 1_000_000, "capacity": 1 << 20, "rows_per_sec": 30e6},
-    # cpu platform: keyed loses its high-cardinality cell
-    {"device_platform": "cpu", "bench": "segment_reduce", "algo": "keyed",
-     "rows": 1_000_000, "capacity": 1 << 20, "rows_per_sec": 2e6},
-    {"device_platform": "cpu", "bench": "segment_reduce", "algo": "scatter",
-     "rows": 1_000_000, "capacity": 1 << 20, "rows_per_sec": 140e6},
-]
-
-
-def test_emit_schema_is_pinned():
-    doc = emit_routing_table(GRID_ROWS, ["KERNELBENCH_test.json"])
-    # top-level shape: exactly these keys
-    assert sorted(doc) == ["generated_by", "inputs", "platforms", "schema"]
-    assert doc["schema"] == "ballista.routing/v1"
-    assert doc["inputs"] == ["KERNELBENCH_test.json"]
-    assert sorted(doc["platforms"]) == ["cpu", "tpu"]
-    for vals in doc["platforms"].values():
-        # per-platform shape: the routing fields + per-field evidence
-        assert sorted(vals) == sorted(
-            routing.PLATFORM_FIELDS + ("evidence",)
-        )
-        assert sorted(vals["evidence"]) == sorted(routing.PLATFORM_FIELDS)
-        assert isinstance(vals["matmul_max_cap"], int)
-        assert isinstance(vals["matmul_max_elems"], int)
-        assert isinstance(vals["highcard_min_groups"], int)
-        assert isinstance(vals["highcard_ratio"], float)
-        assert isinstance(vals["keyed_route_auto"], bool)
-    # the document round-trips through JSON unchanged
-    assert json.loads(json.dumps(doc)) == doc
-
-
-def test_emit_derives_measured_values():
-    doc = emit_routing_table(GRID_ROWS, ["g.json"])
-    tpu = doc["platforms"]["tpu"]
-    assert tpu["matmul_max_cap"] == 4096
-    assert tpu["matmul_max_elems"] == 1_000_000 * 4096
-    assert tpu["keyed_route_auto"] is True
-    cpu = doc["platforms"]["cpu"]
-    # matmul never won on cpu → builtin default retained
-    assert cpu["matmul_max_cap"] == routing._DEFAULTS["matmul_max_cap"]
-    assert cpu["keyed_route_auto"] is False
+from arrow_ballista_tpu.ops import kernels as K
+from arrow_ballista_tpu.ops import stage_compiler as SC
+from arrow_ballista_tpu.ops.stage_compiler import FirstBatch, Route, choose_route
 
 
 def test_builtin_defaults_are_the_pre_table_constants():
-    """No artifact → the exact constants that used to be hand-edited
-    literals in ops/kernels.py and ops/stage_compiler.py."""
-    d = routing._DEFAULTS
-    assert d["matmul_max_cap"] == 8192
-    assert d["matmul_max_elems"] == 1 << 36
-    assert d["highcard_min_groups"] == 1 << 16
-    assert d["highcard_ratio"] == 0.05
-    assert d["keyed_route_auto"] is False
+    """The seven values the routing table used to hand out (six live on;
+    ``auto`` never routes keyed, which the decision table below pins)."""
+    assert K._MATMUL_MAX_CAP == 8192
+    assert K._MATMUL_MAX_ELEMS == 1 << 36
+    assert SC._HIGHCARD_MIN_GROUPS == 1 << 16
+    assert SC._HIGHCARD_RATIO == 0.05
+    assert SC._FUSION_MAX_OPS == 8
+    assert SC._FUSION_MIN_ROWS == 2048
+    # the two matmul bounds stay part of every compiled-kernel cache key
+    assert K.algo_cache_token()[2:] == (8192, 1 << 36)
 
 
-def test_loader_roundtrip_and_fallbacks(tmp_path, monkeypatch):
-    doc = emit_routing_table(GRID_ROWS, ["g.json"])
-    p = tmp_path / "routing_table.json"
-    p.write_text(json.dumps(doc))
-    try:
-        routing.reload(str(p))
-        assert "cpu" in routing._TABLES and "tpu" in routing._TABLES
-        assert routing._TABLES["tpu"].matmul_max_cap == 4096
-        assert routing._TABLES["tpu"].keyed_route_auto is True
-        assert routing._TABLES["cpu"].keyed_route_auto is False
-        # a platform missing from the artifact → builtin defaults
-        assert routing._TABLES.get("gpu") is None
+MAX_CAP = 1 << 21  # ballista.tpu.max_capacity's default
+STAGE = dict(
+    highcard_mode="auto", device_encode=True, grouped=True,
+    needs_keyed=False, folded_join=False, max_capacity=MAX_CAP,
+)
+# what a gang stage's probe passes: no join, host-encoded keys taken as
+# fitting, the batch already in a gid table
+GANG = dict(STAGE, device_encode=False)
+# a first batch of 1M rows; "groups ~ rows" is 100,000 groups of it
+FIRST = dict(rows=1_000_000, fast=False, fit=True, groups=100_000)
+NO_BATCH = None
 
-        # unreadable / wrong-schema artifacts degrade to builtins
-        bad = tmp_path / "bad.json"
-        bad.write_text("{not json")
-        routing.reload(str(bad))
-        assert routing._TABLES == {}
-        wrong = tmp_path / "wrong.json"
-        wrong.write_text(json.dumps({"schema": "other/v9", "platforms": {}}))
-        routing.reload(str(wrong))
-        assert routing._TABLES == {}
+CASES = [
+    # id, stage facts, first-batch facts, route, questions asked in order
+    ("small-input", dict(small_input=True), NO_BATCH, Route.CPU_SMALL, []),
+    ("ungrouped", dict(grouped=False), NO_BATCH, Route.GID, []),
+    ("ungrouped-with-batch", dict(grouped=False), {}, Route.GID, []),
+    ("grouped-before-its-first-batch", {}, NO_BATCH, None, []),
+    ("fast-encoders-median", dict(needs_keyed=True), dict(fast=True),
+     Route.KEYED, ["fast"]),
+    ("fast-encoders-mode-device", dict(highcard_mode="device"),
+     dict(fast=True, groups=6), Route.KEYED, ["fast"]),
+    ("device-encode-off-never-asks", dict(highcard_mode="device", device_encode=False),
+     dict(fast=True, groups=6), Route.GID, ["groups"]),
+    ("needs-keyed-keys-fit", dict(needs_keyed=True), dict(groups=6),
+     Route.KEYED, ["fast", "fit"]),
+    ("needs-keyed-keys-do-not-fit", dict(needs_keyed=True), dict(fit=False),
+     Route.CPU_HASH, ["fast", "fit"]),
+    ("low-cardinality", {}, dict(groups=6), Route.GID, ["groups"]),
+    ("at-the-group-bound", {}, dict(groups=1 << 16), Route.GID, ["groups"]),
+    ("many-groups-under-the-ratio", {}, dict(rows=10_000_000, groups=400_000),
+     Route.GID, ["groups"]),
+    ("low-cardinality-mode-device-no-device-key", dict(highcard_mode="device"),
+     dict(groups=6), Route.GID, ["fast", "groups"]),
+    ("table-overflow-on-batch-one", {}, dict(groups=None), Route.CPU_HASH,
+     ["groups"]),
+    ("table-overflow-on-batch-one-join", dict(folded_join=True),
+     dict(groups=None), Route.NOJOIN, ["groups"]),
+    ("groups~rows-auto", {}, {}, Route.CPU_HASH, ["groups"]),
+    ("groups~rows-cpu", dict(highcard_mode="cpu"), {}, Route.CPU_HASH, ["groups"]),
+    ("groups~rows-device-keys-fit", dict(highcard_mode="device"), {},
+     Route.KEYED, ["fast", "groups", "fit"]),
+    ("groups~rows-device-keys-do-not-fit", dict(highcard_mode="device"),
+     dict(fit=False), Route.CPU_HASH, ["fast", "groups", "fit"]),
+    ("table-overflow-device-keys-fit", dict(highcard_mode="device"),
+     dict(groups=None), Route.KEYED, ["fast", "groups", "fit"]),
+    ("groups~rows-gid", dict(highcard_mode="gid"), {}, Route.GID, ["groups"]),
+    ("table-overflow-gid", dict(highcard_mode="gid"), dict(groups=None),
+     Route.CPU_HASH, ["groups"]),
+    # cell 3's stage 5: a first batch of 1.52M rows with several hundred
+    # thousand order keys, join folded, the table at half its ceiling or under
+    ("q3-folded-join-under-half-the-ceiling", dict(folded_join=True),
+     dict(rows=1_520_000, groups=700_000), Route.GID, ["groups"]),
+    ("folded-join-at-half-the-ceiling", dict(folded_join=True),
+     dict(rows=4_000_000, groups=MAX_CAP // 2), Route.GID, ["groups"]),
+    ("folded-join-over-half-the-ceiling", dict(folded_join=True),
+     dict(rows=4_000_000, groups=MAX_CAP // 2 + 1), Route.NOJOIN, ["groups"]),
+    ("folded-join-device-keys-do-not-fit", dict(folded_join=True, highcard_mode="device"),
+     dict(fit=False), Route.GID, ["fast", "groups", "fit"]),
+    ("gang-low-cardinality", GANG, dict(groups=6), Route.GID, ["groups"]),
+    ("gang-groups~rows-gid-stays", dict(GANG, highcard_mode="gid"), {},
+     Route.GID, ["groups"]),
+    ("gang-groups~rows-device-mesh-keyed", dict(GANG, highcard_mode="device"), {},
+     Route.KEYED, ["groups", "fit"]),
+    ("gang-groups~rows-auto-mesh-fallback", GANG, {}, Route.CPU_HASH, ["groups"]),
+]
 
-        # empty env var disables loading entirely
-        monkeypatch.setenv("BALLISTA_ROUTING_TABLE", "")
-        routing.reload()
-        assert routing._TABLES == {}
-    finally:
-        monkeypatch.delenv("BALLISTA_ROUTING_TABLE", raising=False)
-        routing.reload()
 
+@pytest.mark.parametrize(
+    "stage,first,route,asks", [c[1:] for c in CASES], ids=[c[0] for c in CASES]
+)
+def test_choose_route(stage, first, route, asks):
+    asked = []
 
-def test_keyed_route_auto_steers_auto_mode(tmp_path):
-    """'auto' highcard mode consults the table: a platform whose grid
-    shows the keyed reduction winning routes groups~rows keyed."""
-    from arrow_ballista_tpu.config import BallistaConfig
-    from arrow_ballista_tpu.ops.stage_compiler import keyed_route_wanted
+    def answer(name, value):
+        def ask():
+            asked.append(name)
+            return value
 
-    auto_cfg = BallistaConfig({"ballista.tpu.highcard_mode": "auto"})
-    try:
-        assert keyed_route_wanted(auto_cfg) is False  # builtin default
-        rows = [
-            {"device_platform": "cpu", "bench": "segment_reduce",
-             "algo": "keyed", "rows": 1_000_000, "capacity": 1 << 20,
-             "rows_per_sec": 100e6},
-            {"device_platform": "cpu", "bench": "segment_reduce",
-             "algo": "scatter", "rows": 1_000_000, "capacity": 1 << 20,
-             "rows_per_sec": 10e6},
-        ]
-        p = tmp_path / "t.json"
-        p.write_text(json.dumps(emit_routing_table(rows, ["g.json"])))
-        routing.reload(str(p))
-        assert keyed_route_wanted(auto_cfg) is True
-        # explicit pins always beat the table
-        assert keyed_route_wanted(
-            BallistaConfig({"ballista.tpu.highcard_mode": "cpu"})
-        ) is False
-    finally:
-        routing.reload()
+        return ask
 
-
-def test_shipped_artifact_matches_loader_and_grid():
-    """The committed artifact is a faithful emit over the checked-in
-    KERNELBENCH grid and loads cleanly."""
-    import os
-
-    path = routing.default_artifact_path()
-    assert os.path.exists(path), (
-        "ops/routing_table.json missing — regenerate with "
-        "python dev/analyze_grid.py KERNELBENCH_r05.json --emit "
-        "arrow_ballista_tpu/ops/routing_table.json"
-    )
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["schema"] == routing.SCHEMA
-    from dev.analyze_grid import load
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    inputs = [os.path.join(repo, p) for p in doc["inputs"]]
-    if all(os.path.exists(p) for p in inputs):
-        regen = emit_routing_table(load(inputs), inputs)
-        assert regen["platforms"] == doc["platforms"], (
-            "artifact drifted from its grid — regenerate via --emit"
+    if first is not None:
+        f = dict(FIRST, **first)
+        first = FirstBatch(
+            f["rows"], answer("fast", f["fast"]), answer("fit", f["fit"]),
+            answer("groups", f["groups"]),
         )
-    # the committed artifact must not flip cpu-platform routing away
-    # from the measured defaults (keyed loses on cpu in r05)
-    if "cpu" in doc["platforms"]:
-        assert doc["platforms"]["cpu"]["keyed_route_auto"] is False
+    assert choose_route(**dict(STAGE, **stage), first=first) is route
+    assert asked == asks
+
+
+def test_choose_route_reads_the_bounds_when_called(monkeypatch):
+    """Tests route small fixtures by setting the two bounds on the module."""
+    first = FirstBatch(1000, lambda: False, lambda: True, lambda: 100)
+    assert choose_route(**STAGE, first=first) is Route.GID
+    monkeypatch.setattr(SC, "_HIGHCARD_MIN_GROUPS", 16)
+    assert choose_route(**STAGE, first=first) is Route.CPU_HASH
+    monkeypatch.setattr(SC, "_HIGHCARD_RATIO", 0.5)
+    assert choose_route(**STAGE, first=first) is Route.GID
