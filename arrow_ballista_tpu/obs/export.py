@@ -331,14 +331,22 @@ _GANG_PHASES = (
 
 # the join / exchange / per-partition device path's counters -> their names
 # in a profile row's "tpu" block: MeshRepartitionExec's exchange (rows and
-# bytes handed to the exchange program, padding included; encode, device
-# and decode are host timers that sum to the exchange's part of the task),
-# then TpuStageExec's folded join and its padding.
+# bytes handed to the exchange program, padding included; wait, encode,
+# device and decode are the task thread's host timers and sum to the
+# exchange's part of the task; pull, hash and convert are summed over the
+# exchange_workers threads that prepare input partitions side by side,
+# inside the task thread's wait), then TpuStageExec's folded join and its
+# padding.
 _EXCHANGE_COUNTERS = (
     ("mesh_exchange_rows", "exchange_rows"),
     ("mesh_exchange_padded_rows", "exchange_padded_rows"),
     ("mesh_exchange_bytes", "exchange_bytes"),
     ("mesh_exchange_recv_bytes", "exchange_recv_bytes"),
+    ("exchange_workers", "exchange_workers"),
+    ("exchange_wait_ns", "exchange_wait_ms"),
+    ("exchange_pull_ns", "exchange_pull_ms"),
+    ("repart_time_ns", "exchange_hash_ms"),
+    ("exchange_convert_ns", "exchange_convert_ms"),
     ("exchange_encode_ns", "exchange_encode_ms"),
     ("device_time_ns", "exchange_device_ms"),
     ("exchange_decode_ns", "exchange_decode_ms"),

@@ -187,43 +187,28 @@ def ici_batch_exchange(mesh: Mesh, n_cols: int, capacity: int):
     return fn
 
 
-class BatchExchanger:
-    """Schema-aware host bridge around :func:`ici_batch_exchange`.
+class ExchangeLayout:
+    """The host side of the exchange's bridge, a property of the schema
+    alone (no mesh, no capacity): which device columns each field becomes
+    (value + validity per field; strings as shared dictionary codes; i64
+    as exact lo/hi i32 pairs when the device dtype mode is x32) and the
+    string fields' dictionaries.  ``encoders`` hands in dictionaries that
+    already exist (field index -> DictEncoder, one for each string field)
+    in place of new ones."""
 
-    Turns RecordBatches into device columns (value + validity per field;
-    strings as shared dictionary codes; i64 as exact lo/hi i32 pairs when
-    the device dtype mode is x32), runs the on-mesh exchange, and
-    reassembles per-destination RecordBatches.
-    """
-
-    def __init__(self, mesh: Mesh, schema, capacity: int, share_from=None):
+    def __init__(self, schema, encoders: Optional[dict] = None):
         import pyarrow as pa
 
         from ..ops import kernels as K
-        from ..ops.bridge import DictEncoder
 
-        self.mesh = mesh
         self.schema = schema
-        self.capacity = capacity
-        if share_from is not None:
-            # capacity retry: the layout/encoders (and any columns already
-            # produced by to_columns) are schema-properties, capacity only
-            # parameterizes the jitted exchange — share them
-            self._x32 = share_from._x32
-            self.layout = share_from.layout
-            self.encoders = share_from.encoders
-            self.n_cols = share_from.n_cols
-            self._fn = ici_batch_exchange(mesh, self.n_cols, capacity)
-            return
         self._x32 = K.precision_mode() == "x32"
         # per-field device layout: "num" (one array), "dict" (codes),
         # "i64pair" (lo/hi split — exchange-exact without device i64)
         self.layout: list[tuple] = []
-        self.encoders: dict[int, DictEncoder] = {}
         for i, f in enumerate(schema):
             t = f.type
             if pa.types.is_string(t) or pa.types.is_large_string(t):
-                self.encoders[i] = DictEncoder()
                 self.layout.append(("dict", i))
             elif self._x32 and (
                 pa.types.is_int64(t)
@@ -239,23 +224,40 @@ class BatchExchanger:
                 self.layout.append(("i64pair", i))
             else:
                 self.layout.append(("num", i))
+        self.encoders = self.new_encoders() if encoders is None else encoders
+        if set(self.encoders) != {i for kind, i in self.layout if kind == "dict"}:
+            raise ValueError("encoders do not match the schema's string fields")
         self.n_cols = sum(
             2 if kind == "i64pair" else 1 for kind, _ in self.layout
         ) + len(self.layout)  # +1 validity per field
-        self._fn = ici_batch_exchange(mesh, self.n_cols, capacity)
 
     # ------------------------------------------------------------- host →
-    def to_columns(self, batch) -> list[np.ndarray]:
-        """Flatten one RecordBatch into the exchange's column list."""
+    def new_encoders(self) -> dict:
+        """Empty dictionaries, one for each string field: the layout's
+        own, or one input partition's so that partitions can be flattened
+        side by side (``flatten`` on a worker, ``adopt_codes`` at the
+        hand-over)."""
+        from ..ops.bridge import DictEncoder
+
+        return {i: DictEncoder() for kind, i in self.layout if kind == "dict"}
+
+    def flatten(self, batch, encoders: dict) -> list[np.ndarray]:
+        """One RecordBatch of the layout's schema as the exchange's column
+        list, string codes against ``encoders``."""
         import pyarrow.compute as pc
 
         from ..ops.bridge import arrow_to_numpy
 
+        if batch.num_columns != len(self.layout):
+            raise ValueError(
+                f"batch has {batch.num_columns} columns, "
+                f"the layout {len(self.layout)}"
+            )
         cols: list[np.ndarray] = []
         for kind, i in self.layout:
             arr = batch.column(i)
             if kind == "dict":
-                codes = self.encoders[i].encode(arr)
+                codes = encoders[i].encode(arr)
                 validity = (
                     np.asarray(pc.is_valid(arr))
                     if arr.null_count
@@ -282,6 +284,42 @@ class BatchExchanger:
                     cols.append(values)
             cols.append(validity)
         return cols
+
+    def adopt_codes(self, cols: list, encoders: dict) -> None:
+        """Rewrite, in ``cols`` (a ``flatten`` against ``encoders``), the
+        string codes into the layout's own.  Called partition by partition
+        in order it builds the dictionaries that one encoder builds when
+        fed the partitions one after the other (``DictEncoder.merge``)."""
+        ci = 0
+        for kind, i in self.layout:
+            if kind == "dict":
+                remap = self.encoders[i].merge(encoders[i]).astype(np.int32)
+                cols[ci] = remap[cols[ci]]
+            ci += 3 if kind == "i64pair" else 2
+
+
+class BatchExchanger(ExchangeLayout):
+    """Schema-aware host bridge around :func:`ici_batch_exchange`.
+
+    Turns RecordBatches into device columns (:class:`ExchangeLayout`),
+    runs the on-mesh exchange, and reassembles per-destination
+    RecordBatches.  ``share_from`` is an exchanger or a layout with the
+    same string fields (a capacity retry; the layout of the input a
+    caller flattened before it knew the capacity): its dictionaries, and
+    so the columns already flattened against them, stay good.
+    """
+
+    def __init__(self, mesh: Mesh, schema, capacity: int, share_from=None):
+        super().__init__(
+            schema, None if share_from is None else share_from.encoders
+        )
+        self.mesh = mesh
+        self.capacity = capacity
+        self._fn = ici_batch_exchange(mesh, self.n_cols, capacity)
+
+    def to_columns(self, batch) -> list[np.ndarray]:
+        """Flatten one RecordBatch into the exchange's column list."""
+        return self.flatten(batch, self.encoders)
 
     # ------------------------------------------------------------ exchange
     def exchange(self, dest: np.ndarray, valid: np.ndarray, cols):

@@ -770,6 +770,55 @@ class MeshGangExec(ExecutionPlan):
         )
 
 
+@dataclass
+class _ExchangePart:
+    """What a worker hands back for one input partition of an exchange:
+    nothing in it is shared with another partition's."""
+
+    rows: int = 0
+    cols: list = field(default_factory=list)  # numpy, the exchange's order
+    encoders: dict = field(default_factory=dict)  # field -> its own DictEncoder
+
+
+# A worker coalesces at most this many bytes of Arrow batches with a string
+# column into one batch before it flattens them: a string column's int32
+# offsets end at 2 GiB, and past them ``Table.combine_chunks`` leaves
+# several chunks, of which ``_concat_batches`` returns the first.  A
+# partition under it, or with no string column, is coalesced once.
+_COALESCE_BYTES = 1 << 30
+
+
+def _byte_groups(batches: list, limit: Optional[int]) -> Iterator[list]:
+    """``batches`` in order, cut into runs of at most ``limit`` bytes (a
+    batch that is larger alone is a run of its own); one run where
+    ``limit`` is None."""
+    group: list = []
+    size = 0
+    for b in batches:
+        if limit is not None:
+            if group and size + b.nbytes > limit:
+                yield group
+                group, size = [], 0
+            size += b.nbytes
+        group.append(b)
+    if group:
+        yield group
+
+
+def _holds_device_stage(plan: ExecutionPlan) -> bool:
+    """Does this subtree hold an operator that runs on the device?"""
+    from ..ops.stage_compiler import TpuStageExec
+    from ..ops.window_compiler import TpuWindowExec
+
+    stack = [plan]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, (TpuStageExec, TpuWindowExec)):
+            return True
+        stack.extend(node.children())
+    return False
+
+
 class MeshExchangeError(Exception):
     """Exchange-specific failure (capacity ceiling, untransferable column):
     the owning writer falls back to the classic hash-split.  Deliberately
@@ -805,6 +854,17 @@ class MeshRepartitionExec(ExecutionPlan):
     (:class:`..parallel.mesh.BatchExchanger`), and hands the owning
     :class:`ShuffleWriterExec` already-partitioned output batches — zero
     hash-split files, one memory write per output partition.
+
+    The host side works a partition at a time: a small pool of workers
+    (``_gang_width``; inline where the input holds a device stage) pulls
+    the input partitions side by side, each coalescing, hashing and
+    flattening its own partition once; the task thread takes them in
+    partition order, concatenates them and makes the one device call, so
+    the rows go up in the order a sequential read would give.  The whole
+    input is buffered on the host, so ``mesh.exchange_max_rows`` bounds
+    it: the workers sum the rows pulled at every batch and all stop
+    pulling when the sum passes the ceiling (MeshExchangeError → writer
+    fallback).
 
     ``output_partitioning()`` is 1 so the scheduler sees an ordinary
     one-task stage (same trick as :class:`MeshGangExec`); recovery and
@@ -859,47 +919,203 @@ class MeshRepartitionExec(ExecutionPlan):
             yield from self.input.execute(p, ctx)
 
     # -------------------------------------------------------- exchanged
-    def execute_exchanged(
-        self, ctx: TaskContext
-    ) -> Iterator[tuple[int, pa.RecordBatch]]:
-        """Yield (output_partition, batch) pairs after the mesh exchange."""
-        from ..errors import ExecutionError
-        from ..ops import kernels as K
-        from ..shuffle.execution_plans import partition_indices
-        from . import mesh as M
+    def _prepare_partitions(self, ctx: TaskContext, layout) -> tuple[list, int]:
+        """The stage's input as the exchange's columns: a column list for
+        each non-empty input partition, in partition order (the input's
+        fields as ``layout`` lays them out, then the destination partition
+        and its validity), and the ns this thread spent mapping
+        dictionaries.
 
-        n_out = self.partitioning.n
-        exprs = list(self.partitioning.exprs)
-        n_dev = _mesh_width(self.n_devices, ctx)
+        The partition is the unit of the host work.  PREPARE (a worker, up
+        to ``width`` partitions side by side): pull the partition's
+        batches from the input (scan, decode and filter run inside that
+        pull), coalesce them once at the Arrow level, hash the
+        destinations once, flatten to numpy once, strings against the
+        partition's OWN dictionaries.  Workers share nothing mutable but
+        the rows pulled so far, a slot a partition; the width is the gang
+        stage's rule, and 1 (inline) where the input holds a device stage:
+        running that stage's partitions side by side is its own question
+        (they share one instance's join build and gid table).
+        HAND OVER (this thread, in partition order): the row ceiling on
+        the running count, and the partition's dictionaries mapped into
+        the layout's.  What is buffered: every prepared partition's numpy
+        columns (the stage's whole input, as before) and, inside a worker,
+        its partition's Arrow batches until they are flattened; the
+        workers sum the rows pulled at every batch and all stop pulling
+        when the sum passes the ceiling, so no more than the ceiling and a
+        batch or two a worker is ever held."""
+        from ..errors import Cancelled, ExecutionError
+        from ..ops.bridge import _concat_batches
+        from ..shuffle.execution_plans import partition_indices
+
         add = self.metrics.add
         clock = time.perf_counter_ns
-
+        n_out = self.partitioning.n
+        exprs = list(self.partitioning.exprs)
+        n_parts = self.input.output_partitioning().n
+        width = (
+            1 if _holds_device_stage(self.input)
+            else _gang_width(ctx, n_parts)
+        )
+        add("exchange_workers", width)
         # the exchange buffers the stage input in host memory (~2x resident
         # plus device staging): a row ceiling keeps huge shuffles on the
         # streaming hash-split path instead of OOMing this task
         max_rows = ctx.config.mesh_exchange_max_rows
+        # rows pulled so far, a slot a partition: each is written by its own
+        # worker alone (no lock for 18 threads to queue behind), and the
+        # worker whose write comes last sees every other in its sum
+        pulled = [0] * n_parts
+        # the exchange.* spans hang under the task's current span
+        stage_ctx = trace.current_context() if trace.is_enabled() else None
+        traced = stage_ctx is not None
+        stop = threading.Event()
+
+        def over_ceiling(rows: int) -> MeshExchangeError:
+            return MeshExchangeError(
+                f"stage exceeds mesh.exchange_max_rows ({rows} > {max_rows})"
+            )
+
+        def check_stop() -> None:
+            if stop.is_set():
+                # stopped for the ceiling (any worker's batch took the sum
+                # past it): every partition still in the making says so,
+                # whichever one's turn comes first
+                if sum(pulled) > max_rows:
+                    raise over_ceiling(sum(pulled))
+                raise Cancelled("exchange stopped")
+
+        def prepare(p: int) -> _ExchangePart:
+            part_span = trace.NOOP
+            if traced:
+                part_span = trace.span(
+                    "exchange.partition", parent=stage_ctx, partition=p,
+                    worker=threading.current_thread().name,
+                )
+            pull_ns = hash_ns = convert_ns = 0
+            out = _ExchangePart()
+            batches: list[pa.RecordBatch] = []
+            it = None
+            with part_span:
+                try:
+                    check_stop()
+                    it = iter(self.input.execute(p, ctx))
+                    while True:
+                        batch, ns = _pull(it)
+                        pull_ns += ns
+                        if batch is None:
+                            break
+                        ctx.check_cancelled()
+                        check_stop()
+                        if batch.num_rows == 0:
+                            continue
+                        batches.append(batch)
+                        out.rows += batch.num_rows
+                        pulled[p] = out.rows
+                        if sum(pulled) > max_rows:
+                            stop.set()
+                            check_stop()
+                    out.encoders = layout.new_encoders()
+                    flat: list[list] = []
+                    # only a string column has offsets to overflow
+                    limit = _COALESCE_BYTES if out.encoders else None
+                    for group in _byte_groups(batches, limit):
+                        t0 = clock()
+                        whole = _concat_batches(group)
+                        t1 = clock()
+                        dest = partition_indices(whole, exprs, n_out).astype(
+                            np.int32
+                        )
+                        t2 = clock()
+                        try:
+                            cols = layout.flatten(whole, out.encoders)
+                        except ExecutionError as e:
+                            # column didn't cross the bridge (dtype slipped
+                            # past the plan-time check): an exchange
+                            # failure, not a plan failure
+                            raise MeshExchangeError(str(e)) from e
+                        flat.append(cols + [dest, np.ones(len(dest), dtype=bool)])
+                        hash_ns += t2 - t1
+                        convert_ns += (t1 - t0) + (clock() - t2)
+                    if len(flat) > 1:
+                        t0 = clock()
+                        flat = [[np.concatenate(one) for one in zip(*flat)]]
+                        convert_ns += clock() - t0
+                    if flat:
+                        out.cols = flat[0]
+                    return out
+                finally:
+                    _close(it)
+                    add("exchange_pull_ns", pull_ns)
+                    add("repart_time_ns", hash_ns)
+                    add("exchange_convert_ns", convert_ns)
+                    if traced:
+                        for k, v in (
+                            ("rows", out.rows), ("batches", len(batches)),
+                            ("pull_ns", pull_ns), ("hash_ns", hash_ns),
+                            ("convert_ns", convert_ns),
+                        ):
+                            part_span.set_attr(k, v)
+
+        prepared: list = []
+        rows_seen = merge_ns = 0
+        with contextlib.closing(
+            _in_partition_order(prepare, n_parts, width, stop)
+        ) as in_order:
+            for p in range(n_parts):
+                hand_span = trace.NOOP
+                if traced:
+                    hand_span = trace.span("exchange.handover", partition=p)
+                with hand_span:
+                    t0 = clock()
+                    try:
+                        part = next(in_order)
+                    finally:
+                        wait_ns = clock() - t0
+                        add("exchange_wait_ns", wait_ns)
+                        hand_span.set_attr("wait_ns", wait_ns)
+                    if not part.rows:
+                        continue
+                    rows_seen += part.rows
+                    if rows_seen > max_rows:
+                        raise over_ceiling(rows_seen)
+                    t0 = clock()
+                    layout.adopt_codes(part.cols, part.encoders)
+                    merge_ns += clock() - t0
+                    prepared.append(part.cols)
+        return prepared, merge_ns
+
+    def execute_exchanged(
+        self, ctx: TaskContext
+    ) -> Iterator[tuple[int, pa.RecordBatch]]:
+        """Yield (output_partition, batch) pairs after the mesh exchange.
+
+        Always-on counters: ``exchange_workers`` (the pool's width, 1 =
+        inline); on the task thread ``exchange_wait_ns`` (blocked until
+        the next partition IN ORDER is prepared), ``exchange_encode_ns``
+        (what is left of the encode there: dictionaries mapped at the
+        hand-over, the final concatenate, the bucket count),
+        ``device_time_ns``, ``exchange_decode_ns``; summed over the
+        workers (at width 1 this thread, inside its wait)
+        ``exchange_pull_ns`` (inside ``next()`` on the input),
+        ``repart_time_ns`` (destination hash), ``exchange_convert_ns``
+        (coalesce + flatten)."""
+        from ..ops import kernels as K
+        from . import mesh as M
+
+        n_out = self.partitioning.n
+        n_dev = _mesh_width(self.n_devices, ctx)
+        add = self.metrics.add
+        clock = time.perf_counter_ns
+
         # the stage's wall stops before the first yield: the writer that
         # consumes the batches has its own timers
         with self.metrics.timer("mesh_stage_time_ns"):
-            batches: list[pa.RecordBatch] = []
-            dest_parts: list[np.ndarray] = []
-            rows_seen = 0
-            for p in range(self.input.output_partitioning().n):
-                for b in self.input.execute(p, ctx):
-                    ctx.check_cancelled()
-                    if b.num_rows == 0:
-                        continue
-                    rows_seen += b.num_rows
-                    if rows_seen > max_rows:
-                        raise MeshExchangeError(
-                            f"stage exceeds mesh.exchange_max_rows "
-                            f"({rows_seen} > {max_rows})"
-                        )
-                    with self.metrics.timer("repart_time_ns"):
-                        idx = partition_indices(b, exprs, n_out)
-                    batches.append(b)
-                    dest_parts.append(idx.astype(np.int32))
-            if not batches:
+            # the input's layout and dictionaries now, the program once
+            # the rows have said what capacity it needs
+            layout = M.ExchangeLayout(self.input.schema)
+            prepared, merge_ns = self._prepare_partitions(ctx, layout)
+            if not prepared:
                 return
 
             mesh = M.make_mesh(n_dev)
@@ -911,7 +1127,9 @@ class MeshRepartitionExec(ExecutionPlan):
                     list(self.input.schema)
                     + [pa.field("__part", pa.int32())]
                 )
-                dest_dev = (np.concatenate(dest_parts) % n_dev).astype(np.int32)
+                cols = [np.concatenate(one) for one in zip(*prepared)]
+                # the last field is __part: its values, then its validity
+                dest_dev = (cols[-2] % n_dev).astype(np.int32)
                 total = len(dest_dev)
                 valid = np.ones(total, dtype=bool)
                 # exact per-(source shard, destination) bucket need, from
@@ -927,23 +1145,7 @@ class MeshRepartitionExec(ExecutionPlan):
                     ).max()
                 )
                 cap = K.bucket_rows(need, floor=1)
-                try:
-                    ex = M.BatchExchanger(mesh, ext_schema, cap)
-                    # encoding is capacity-independent
-                    cols_per_batch = [
-                        ex.to_columns(
-                            pa.RecordBatch.from_arrays(
-                                list(b.columns) + [pa.array(d)],
-                                schema=ext_schema,
-                            )
-                        )
-                        for b, d in zip(batches, dest_parts)
-                    ]
-                except ExecutionError as e:
-                    # column didn't cross the bridge (dtype slipped past the
-                    # plan-time check): an exchange failure, not a plan failure
-                    raise MeshExchangeError(str(e)) from e
-                cols = [np.concatenate(parts) for parts in zip(*cols_per_batch)]
+                ex = M.BatchExchanger(mesh, ext_schema, cap, share_from=layout)
             t1 = clock()
             growths = 0
             with trace.span(
@@ -982,7 +1184,7 @@ class MeshRepartitionExec(ExecutionPlan):
                         lo, hi = bounds[out_p], bounds[out_p + 1]
                         if hi > lo:
                             out.append((out_p, shuffled.slice(lo, hi - lo)))
-            add("exchange_encode_ns", t1 - t0)
+            add("exchange_encode_ns", merge_ns + t1 - t0)
             add("device_time_ns", t2 - t1)
             add("exchange_decode_ns", clock() - t2)
             if growths:

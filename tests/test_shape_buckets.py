@@ -126,6 +126,9 @@ def test_q3_served_on_the_device_route_equals_the_plain_reference(served, tmp_pa
     assert profile["join_probe_rows"] == tpu["join_probe_rows"] and profile["stage_pad_rows"] == tpu["stage_pad_rows"]
     assert profile["join_build_ms"] > 0 and profile["exchange_device_ms"] > 0
     assert profile["exchange_encode_ms"] > 0 and profile["exchange_decode_ms"] > 0
+    # five exchanges' pools summed in the row (PR 32); the workers' sums beside the task thread's wait
+    assert profile["exchange_workers"] == ex["exchange_workers"] >= 5 and profile["exchange_wait_ms"] > 0
+    assert profile["exchange_pull_ms"] > 0 and profile["exchange_hash_ms"] > 0 and profile["exchange_convert_ms"] > 0
 
 
 def test_a_second_data_set_compiles_nothing(served, tmp_path):
