@@ -15,7 +15,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark.chip.phases import window_queries  # noqa: E402
+from benchmark.chip.phases import PHASES, window_queries  # noqa: E402
 from benchmark.metrics import _gang  # noqa: E402
 
 TASK_THREAD = ("gang_wait_ns", "gang_merge_ns", "gang_upload_ns", "gang_assemble_ns",
@@ -71,7 +71,7 @@ def by_kind(kept: str) -> None:
         if None not in task:  # the change: what the task thread's six phases leave of the wall
             row["task_thread_rest_%"] = 100.0 * (wall - sum(task)) / wall
         else:  # the parent: its seven self times
-            row["seven_phases_rest_%"] = _gang.share_of_wall(run, _gang.PHASES, rest=True)
+            row["seven_phases_rest_%"] = _gang.share_of_wall(run, PHASES, rest=True)
         stages: dict = {}
         for q in qs:
             for st in q["job"]["stages"]:

@@ -4,6 +4,8 @@ task the exchange's encode / device / decode, the join's build, the device
 stage, shuffle write and fetch; means over the run's window queries.
 
     python3 benchmark/chip/q3_phases.py DIR [DIR ...]
+
+In a window of mixed kinds (``loadtest4``) only the q3s are read.
 """
 
 import json
@@ -30,7 +32,7 @@ def main(keep_dir: str) -> None:
     with open(os.path.join(keep_dir, "queries.json")) as f:
         records = json.load(f)
     jobstats.match(records, jobs)
-    window = [r for r in records if r.get("seq") is not None and r.get("job")]
+    window = [r for r in records if r.get("seq") is not None and r.get("job") and r["kind"] == 3]
     print(f"{keep_dir}: {len(window)} window queries, mean latency "
           f"{sum(r['latency_s'] for r in window) / max(1, len(window)):.3f} s")
     n = len(window)

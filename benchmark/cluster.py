@@ -99,6 +99,17 @@ class Child:
         return {"returncode": self.proc.returncode, "sigkill": killed}
 
 
+def new_job_id(ctx, seen: set):
+    """The id of the job that the context's last ``collect()`` ran: what the
+    client has submitted (``BallistaContext._job_ids``, the program's private
+    name, read here and nowhere else) less ``seen``, which takes the new ids.
+    None where the call submitted no job, or more than one, or the context
+    keeps no ids (a test's stand-in)."""
+    new = set(getattr(ctx, "_job_ids", ())) - seen
+    seen |= new
+    return new.pop() if len(new) == 1 else None
+
+
 def rest(rest_port: int, path: str):
     with urllib.request.urlopen(f"http://127.0.0.1:{rest_port}{path}", timeout=30) as r:
         return json.loads(r.read().decode())

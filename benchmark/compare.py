@@ -9,6 +9,11 @@ were set from):
   (group keys, order keys, dates, counts) and differ.  Exact: limit 0.
 - ``rel_gap_max``: the widest gap of a float cell from the reference's,
   as a share of the reference's magnitude.
+- ``rows_out_of_order``: neighbouring rows of an answer that stand the wrong
+  way round in the order its text asks (``queries.ORDER_BY``; q3: revenue
+  descending, then order date).  Two neighbours whose float keys lie within
+  ``rel_gap_max``'s limit of each other may stand either way round: the
+  program sums in float32 and may rank them so.  Exact otherwise: limit 0.
 """
 
 from __future__ import annotations
@@ -20,7 +25,7 @@ import pyarrow as pa
 # A number has to be <= its limit.  rel_gap_max lies between the program's
 # largest reading over its seeds (7.7e-7, steady) and the bfloat16 control's
 # smallest (~1e-3); PERF.md section 2 gives the readings.
-LIMITS = {"cells_wrong": 0.0, "rel_gap_max": 3e-5}
+LIMITS = {"cells_wrong": 0.0, "rel_gap_max": 3e-5, "rows_out_of_order": 0.0}
 
 
 def _rows(t: pa.Table, keys) -> dict:
@@ -58,21 +63,45 @@ def table_gap(served: pa.Table, ref: pa.Table) -> tuple:
     return wrong, gap, where
 
 
+def out_of_order(served: pa.Table, order) -> int:
+    """Neighbouring rows of ``served`` that stand the wrong way round under
+    ``order``, a list of (column, descending).  Keys are compared one after
+    the other: equal keys pass to the next; float keys that differ by no
+    more than the limit allow either way."""
+    if not order or any(name not in served.column_names for name, _ in order):
+        return 0
+    cols = [(served.column(name).to_pylist(), desc) for name, desc in order]
+    wrong = 0
+    for i in range(served.num_rows - 1):
+        for values, desc in cols:
+            a, b = values[i], values[i + 1]
+            if a == b:
+                continue  # the next key decides
+            if isinstance(a, float) and abs(a - b) <= LIMITS["rel_gap_max"] * max(abs(a), abs(b)):
+                break  # a tie to the comparison's eye: either way round
+            wrong += (a < b) if desc else (a > b)
+            break
+    return wrong
+
+
 def judge(pairs) -> dict:
-    """``pairs``: (served table, reference table) of every answer compared.
-    Returns {"correct", "numbers": {name: {"value", "limit"}}, "compared",
-    "widest": the column of the widest gap}."""
-    wrong, gap, widest = 0, 0.0, ""
+    """``pairs``: (served table, reference table[, order]) of every answer
+    compared; ``order`` as ``out_of_order`` takes it.  Returns {"correct",
+    "numbers": {name: {"value", "limit"}}, "compared", "widest": the column
+    of the widest gap}."""
+    wrong, gap, widest, disorder = 0, 0.0, "", 0
     n = 0
-    for served, ref in pairs:
+    for served, ref, *order in pairs:
         w, g, where = table_gap(served, ref)
         wrong += w
         if g > gap:
             gap, widest = g, where
+        disorder += out_of_order(served, order[0]) if order else 0
         n += 1
     numbers = {
         "cells_wrong": {"value": float(wrong), "limit": LIMITS["cells_wrong"]},
         "rel_gap_max": {"value": gap, "limit": LIMITS["rel_gap_max"]},
+        "rows_out_of_order": {"value": float(disorder), "limit": LIMITS["rows_out_of_order"]},
     }
     ok = n > 0 and all(v["value"] <= v["limit"] for v in numbers.values())
     return {"correct": bool(ok), "numbers": numbers, "compared": n, "widest": widest}

@@ -38,9 +38,8 @@ def summarize(detail: dict) -> dict:
         disp, fin = timing.get("dispatch_us") or {}, timing.get("finish_us") or {}
         start = min(disp.values()) if disp else None
         end = max(fin.values()) if fin else None
-        for p, f in fin.items():
-            if p in disp:
-                intervals.append((disp[p], f))
+        tasks = [(disp[p], f) for p, f in fin.items() if p in disp]
+        intervals += tasks
         ops = {
             op: vals for op, vals in (st.get("metrics") or {}).items()
             if not op.startswith("__")
@@ -48,6 +47,7 @@ def summarize(detail: dict) -> dict:
         stages.append({
             "stage_id": st["stage_id"], "partitions": st.get("partitions"),
             "start_us": start, "end_us": end, "ops": ops,
+            "task_us": sum(f - d for d, f in tasks),  # dispatch to finish, summed over the stage's tasks
             "chain": "_".join(op for op in ops if op != "ShuffleWriterExec")[:60],
         })
     ends = [s["end_us"] for s in stages if s["end_us"] is not None]
@@ -103,34 +103,37 @@ def wrong_route(job: dict, chips: int, kinds_need_gang: bool) -> str:
         devices = max(int(st["ops"]["MeshGangExec"].get("mesh_devices", 0) or 0) for st in gangs)
         if devices != chips:
             return f"mesh_devices {devices} != {chips}"
-    elif not device_stages(job):
+        return ""
+    stages = device_stages(job)
+    if not stages:
         return "no device stage"
+    if any(stage_off_device(st) for st in stages):
+        return "device stage fell back"
     return ""
 
 
-def match(records: list, jobs: list) -> None:
-    """Give each query record its job: the job submitted soonest after the
-    client's call (queries of one client are sequential, so this is exact
-    there; concurrent clients with equal texts may swap, which changes no
-    sum).  Sets ``rec["job"]`` (or None)."""
-    free = sorted((j for j in jobs if j.get("submitted_us")), key=lambda j: j["submitted_us"])
-    for rec in sorted(records, key=lambda r: r["unix_submit"]):
-        rec["job"] = None
+def match(records: list, jobs: list) -> list:
+    """Give each query record its job (``rec["job"]``, or None).  A record
+    that carries the id of the job its own ``collect()`` ran (``job_id``)
+    takes that job, or none where the scheduler has no detail of it: exact
+    whoever else was submitting.  A record with no id (a stand-in for the
+    served system keeps none) takes, of the jobs no id claims, the one
+    submitted soonest after the client's call.  That rule is exact for ONE
+    client only: a client plans its query before the scheduler stamps
+    ``submitted_us``, a q3 plans longer than a q6, and with several clients
+    the two orders cross.  Returns the records that took it, for the caller
+    to report."""
+    by_id = {j["job_id"]: j for j in jobs if j.get("job_id")}
+    claimed = {rec["job_id"] for rec in records if rec.get("job_id")}
+    for rec in records:
+        rec["job"] = by_id.get(rec.get("job_id"))
+    by_time = [rec for rec in records if not rec.get("job_id")]
+    free = sorted((j for j in jobs if j.get("submitted_us") and j.get("job_id") not in claimed),
+                  key=lambda j: j["submitted_us"])
+    for rec in sorted(by_time, key=lambda r: r["unix_submit"]):
         lo, hi = rec["unix_submit"] * 1e6 - 5e3, rec["unix_done"] * 1e6 + 5e3
         for i, j in enumerate(free):
             if lo <= j["submitted_us"] <= hi:
                 rec["job"] = free.pop(i)
                 break
-
-
-def gang_timer_share(queries: list, key: str):
-    """One of the gang stage's host timers over the gang stage's wall (first
-    task dispatch to last finish), in percent, over ``queries``; None where
-    there is no gang stage or no such timer."""
-    timer = wall = 0
-    for q in queries:
-        for st in gang_stages(q["job"]) if q.get("job") else ():
-            if st["start_us"] is not None and st["end_us"] is not None:
-                timer += sum(int(v.get(key, 0) or 0) for v in st["ops"].values())
-                wall += (st["end_us"] - st["start_us"]) * 1000
-    return 100.0 * timer / wall if wall and timer else None
+    return by_time

@@ -9,12 +9,22 @@ def ops_with(job: dict, key: str) -> list:
     return [vals for st in job["stages"] for vals in st["ops"].values() if key in vals]
 
 
+def mean_over_queries(run, per_job):
+    """Mean of ``per_job(job)`` over the window's queries for which it is
+    not None: a query with nothing to read (a q1 beside a q3 has no exchange
+    and no join) is left out, not averaged in as 0.  None where none has."""
+    found = [x for q in run["window"] if q.get("job") for x in [per_job(q["job"])] if x is not None]
+    return sum(found) / len(found) if found else None
+
+
 def per_query(run, key: str, scale: float = 1.0):
     """One counter summed over a query's operators, mean over the window's
-    queries; None where the program has no such counter."""
-    jobs = [q["job"] for q in run["window"] if q.get("job")]
-    found = [int(v[key] or 0) for j in jobs for v in ops_with(j, key)]
-    return sum(found) / scale / len(jobs) if found else None
+    queries that count it; None where the program has no such counter."""
+    def of(job):
+        ops = ops_with(job, key)
+        return sum(int(v[key] or 0) for v in ops) / scale if ops else None
+
+    return mean_over_queries(run, of)
 
 
 def pad_share(run, pad_key: str, rows_key: str):

@@ -1,13 +1,18 @@
 """Shared by the gang-stage phase readers (PR 26): ``MeshGangExec``'s
-always-on phase counters, as the job detail carries them.  The seven
-phases are self times that sum to the stage's wall ``mesh_stage_time_ns``
-up to loop overhead.  A file whose name starts with ``_`` is no reader."""
+always-on phase counters, as the job detail carries them.  Since PR 29 they
+are of two kinds.  ``TASK_PHASES`` are self times of the ONE thread that
+runs the task and sum to the stage's wall ``mesh_stage_time_ns`` up to loop
+overhead.  The workers' three (``gang_scan_ns``, ``key_encode_time_ns``,
+``gang_convert_ns``) are summed over the ``gang_workers`` threads that
+prepare partitions side by side, inside the task thread's wait: up to
+``gang_workers`` x the wall, never a share of it.  A file whose name starts
+with ``_`` is no reader."""
 
 from benchmark import jobstats
 
-PHASES = (
-    "gang_scan_ns", "key_encode_time_ns", "gang_convert_ns", "gang_upload_ns",
-    "gang_assemble_ns", "gang_step_ns", "gang_materialize_ns",
+TASK_PHASES = (
+    "gang_wait_ns", "gang_merge_ns", "gang_upload_ns", "gang_assemble_ns",
+    "gang_step_ns", "gang_materialize_ns",
 )
 WALL = "mesh_stage_time_ns"
 

@@ -1,16 +1,21 @@
 """Host-timed ``device_time_ns`` of the operators that repartitioned rows
-through the device exchange (``mesh_exchange_rows`` > 0), per query.  A
-host timer, named so.  Nothing to read where no stage exchanges (q1, q6)."""
+through the device exchange (``mesh_exchange_rows`` > 0), per query that
+has one.  A host timer, named so.  Nothing to read where no stage exchanges
+(q1, q6)."""
+
+from benchmark.metrics import _exchange
 
 UNIT, BETTER, SOURCE = "ms", "lower", "program_span"
 LAYER, MOVES = "exchange", "query_geomean_s"
 
 
-def read(run):
-    jobs = [q["job"] for q in run["window"] if q.get("job")]
+def _of(job):
     ns = sum(
-        int(vals.get("device_time_ns", 0) or 0)
-        for j in jobs for st in j["stages"] for vals in st["ops"].values()
-        if int(vals.get("mesh_exchange_rows", 0) or 0) > 0
+        int(v.get("device_time_ns", 0) or 0)
+        for v in _exchange.ops_with(job, "mesh_exchange_rows") if int(v["mesh_exchange_rows"] or 0) > 0
     )
-    return ns / 1e6 / len(jobs) if ns else None
+    return ns / 1e6 or None
+
+
+def read(run):
+    return _exchange.mean_over_queries(run, _of)

@@ -12,10 +12,12 @@ UNIT, BETTER, SOURCE = "ms", "lower", "program_counter"
 LAYER, MOVES = "exchange", "query_geomean_s"
 
 
-def read(run):
-    jobs = [q["job"] for q in run["window"] if q.get("job")]
-    ops = [v for j in jobs for v in _exchange.ops_with(j, "exchange_wait_ns")]
+def _of(job):
+    ops = _exchange.ops_with(job, "exchange_wait_ns")
     if not ops:
         return None
-    pooled = [v for v in ops if int(v.get("exchange_workers") or 0) > 1]
-    return sum(int(v["exchange_wait_ns"] or 0) for v in pooled) / 1e6 / len(jobs)
+    return sum(int(v["exchange_wait_ns"] or 0) for v in ops if int(v.get("exchange_workers") or 0) > 1) / 1e6
+
+
+def read(run):
+    return _exchange.mean_over_queries(run, _of)

@@ -1,6 +1,5 @@
 """``gang_convert_ns``: Arrow -> numpy (``build_env``, casts, building the
-column list) in the gang stage, per query.  With ``gang_upload_ms`` it is what
-``gang_bridge_share`` lumped."""
+column list) in the gang stage, per query."""
 
 from benchmark.metrics import _gang
 

@@ -1,6 +1,5 @@
 """``gang_scan_ns`` of ``MeshGangExec``: time inside ``next()`` on the gang stage's
-source (parquet read, decode, ``from_arrays``), per query.  Supersedes
-``gang_scan_share``, whose timer used to count the consumer too."""
+source (parquet read, decode, ``from_arrays``), per query."""
 
 from benchmark.metrics import _gang
 

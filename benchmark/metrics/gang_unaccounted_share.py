@@ -1,5 +1,7 @@
-"""What the seven phase counters leave of the gang stage's wall
-(``mesh_stage_time_ns``): loop overhead, mesh set-up, cancellation checks."""
+"""What the task thread's six phases (wait, merge, upload, assemble, step,
+materialize: ``_gang.TASK_PHASES``) leave of the gang stage's wall
+(``mesh_stage_time_ns``): loop overhead, mesh set-up, cancellation checks.
+Nothing to read on a program from before PR 29, which has no wait counter."""
 
 from benchmark.metrics import _gang
 
@@ -8,4 +10,4 @@ LAYER, MOVES = "gang stage", "query_geomean_s"
 
 
 def read(run):
-    return _gang.share_of_wall(run, _gang.PHASES, rest=True)
+    return _gang.share_of_wall(run, _gang.TASK_PHASES, rest=True)

@@ -2,7 +2,10 @@
 queries scan (bytes from the data's shapes: rows x the columns' widths as
 stored, whatever kernel reads them; divided over the cell's chips; over the
 table of peaks' bytes/s), over the summed device time of the programs in the
-trace.  Bound: memory (a scan-aggregate does a few operations a byte)."""
+trace.  Bound: memory (a scan-aggregate does a few operations a byte).
+With several clients, queries are in flight when the trace ends: the bytes
+are of the queries that COMPLETED inside the trace and the time is of every
+program in it, so the share reads low there, never high."""
 
 from benchmark import queries
 

@@ -31,6 +31,13 @@ COLUMNS_OF = {
         "lineitem": ("l_orderkey", "l_extendedprice", "l_discount", "l_shipdate")},
 }
 
+# the order each kind's text asks its rows in: (column, descending)
+ORDER_BY = {
+    1: (("l_returnflag", False), ("l_linestatus", False)),
+    6: (),
+    3: (("revenue", True), ("o_orderdate", False)),
+}
+
 # bytes a row holds of each column, as stored (float64/int64 8, date32/int32
 # 4, one-letter flags 1, c_mktsegment its mean length)
 COLUMN_BYTES = {

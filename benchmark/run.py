@@ -36,7 +36,7 @@ sys.path.insert(0, REPO)
 from benchmark import (  # noqa: E402
     compare, datagen, harness, jobstats, queries, reference, trace_reduce, window,
 )
-from benchmark.cluster import Cluster, ClusterFailure  # noqa: E402
+from benchmark.cluster import Cluster, ClusterFailure, new_job_id  # noqa: E402
 
 
 def log(msg: str) -> None:
@@ -60,14 +60,28 @@ def parse_args(argv=None):
     return args
 
 
-def timed(ctx, kind: int, params: dict) -> dict:
+def collect(ctx, seen: set, kind: int, params: dict) -> window.WithJob:
+    """The timed call, ``sql(text).collect()``, and the id of the job it ran,
+    from the client's own books (``seen``: the ids this context had before).
+    A record finds its job by that id, whoever else was submitting."""
+    try:
+        answer = ctx.sql(queries.render(kind, params)).collect()
+    except Exception as e:
+        e.job_id = new_job_id(ctx, seen)
+        raise
+    return window.WithJob(answer, new_job_id(ctx, seen))
+
+
+def timed(ctx, seen: set, kind: int, params: dict) -> dict:
     """One query outside the window (warm-up, CPU-operator read)."""
-    rec = {"kind": kind, "params": params, "unix_submit": time.time(), "error": None, "answer": None}
+    rec = {"kind": kind, "params": params, "unix_submit": time.time(), "error": None,
+           "answer": None, "job_id": None}
     t = time.monotonic()
     try:
-        rec["answer"] = ctx.sql(queries.render(kind, params)).collect()
+        rec["answer"], rec["job_id"] = collect(ctx, seen, kind, params)
     except Exception as e:  # noqa: BLE001 - reported with the run's failure
         rec["error"] = f"{type(e).__name__}: {e}"
+        rec["job_id"] = getattr(e, "job_id", None)
     rec["latency_s"] = time.monotonic() - t
     rec["unix_done"] = time.time()
     return rec
@@ -84,6 +98,7 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     clients = int(traffic.get("clients", 1))
     settings = dict(config.get("session", {}))
     ctxs = [served.client(settings) for _ in range(clients)]
+    seen = [set() for _ in ctxs]  # per client, the job ids already given to a record
     fixed = traffic.get("requests")
     draws = queries.Draws(seed, kinds, traffic.get("parameter_sets", 1))
 
@@ -94,16 +109,16 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
         (kind, p) for kind in kinds for p in draws.window_sets(kind)
     ]
     for kind, params in todo:
-        rec = timed(ctxs[0], kind, params)
+        rec = timed(ctxs[0], seen[0], kind, params)
         log(f"warm-up q{kind}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
         if rec["error"]:
             raise ClusterFailure(f"warm-up of q{kind} failed: {rec['error']}")
         warmup.append(rec)
     cpu_ops = []
     if trace:
-        ref_ctx = served.client({**settings, "ballista.tpu.enable": "false"})
+        ref_ctx, ref_seen = served.client({**settings, "ballista.tpu.enable": "false"}), set()
         for kind in kinds:
-            rec = timed(ref_ctx, kind, draws.aside(kind))
+            rec = timed(ref_ctx, ref_seen, kind, draws.aside(kind))
             log(f"cpu operators q{kind}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
             cpu_ops.append(rec)
         ref_ctx.close()
@@ -121,7 +136,7 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     log(f"set-up done in {setup_s:.1f}s; window of {seconds:g}s")
     result = window.run_window(
         traffic, seed, seconds,
-        lambda c, kind, params: ctxs[c].sql(queries.render(kind, params)).collect(),
+        lambda c, kind, params: collect(ctxs[c], seen[c], kind, params),
         after_first_cycle=stop_trace,
     )
     stop_trace()
@@ -132,7 +147,12 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     records = result["queries"]
     details = [d for d in served.job_details() if d.get("stages") is not None]
     jobs = [jobstats.summarize(d) for d in details]
-    jobstats.match(warmup + cpu_ops + records, jobs)
+    by_time = jobstats.match(warmup + cpu_ops + records, jobs)
+    if by_time:
+        # sound only for one client (a test's stand-in keeps no ids): with
+        # several, the clients' order and the scheduler's cross
+        log(f"{len(by_time)} record(s) carry no job id and took the job submitted soonest after "
+            f"their call ({clients} client(s))")
     for r in records:
         if r["error"] is None:
             why = (
@@ -141,11 +161,11 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
             )
             if why:
                 r["wrong_route"] = why
-                log(f"q{r['kind']} #{r['seq']} off the cell's path: {why}")
+                log(f"q{r['kind']} client {r['client']} #{r['seq']} job {r['job_id']} off the cell's path: {why}")
     return {
         "setup_s": setup_s, "records": records, "window_s": result["window_s"],
         "warmup": warmup, "cpu_ops": cpu_ops, "memory": memory,
-        "trace_marks": trace_marks, "chips": chips, "job_details": details,
+        "trace_marks": trace_marks, "chips": chips, "job_details": details, "paired_by_time": len(by_time),
         "rows_of_kind": {
             k: sum(data["rows"][t] for t in queries.TABLES_OF[k]) for k in kinds
         },
@@ -162,7 +182,7 @@ def judge(measured: dict, data_dir: str) -> dict:
             key = (r["kind"], json.dumps(r["params"], sort_keys=True))
             if key not in answers:
                 answers[key] = reference.answer(ref, r["kind"], r["params"])
-            pairs.append((r["answer"], answers[key]))
+            pairs.append((r["answer"], answers[key], queries.ORDER_BY[r["kind"]]))
     return compare.judge(pairs)
 
 
@@ -283,6 +303,7 @@ def main(argv=None) -> int:
         run = {
             "cell": cell, "config": config, "traffic": resolved["traffic"],
             "chips": measured["chips"], "window": good, "window_all": records,
+            "window_s": measured["window_s"],
             "warmup": measured["warmup"], "cpu_ops": measured["cpu_ops"],
             "trace": trace, "memory": measured["memory"], "data": data,
             "peaks": peaks_table.get(info["device_kind"]),  # None only in a rehearsal
@@ -299,6 +320,10 @@ def main(argv=None) -> int:
         "window_s": measured["window_s"], "seconds": args.seconds,
         "latencies_s": {f"q{k}": [r["latency_s"] for r in good if r["kind"] == k] for k in kinds},
         "compared": verdict["compared"],
+        "completions_by_client": [
+            sum(r["client"] == c for r in good) for c in range(int(resolved["traffic"].get("clients", 1)))
+        ],
+        "paired_by_time": measured["paired_by_time"],
     }
     if rehearsal:
         out["rehearsal"] = f"--platform cpu at SF{sf:g}: no number here is a device's"

@@ -3,6 +3,7 @@ text, with a fault planted where the answer is produced.  It lets the tests
 drive the rest of a run (warm-up, window, job matching, route checks, the
 comparison) without a cluster."""
 
+import itertools
 import time
 
 from benchmark import queries, reference
@@ -14,8 +15,9 @@ class _Frame:
 
 
 class FakeServed:
-    def __init__(self, data_dir, chips=1, fault=None, precision="float64", mesh_devices=None):
+    def __init__(self, data_dir, chips=1, fault=None, precision="float64", mesh_devices=None, ids=True):
         self.chips, self.fault, self.precision = chips, fault, precision
+        self.ids = ids  # False: contexts keep no job ids, and records fall to the time rule
         self.mesh_devices = chips if mesh_devices is None else mesh_devices
         files = None
         if fault == "half_batch":  # half of the rows left out
@@ -27,20 +29,25 @@ class FakeServed:
             queries.render(k, p): (k, p) for k in (1, 6, 3) for p in queries.parameter_sets(k)
         }
         self.jobs = []
+        self._n = itertools.count()  # several clients answer at once
 
     def client(self, settings):
         served = self
 
         class Ctx:
+            def __init__(self):
+                if served.ids:
+                    self._job_ids = set()  # as BallistaContext keeps them
+
             def sql(self, text):
-                return _Frame(lambda: served._answer(text))
+                return _Frame(lambda: served._answer(text, self))
 
             def close(self):
                 pass
 
         return Ctx()
 
-    def _answer(self, text):
+    def _answer(self, text, ctx=None):
         kind, params = self.texts[text]
         t0 = time.time()
         table = reference.answer(self.data, kind, params, self.precision)
@@ -49,8 +56,12 @@ class FakeServed:
             col = table.column(name).to_pylist()
             col[0] = col[0] * (1 + 1e-4)
             table = table.set_column(table.column_names.index(name), name, [col])
+        elif self.fault == "swapped" and table.num_rows > 1:  # right rows, two of them the wrong way round
+            table = table.take([1, 0, *range(2, table.num_rows)])
         t1 = time.time()
-        n = len(self.jobs)
+        n = next(self._n)
+        if ctx is not None and self.ids:
+            ctx._job_ids.add(f"job{n}")
         self.jobs.append({
             "job_id": f"job{n}", "state": "completed", "submitted_us": int(t0 * 1e6) + 1,
             "planning_us": 10,

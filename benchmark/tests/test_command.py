@@ -29,9 +29,10 @@ def test_last_line_has_the_contracts_shape(trace):
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(out)
     assert out["correct"] is True and out["failed"] == 0 and out["attempted"] >= 2
     assert out["device"]["platform"] == "cpu" and "rehearsal" in out
-    assert set(out["compared"]) == {"cells_wrong", "rel_gap_max"}
-    tail = p.stderr.strip().splitlines()[-3:]
+    assert set(out["compared"]) == {"cells_wrong", "rel_gap_max", "rows_out_of_order"}
+    tail = p.stderr.strip().splitlines()[-4:]
     assert tail[0].startswith("compared cells_wrong") and tail[1].startswith("compared rel_gap_max")
+    assert tail[2].startswith("compared rows_out_of_order") and tail[3].startswith("correct = True")
     names = {m["name"] for m in bench["per_layer" if trace else "end_to_end"]}
     assert set(out["metrics"]) <= names and out["metrics"]
     for v in out["metrics"].values():

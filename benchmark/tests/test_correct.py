@@ -26,17 +26,19 @@ def test_sound_run_is_correct(small_data):
     assert verdict["numbers"]["rel_gap_max"]["value"] == 0.0
 
 
+@pytest.mark.parametrize("cell", ["tpch-sf1-1chip.scan-agg", "tpch-sf1-1chip.loadtest4"])
 @pytest.mark.parametrize("seed", [3, 2**31 + 9, 123456789])
-def test_control_bfloat16_reference_in_the_programs_place_is_not_correct(small_data, seed):
+def test_control_bfloat16_reference_in_the_programs_place_is_not_correct(small_data, seed, cell):
     data_dir, info = small_data
-    _, verdict = _drive(FakeServed(data_dir, precision="bfloat16"), info, data_dir, seed=seed)
+    _, verdict = _drive(FakeServed(data_dir, precision="bfloat16"), info, data_dir, seed=seed, cell=cell)
     assert not verdict["correct"]
     assert verdict["numbers"]["rel_gap_max"]["value"] > compare.LIMITS["rel_gap_max"]
     assert verdict["numbers"]["cells_wrong"]["value"] == 0  # keys and counts are still exact
 
 
 @pytest.mark.parametrize("fault", ["altered", "half_batch", "no_exchange"])
-@pytest.mark.parametrize("cell,chips", [("tpch-sf1-1chip.scan-agg", 1), ("tpch-sf1-4chip-gang.scan-agg", 4)])
+@pytest.mark.parametrize("cell,chips", [("tpch-sf1-1chip.scan-agg", 1), ("tpch-sf1-4chip-gang.scan-agg", 4),
+                                        ("tpch-sf1-1chip.loadtest4", 1)])
 def test_planted_fault_comes_out_not_correct(small_data, fault, cell, chips):
     data_dir, info = small_data
     _, verdict = _drive(FakeServed(data_dir, chips=chips, fault=fault), info, data_dir, cell=cell)
@@ -51,12 +53,43 @@ def test_query_off_the_cells_path_is_failed_not_averaged(small_data, fault, mesh
     assert measured["records"] and all(r.get("wrong_route") for r in measured["records"])
 
 
-def test_join_traffic_is_compared_too(small_data):
+@pytest.mark.parametrize("cell", ["tpch-q3-sf1-1chip.join-agg", "tpch-sf1-1chip.scan-agg"])
+def test_two_rows_the_wrong_way_round_are_not_correct(small_data, cell):
+    """The right ten rows (q1: the right groups) in another order than the
+    text asks: every cell is equal, only ``rows_out_of_order`` reads it."""
     data_dir, info = small_data
-    _, ok = _drive(FakeServed(data_dir), info, data_dir, cell="tpch-sf1-1chip.join-agg")
-    assert ok["correct"]
-    _, bad = _drive(FakeServed(data_dir, fault="altered"), info, data_dir, cell="tpch-sf1-1chip.join-agg")
+    _, ok = _drive(FakeServed(data_dir), info, data_dir, cell=cell)
+    assert ok["correct"] and ok["numbers"]["rows_out_of_order"] == {"value": 0.0, "limit": 0.0}
+    _, bad = _drive(FakeServed(data_dir, fault="swapped"), info, data_dir, cell=cell)
     assert not bad["correct"]
+    assert bad["numbers"]["cells_wrong"]["value"] == 0 and bad["numbers"]["rel_gap_max"]["value"] == 0.0
+    assert bad["numbers"]["rows_out_of_order"]["value"] >= 1
+
+
+def test_neighbours_within_the_limit_may_stand_either_way_round():
+    import datetime as dt
+
+    import pyarrow as pa
+
+    order = (("revenue", True), ("o_orderdate", False))
+    day = dt.date(1995, 3, 1)
+
+    def rows(revenues, dates=None):
+        return pa.table({"revenue": revenues, "o_orderdate": dates or [day] * len(revenues)})
+
+    assert compare.out_of_order(rows([300.0, 200.0, 100.0]), order) == 0
+    assert compare.out_of_order(rows([200.0, 300.0, 100.0]), order) == 1
+    # float32 sums may rank two revenues 1e-5 apart the other way: a tie to the comparison
+    assert compare.out_of_order(rows([200.0, 200.0 * (1 + 1e-5), 100.0]), order) == 0
+    assert compare.out_of_order(rows([200.0, 200.0 * (1 + 1e-4), 100.0]), order) == 1
+    # equal revenues: the order date decides, ascending
+    later = day + dt.timedelta(days=1)
+    assert compare.out_of_order(rows([200.0, 200.0], [day, later]), order) == 0
+    assert compare.out_of_order(rows([200.0, 200.0], [later, day]), order) == 1
+    assert compare.out_of_order(rows([200.0, 200.0 * (1 + 1e-5)], [later, day]), order) == 0  # within the limit
+    assert compare.out_of_order(rows([100.0, 100.0]), (("o_orderdate", False),)) == 0
+    assert compare.out_of_order(rows([1.0, 1.0], [later, day]), (("o_orderdate", False),)) == 1
+    assert compare.out_of_order(rows([1.0]), ()) == 0 and compare.out_of_order(rows([1.0, 2.0]), (("x", True),)) == 0
 
 
 def test_table_gap_counts_missing_rows_keys_and_counts(small_data):

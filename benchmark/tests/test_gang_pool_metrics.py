@@ -62,9 +62,13 @@ def test_the_scan_agg_cells_list_the_three_and_the_join_cell_none(readers):
         m, mod = entries[name], readers[name]
         assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
             mod.UNIT, mod.BETTER, mod.SOURCE, mod.LAYER, mod.MOVES)
-        assert m["workloads"] == ["tpch-sf1-1chip.scan-agg", "tpch-sf1-4chip-gang.scan-agg"]
-    # appended: nothing that was there moved
-    assert [m["name"] for m in bench["per_layer"]][-3:] == ["gang_wait_ms", "gang_merge_ms", "gang_workers"]
+        assert m["workloads"][:2] == ["tpch-sf1-1chip.scan-agg", "tpch-sf1-4chip-gang.scan-agg"]
+        assert "tpch-q3-sf1-1chip.join-agg" not in m["workloads"]
+    # appended after PR 26's entries, side by side, in this order: by position,
+    # since later PRs append theirs behind
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("gang_wait_ms")
+    assert names[at:at + 3] == ["gang_wait_ms", "gang_merge_ms", "gang_workers"] and names.index("task_overhead_ms") < at
     # the hand-built run holds gang stages only: read the gang stage's metrics
     bench = {"workloads": bench["workloads"],
              "per_layer": [m for m in bench["per_layer"] if m["layer"] == "gang stage"]}

@@ -25,31 +25,31 @@ def test_new_config_traffic_and_metric_resolve_without_an_edit(tmp_path):
     cfg = harness.load_json(os.path.join(bdir, "configs", "tpch-sf1-1chip.json"))
     cfg.update(name="tpch-sf1-1chip-defaultclient", session={})
     (root / "benchmark/configs/tpch-sf1-1chip-defaultclient.json").write_text(json.dumps(cfg))
-    (root / "benchmark/traffic/loadtest4.json").write_text(json.dumps(
-        {"kinds": [1, 6, 3], "clients": 4, "loop": "closed", "order": "shuffle"}))
+    (root / "benchmark/traffic/loadtest8.json").write_text(json.dumps(
+        {"kinds": [1, 6, 3], "clients": 8, "loop": "closed", "order": "shuffle"}))
     (root / "benchmark/metrics/key_encode_share.py").write_text(
         'UNIT, BETTER, SOURCE = "%", "lower", "program_span"\n'
         'LAYER, MOVES = "gang stage", "query_geomean_s"\n\n'
         'def read(run):\n'
-        '    from benchmark import jobstats\n'
-        '    return jobstats.gang_timer_share(run["window"], "key_encode_time_ns")\n')
+        '    from benchmark.metrics import _gang\n'
+        '    return _gang.share_of_wall(run, ("key_encode_time_ns",))\n')
     # ... and one entry each
     bench["configs"].append({"name": cfg["name"], "source": "x", "reduced": [], "why": "y",
                              "file": "benchmark/configs/tpch-sf1-1chip-defaultclient.json"})
-    bench["workloads"].append({"name": "tpch-sf1-1chip-defaultclient.loadtest4", "chips": 1, "why": "z",
-                               "config": cfg["name"], "traffic": "loadtest4"})
+    bench["workloads"].append({"name": "tpch-sf1-1chip-defaultclient.loadtest8", "chips": 1, "why": "z",
+                               "config": cfg["name"], "traffic": "loadtest8"})
     bench["per_layer"].append({"name": "key_encode_share", "unit": "%", "better": "lower",
                                "source": "program_span", "layer": "gang stage", "moves": "query_geomean_s",
-                               "workloads": ["tpch-sf1-1chip-defaultclient.loadtest4"]})
-    got = harness.resolve("tpch-sf1-1chip-defaultclient.loadtest4", bench, bdir)
-    assert got["traffic"]["clients"] == 4 and got["config"]["session"] == {}
+                               "workloads": ["tpch-sf1-1chip-defaultclient.loadtest8"]})
+    got = harness.resolve("tpch-sf1-1chip-defaultclient.loadtest8", bench, bdir)
+    assert got["traffic"]["clients"] == 8 and got["config"]["session"] == {}
     readers = harness.load_readers(bdir)
     assert "key_encode_share" in readers
     job = {"stages": [{"start_us": 0, "end_us": 1000, "ops": {
-        "MeshGangExec": {"key_encode_time_ns": 250_000, "mesh_devices": 1}}}]}
+        "MeshGangExec": {"key_encode_time_ns": 250_000, "mesh_stage_time_ns": 1_000_000, "mesh_devices": 1}}}]}
     run = {"window": [{"job": job}], "window_all": [{"job": job}], "warmup": [], "cpu_ops": [],
            "trace": None, "memory": {}, "chips": 1}
-    out = harness.read_per_layer(bench, "tpch-sf1-1chip-defaultclient.loadtest4", run, readers)
+    out = harness.read_per_layer(bench, "tpch-sf1-1chip-defaultclient.loadtest8", run, readers)
     assert out["key_encode_share"] == {"value": 25.0, "unit": "%"}
     # a reader with nothing to read is left out of the line, never 0
     assert "scan_roofline" not in out and "device_idle_share" not in out

@@ -49,8 +49,8 @@ class PadLeak(FakeServed):
         super().__init__(data_dir)
         self.leak = leak
 
-    def _answer(self, text):
-        table = super()._answer(text)
+    def _answer(self, text, ctx=None):
+        table = super()._answer(text, ctx)
         cols = {n: table.column(n).to_pylist() for n in table.column_names}
         if self.leak == "double":
             cols["revenue"][-1] *= 2.0
@@ -86,6 +86,39 @@ def test_a_pad_row_that_leaks_into_the_answer_is_not_correct(small_data, leak):
         assert numbers["rel_gap_max"]["value"] > numbers["rel_gap_max"]["limit"]
     else:
         assert numbers["cells_wrong"]["value"] > 0
+
+
+@pytest.mark.parametrize("counter", ["join_fallback", "tpu_fallback", "cpu_fallback", "highcard_fallback"])
+def test_a_q3_whose_device_stage_fell_back_is_off_the_cells_path(counter):
+    """A kind that is no gang kind is held to its route too (PR 33): a device
+    stage with a fallback counter set left the device, whatever it answered."""
+    job = _job(3)
+    assert jobstats.wrong_route(job, 1, False) == "" and len(jobstats.device_stages(job)) == 1
+    stage = jobstats.device_stages(job)[0]
+    stage["ops"]["TpuStageExec"][counter] = 2
+    assert jobstats.wrong_route(job, 1, False) == "device stage fell back"
+    # a counter on a stage that was never the device's (the CPU hash join) says nothing
+    job = _job(3)
+    next(st for st in job["stages"] if "HashJoinExec" in st["ops"])["ops"]["HashJoinExec"][counter] = 1
+    assert jobstats.wrong_route(job, 1, False) == ""
+    # and a whole run counts such a query under failed, never averaged in
+    job = _job(3)
+    job["stages"] = [st for st in job["stages"] if st not in jobstats.device_stages(job)]
+    assert jobstats.wrong_route(job, 1, False) == "no device stage"
+
+
+class FellBack(FakeServed):
+    def _answer(self, text, ctx=None):
+        table = super()._answer(text, ctx)
+        self.jobs[-1]["stages"][0]["metrics"]["MeshGangExec"]["cpu_fallback"] = 1
+        return table
+
+
+def test_a_run_counts_a_q3_that_fell_back_as_failed(small_data):
+    data_dir, info = small_data
+    measured, verdict = _drive(FellBack(data_dir), info, data_dir)
+    assert verdict["correct"]  # the answers are right: the route is what failed
+    assert measured["records"] and all(r["wrong_route"] == "device stage fell back" for r in measured["records"])
 
 
 # ------------------------------------------------------------ the readers
@@ -163,6 +196,10 @@ def test_new_readers_find_nothing_on_a_q1_job_or_a_parent(readers):
     for name in ("exchange_pad_share", "exchange_roofline", "join_build_ms", "stage_pad_share"):
         assert readers[name].read(parent) is None, name
     assert readers["exchange_ms"].read(parent) > 0 and readers["device_stage_ms"].read(parent) > 0
+    # a q1 traced beside the q3 (four clients) moves nothing through the exchange and changes nothing
+    mixed = _run_of(3)
+    mixed["trace"]["queries"] = mixed["trace"]["queries"] + [{"kind": 1, "job": _job(1)}]
+    assert readers["exchange_roofline"].read(mixed) == pytest.approx(readers["exchange_roofline"].read(_run_of(3)))
     # no trace, no exchange program in it, or no peaks: no roofline
     for broken in ({"trace": None}, {"peaks": None},
                    {"trace": {**_run_of(3)["trace"], "device_ops": [["jit_fn", 0.1]]}}):

@@ -4,9 +4,11 @@ One general loop reads a traffic file's parameters:
 
 - ``kinds``: the query kinds of the mix (TPC-H query numbers).
 - ``clients``: closed-loop clients, each a thread with its own remote
-  context; a client sends its next query when its last one has returned.
+  context and session; a client sends its next query when its last one has
+  returned, with no think time.
 - ``order``: ``cycle`` (kinds in the listed order, every client) or
-  ``shuffle`` (each round of kinds in an order drawn from the seed).
+  ``shuffle`` (each round of kinds in an order drawn from the seed and the
+  client's number, so that clients at once send different kinds).
 - ``parameter_sets``: how many parameter sets of each kind a window cycles
   through (default 1); warm-up runs every one, since the program compiles
   for each new set of literals.
@@ -21,11 +23,17 @@ start to the last completion.
 
 from __future__ import annotations
 
+import collections
 import random
 import threading
 import time
 
 from . import queries
+
+# What ``submit`` may return in place of the bare answer: the answer and the
+# id of the job that ran it (``cluster.new_job_id``).  An exception may carry
+# the id as its ``job_id`` attribute.
+WithJob = collections.namedtuple("WithJob", "answer job_id")
 
 
 def plan(traffic: dict, seed: int, client: int):
@@ -55,7 +63,8 @@ def plan(traffic: dict, seed: int, client: int):
 
 def run_window(traffic: dict, seed: int, seconds: float, submit, after_first_cycle=None) -> dict:
     """Drive the window.  ``submit(client, kind, params)`` returns the
-    answer (or raises).  A kind with no completion yet is submitted while
+    answer, or a ``WithJob`` (or raises); a record keeps the job's id as
+    ``job_id``.  A kind with no completion yet is submitted while
     the window is open (warm-up latencies hold compile time and say nothing).
     Returns {"queries": [...], "window_s": start to last completion}."""
     clients = int(traffic.get("clients", 1))
@@ -77,11 +86,13 @@ def run_window(traffic: dict, seed: int, seconds: float, submit, after_first_cyc
                 return
             rec = {"client": c, "seq": n, "kind": kind, "params": params,
                    "unix_submit": time.time(), "t_submit": time.monotonic() - t0,
-                   "answer": None, "error": None}
+                   "answer": None, "job_id": None, "error": None}
             try:
-                rec["answer"] = submit(c, kind, params)
+                got = submit(c, kind, params)
+                rec["answer"], rec["job_id"] = got if isinstance(got, WithJob) else (got, None)
             except Exception as e:  # noqa: BLE001 - a failed query is counted, not fatal
                 rec["error"] = f"{type(e).__name__}: {e}"
+                rec["job_id"] = getattr(e, "job_id", None)
             rec["t_done"] = time.monotonic() - t0
             rec["unix_done"] = time.time()
             rec["latency_s"] = rec["t_done"] - rec["t_submit"]
