@@ -359,6 +359,13 @@ _JOIN_COUNTERS = (
     ("stage_pad_rows", "stage_pad_rows"),
     ("stage_batches", "stage_batches"),
     ("stage_uploads", "stage_uploads"),
+    # the build-row route (PR 34): the stage's groups are its build rows
+    ("join_build_groups", "join_build_groups"),
+    ("join_matched_rows", "join_matched_rows"),
+    ("join_groups", "join_groups"),
+    ("join_probe_bytes", "join_probe_bytes"),
+    ("join_state_bytes", "join_state_bytes"),
+    ("join_state_slots", "join_state_slots"),
 )
 
 

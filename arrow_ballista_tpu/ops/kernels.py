@@ -634,7 +634,7 @@ def flat_arg_names(leaves: dict[str, LeafSpec]) -> list[str]:
 
 def make_join_kernel(
     inner_fn, flat_names: list[str], join_slots: dict[str, int],
-    n_build: int, dense: bool = False,
+    n_build: int, dense: bool = False, build_rows: Optional[int] = None,
 ):
     """Wrap a fused aggregate kernel with an on-device PK-FK probe join.
 
@@ -656,10 +656,19 @@ def make_join_kernel(
     (round 5, star join).  Either way non-matching probe rows
     fold into the global row mask (inner join), so shapes stay static
     and the joined relation is never materialized.
+
+    ``build_rows`` (the build side's padded row count, which ``inner_fn``
+    was built at as its capacity): the stage's groups ARE the build rows,
+    so the segment id of a row is the build row it matched, worked out
+    here; the program takes no ``seg`` (``join_aggregate(valid, ...)``) and
+    returns the states, then the count of valid probe rows that matched.
+    A row that matched nothing gets the id ``build_rows``, out of range:
+    the reduction drops it.
     """
     n_probe = sum(1 for n in flat_names if n not in join_slots)
 
-    def fn(seg_ids, valid, *args):
+    def probe(args):
+        """(matched build row, match mask, the inner kernel's args)."""
         probe_args = args[:n_probe]
         if dense:
             pkey, pkey_valid, tbl, kmin = args[n_probe:n_probe + 4]
@@ -693,6 +702,21 @@ def make_join_kernel(
                 full.append(jnp.logical_and(bvalids[j][idx], match))
             else:
                 full.append(bvals[j][idx])
+        return idx, match, full
+
+    if build_rows is not None:
+
+        def join_aggregate(valid, *args):
+            idx, match, full = probe(args)
+            hit = jnp.logical_and(valid, match)
+            seg_ids = jnp.where(hit, idx, jnp.int32(build_rows))
+            states = inner_fn(seg_ids, hit, *full)
+            return tuple(states) + (jnp.sum(hit.astype(jnp.int32)),)
+
+        return join_aggregate
+
+    def fn(seg_ids, valid, *args):
+        _, match, full = probe(args)
         return inner_fn(seg_ids, jnp.logical_and(valid, match), *full)
 
     return fn

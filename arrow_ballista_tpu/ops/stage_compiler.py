@@ -105,10 +105,26 @@ class _KeyedGroups:
         return self._codes[key][gids]
 
 
+class _BuildRows:
+    """The groups of the build-row route: build row i, in key order, is
+    group i, and its key is the build side's i-th sorted key."""
+
+    def __init__(self, keys: np.ndarray):
+        self._keys = keys
+        self.n_groups = len(keys)
+
+    def key_column(self, rows: np.ndarray, t: pa.DataType) -> pa.Array:
+        v = self._keys[rows]
+        if pa.types.is_date32(t):
+            return pa.array(v.astype("datetime64[D]"), t)
+        return pa.array(v, pa.int64()).cast(t)
+
+
 class Route(enum.Enum):
     """How an aggregate stage runs, as :func:`choose_route` says."""
 
     GID = "gid"  # host-assigned group ids into the device segment table
+    BUILD_ROWS = "build_rows"  # a folded join's matched build row is the group
     KEYED = "keyed"  # raw key codes sort on the device (``_run_keyed``)
     CPU_HASH = "cpu_hash"  # the C++ hash aggregate, pulled batches replayed
     CPU_SMALL = "cpu_small"  # source under tpu.min_rows: CPU operators
@@ -152,6 +168,7 @@ def choose_route(
     folded_join: bool,
     max_capacity: int,
     small_input: bool = False,
+    build_rows: bool = False,
     first: Optional[FirstBatch] = None,
 ) -> Optional[Route]:
     """The one place that says how an aggregate stage runs.
@@ -161,13 +178,23 @@ def choose_route(
     ``first`` non-empty batch.  Pure: it reads its arguments and the two
     bounds above, and calls ``first``'s questions in the order of their
     cost.  ``highcard_mode`` is ``ballista.tpu.highcard_mode``; ``auto``
-    is ``cpu`` for a stage without a folded join.  A gang stage asks with
-    what its probe knows (no join, keys taken as fitting:
-    ``_execute_mesh_keyed`` checks them batch by batch)."""
+    is ``cpu`` for a stage without a folded join.  ``build_rows``: the
+    stage folds a join whose groups are its build rows (its one
+    host-encoded group key is the probe join key, no aggregate needs the
+    keyed buffers) and the build side's bucket is within ``max_capacity``;
+    such a stage needs no first batch, and a pinned mode (``device``,
+    ``gid``) keeps its meaning.  A gang stage asks with what its probe
+    knows (no join, keys taken as fitting: ``_execute_mesh_keyed`` checks
+    them batch by batch)."""
     if small_input:
         return Route.CPU_SMALL
     if not grouped:
         return Route.GID  # every row into group 0: nothing to decide
+    if build_rows and highcard_mode in ("auto", "cpu"):
+        # the groups are bounded by the build rows, whatever number of
+        # distinct keys the probe side holds (q3 SF10: 15M order keys,
+        # 1.45M build rows): no gid table, no host key encode
+        return Route.BUILD_ROWS
     if first is None:
         return None
     pinned_keyed = needs_keyed or highcard_mode == "device"
@@ -187,12 +214,12 @@ def choose_route(
         return Route.GID  # pinned (A/B)
     if not folded_join:
         return Route.CPU_HASH
-    # A folded join pays the join on either route, so it stays on the gid
-    # table while that can hold the stage -- but the table keys on every
-    # distinct PROBE key before the join filters, so a first batch that
-    # alone fills half the ceiling says the stream will overflow it after
-    # the host has paid the encode (q3 SF10: 15M order keys against the 2M
-    # ceiling): leave for the join-on-CPU shape now.
+    # A folded join whose groups are not its build rows pays the join on
+    # either route, so it stays on the gid table while that can hold the
+    # stage -- but the table keys on every distinct PROBE key before the
+    # join filters, so a first batch that alone fills half the ceiling
+    # says the stream will overflow it after the host has paid the
+    # encode: leave for the join-on-CPU shape now.
     if groups is None or groups > max_capacity // 2:
         return Route.NOJOIN
     return Route.GID
@@ -1027,6 +1054,16 @@ class TpuStageExec(ExecutionPlan):
                 self._jk_slot is None
             ):
                 raise K.NotLowerable("build group keys without probe key")
+        # the folded join's groups are its build rows: the one host-encoded
+        # group key is the probe join key and the build keys are unique
+        # (``_build_join_state`` refuses duplicates), so a group is exactly
+        # one matched build row; the other keys resolve from the build side
+        self._groups_are_build_rows = (
+            self._n_encoded_groups == 1
+            and self._jk_slot is not None
+            and not self._needs_keyed
+        )
+        self._build_rows_counted = False
         self._build_state = None  # lazily prepared per instance
         self._build_lock = __import__("threading").Lock()
         # (exprs, n_out) installed by a downstream ShuffleWriterExec so
@@ -1075,18 +1112,23 @@ class TpuStageExec(ExecutionPlan):
             "compile_cache_hits" if hit else "compile_cache_misses", 1
         )
 
-    def _kernel_for(self, capacity: int, dense: bool = False):
+    def _kernel_for(
+        self, capacity: int, dense: bool = False, build_rows: bool = False
+    ):
         """(raw, jitted) fused kernel at the given segment capacity.
 
         Group cardinality is data-dependent; capacities grow in 4x buckets
         (execute-time) so the number of distinct XLA compilations stays
         logarithmic while the segment table tracks the data.  ``dense``
         selects the direct-probe join wrapper (decided per execution from
-        the prepared build side's key span).
+        the prepared build side's key span); ``build_rows`` the program of
+        the build-row route (``join_aggregate``), whose capacity is the
+        build side's bucket.
         """
         key = (
             self._sig[:2] + (capacity,) + self._sig[3:]
             + (("dense",) if dense else ())
+            + (("build_rows",) if build_rows else ())
             + K.algo_cache_token()
         )
         cached = _KERNEL_CACHE.get(key)
@@ -1111,6 +1153,7 @@ class TpuStageExec(ExecutionPlan):
                         self._join_slots,
                         len(self._device_build_cols),
                         dense=dense,
+                        build_rows=capacity if build_rows else None,
                     )
                 else:
                     kernel = inner
@@ -1256,12 +1299,11 @@ class TpuStageExec(ExecutionPlan):
         if self.fused.join is None:
             return self.original
         # a join-fused stage's gid table holds every distinct PROBE key,
-        # pre-filter — q3 SF10 has 15M orderkeys against the 2M ceiling
-        # even though only 1.26M groups survive the join.  The round-2
+        # pre-filter (a stage whose groups are its build rows never gets
+        # here, unless its build side outgrows the ceiling).  The round-2
         # shape (join on CPU, aggregate on device over POST-join rows)
-        # keys the gid table on surviving groups instead, which is how
-        # round 3 captured q3 at 1.13x; its own execute() still falls to
-        # full CPU if even that overflows.
+        # keys the gid table on surviving groups instead; its own
+        # execute() still falls to full CPU if even that overflows.
         self.metrics.add("join_fallback", 1)
         return self._nojoin_stage()
 
@@ -1374,6 +1416,11 @@ class TpuStageExec(ExecutionPlan):
             needs_keyed=self._needs_keyed,
             folded_join=fused.join is not None,
             max_capacity=self.max_capacity,
+            build_rows=(
+                build is not None
+                and self._groups_are_build_rows
+                and K.bucket_rows(len(build[4])) <= self.max_capacity
+            ),
         )
         min_rows = self.config.tpu_min_rows
         buffered: list[pa.RecordBatch] = []
@@ -1413,7 +1460,6 @@ class TpuStageExec(ExecutionPlan):
         ]
         group_table = GroupTable(max(self._n_encoded_groups, 1))
         dense_join = build is not None and build[0] == "dense"
-        _, kernel = self._kernel_for(self.capacity, dense=dense_join)
         # join.probe: the batches of this partition through the folded
         # join and the aggregate (one kernel), to the fetch of the states
         probe_span = (
@@ -1431,7 +1477,14 @@ class TpuStageExec(ExecutionPlan):
                     route, key_encoders, codes, seg = self._route_first_batch(
                         facts, first, key_encoders, group_table
                     )
-            if route is Route.GID:
+            if route is Route.BUILD_ROWS:
+                group_table = _BuildRows(build[4])
+                host_states, n_rows_in = self._run_build_rows(
+                    first, src, build, probe_span
+                )
+            elif route is Route.GID:
+                probe_span.set_attr("gid", "table")
+                _, kernel = self._kernel_for(self.capacity, dense=dense_join)
                 host_states, n_rows_in = self._run_gid(
                     first, seg, src, kernel, build, ck, fusion_retain,
                     key_encoders, group_table, probe_span, partition,
@@ -1660,6 +1713,78 @@ class TpuStageExec(ExecutionPlan):
                 ck[0], partition, ck[1],
                 (entries, key_encoders, group_table, n_rows_in, cap),
             )
+        return host_states, n_rows_in
+
+    def _run_build_rows(self, first, src, build, probe_span) -> tuple:
+        """The build-row route: ``first`` (None: the source held no row)
+        and every batch left in ``src`` through ``join_aggregate``, whose
+        segment id is the build row a probe row matched, worked out on the
+        device: no gid table, no host key encode, no segment ids to upload.
+        Its states are the build side's bucket of slots.  Returns ``(host
+        states, rows in)``; group ``i`` of the states is build row ``i``."""
+        import jax.numpy as jnp
+
+        m = len(build[4])
+        m_pad = K.bucket_rows(m)
+        _, kernel = self._kernel_for(
+            m_pad, dense=build[0] == "dense", build_rows=True
+        )
+        with self._build_lock:
+            if not self._build_rows_counted:
+                self._build_rows_counted = True
+                self.metrics.add("join_build_groups", 1)
+        acc = matched = None
+        n_rows_in = pad_rows = batches = uploads = probe_bytes = 0
+        stream = itertools.chain(
+            [] if first is None else [first], (b for b in src if b.num_rows)
+        )
+        for batch in stream:
+            n = batch.num_rows
+            n_pad = K.bucket_rows(n)
+            n_rows_in += n
+            pad_rows += n_pad - n
+            with self.metrics.timer("bridge_time_ns"):
+                args, trivial_idx = self._kernel_args(batch, n, n_pad, build)
+            host = [
+                (i, a) for i, a in enumerate(args) if isinstance(a, np.ndarray)
+            ]
+            batches += 1
+            uploads += sum(i not in trivial_idx for i, _ in host)
+            # what the program reads a row: the row mask and each probe
+            # array as handed (an all-true validity reads the mask's byte)
+            probe_bytes += n_pad * (1 + sum(a.itemsize for _, a in host))
+            with self.metrics.timer("device_time_ns"):
+                tail = jnp.arange(n_pad, dtype=jnp.int32) < n
+                args = [
+                    tail if i in trivial_idx else a
+                    for i, a in enumerate(args)
+                ]
+                *out, hit = kernel(tail, *args)
+                acc = K.combine_states(self.specs, acc, tuple(out), self._mode)
+                matched = hit if matched is None else matched + hit
+        with self.metrics.timer("device_time_ns"):
+            host_states = self._fetch_states(acc, m)
+            matched = 0 if matched is None else int(matched)
+        groups = 0
+        if host_states is not None:
+            groups = int(np.count_nonzero(host_states[-1][:m]))
+            slots = len(host_states[-1])
+            self.metrics.add("join_state_slots", slots)
+            self.metrics.add(
+                "join_state_bytes", slots * sum(a.dtype.itemsize for a in acc)
+            )
+        for k, v in (
+            ("rows", n_rows_in), ("padded_rows", pad_rows),
+            ("gid", "build_row"), ("matched", matched), ("groups", groups),
+        ):
+            probe_span.set_attr(k, v)
+        for k, v in (
+            ("stage_pad_rows", pad_rows), ("stage_batches", batches),
+            ("stage_uploads", uploads), ("join_probe_rows", n_rows_in),
+            ("join_probe_bytes", probe_bytes),
+            ("join_matched_rows", matched), ("join_groups", groups),
+        ):
+            self.metrics.add(k, v)
         return host_states, n_rows_in
 
     def _execute_keyed(
@@ -2731,14 +2856,22 @@ class TpuStageExec(ExecutionPlan):
 
         cols: list[pa.Array] = []
         jk_positions = None
+        build_rows = isinstance(group_table, _BuildRows)
         for pos, (kind, slot) in enumerate(self._group_plan):
             field_t = schema.field(len(cols)).type
+            if kind == "enc" and build_rows:
+                # the probe join key: the matched build row's own key
+                cols.append(group_table.key_column(keep, field_t))
+                continue
             if kind == "enc":
                 codes = group_table.codes_for(keep, slot)
                 cols.append(key_encoders[slot].decode(codes, field_t))
                 continue
             # build-resolved group key: look the kept groups' probe join
-            # keys up in the sorted build table (unique keys => exact)
+            # keys up in the sorted build table (unique keys => exact); on
+            # the build-row route a kept group IS its build row
+            if jk_positions is None and build_rows:
+                jk_positions = keep
             if jk_positions is None:
                 jk_codes = group_table.codes_for(keep, self._jk_slot)
                 jk_vals = (

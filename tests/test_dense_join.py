@@ -104,21 +104,26 @@ def test_dense_negative_probe_range():
     assert _join_fallbacks(plan) == 0
 
 
-def test_probe_key_overflow_degrades_to_cpu_join_device_agg():
-    # The gid table of a join-fused stage holds every distinct PROBE key
-    # pre-filter (q3 SF10: 15M orderkeys vs the 2M ceiling, only 1.26M
-    # surviving groups).  On _CapacityExceeded the stage must retry the
-    # round-2 shape — join on CPU, aggregate on device over POST-join
-    # rows — not fall to full CPU.
+@pytest.mark.parametrize("build_n", [100, 1100], ids=["build-rows-fit", "build-bucket-over-the-ceiling"])
+def test_probe_key_overflow_degrades_to_cpu_join_device_agg(build_n):
+    # The gid table of a join-fused stage would hold every distinct PROBE
+    # key pre-filter (q3 SF10: 15M orderkeys vs the 2M ceiling).  Grouped
+    # by the probe key alone, the stage's groups are its build rows
+    # (PR 34): while the build side's bucket fits the ceiling the stage
+    # stays whole on the device whatever the probe keys number.  Past it
+    # the stage retries the round-2 shape -- join on CPU, aggregate on
+    # device over POST-join rows -- not full CPU.
     rng = np.random.default_rng(7)
     n = 5000
     dim = pa.table({
-        "pk": pa.array(np.arange(100), pa.int64()),
-        "dv": pa.array(rng.uniform(0.5, 1.5, 100)),
+        "pk": pa.array(np.arange(build_n), pa.int64()),
+        "dv": pa.array(rng.uniform(0.5, 1.5, build_n)),
     })
+    # 5000 distinct probe keys, at most 500 of them join (post-join groups
+    # stay under the ceiling); group by the probe key
+    keys = np.concatenate([np.arange(min(build_n, 500)), 2000 + np.arange(n)])
     fact = pa.table({
-        # 5000 distinct probe keys, only 100 join; group by the probe key
-        "fk": pa.array(rng.permutation(5000), pa.int64()),
+        "fk": pa.array(rng.permutation(keys)[:n], pa.int64()),
         "v": pa.array(rng.uniform(0, 100, n)),
     })
     sql = ("select fk, sum(v * dv) as s from dim, fact where pk = fk "
@@ -133,6 +138,7 @@ def test_probe_key_overflow_degrades_to_cpu_join_device_agg():
         plan = df.physical_plan()
         out.append((ctx.execute(plan), plan))
     (cpu, _), (tpu_t, plan) = out
+    assert cpu.num_rows > 0
     _assert_equal(cpu, tpu_t)
     from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
 
@@ -144,8 +150,13 @@ def test_probe_key_overflow_degrades_to_cpu_join_device_agg():
             for k, v in node.metrics.values.items():
                 m[k] = m.get(k, 0) + v
         stack.extend(node.children())
-    assert m.get("join_fallback", 0) >= 1, m   # degraded to round-2 shape
-    assert m.get("device_time_ns", 0) > 0, m   # the aggregate still ran on device
+    assert m.get("device_time_ns", 0) > 0, m   # the aggregate ran on device
+    if build_n == 100:
+        assert m.get("join_build_groups") == 1, m
+        assert m.get("join_fallback", 0) == m.get("tpu_fallback", 0) == 0, m
+    else:
+        assert m.get("join_fallback", 0) >= 1, m   # degraded to round-2 shape
+        assert "join_build_groups" not in m, m
 
 
 def test_wide_span_falls_back_to_sorted_probe():

@@ -93,6 +93,22 @@ CASES = [
     ("gang-groups~rows-device-mesh-keyed", dict(GANG, highcard_mode="device"), {},
      Route.KEYED, ["groups", "fit"]),
     ("gang-groups~rows-auto-mesh-fallback", GANG, {}, Route.CPU_HASH, ["groups"]),
+    # a folded join whose groups are its build rows (PR 34): decided before
+    # the first batch and before the probe-key rule, whatever the batch says
+    ("build-rows-before-its-first-batch", dict(folded_join=True, build_rows=True),
+     NO_BATCH, Route.BUILD_ROWS, []),
+    ("build-rows-probe-keys-past-the-ceiling", dict(folded_join=True, build_rows=True),
+     dict(groups=None), Route.BUILD_ROWS, []),
+    ("build-rows-mode-cpu", dict(folded_join=True, build_rows=True, highcard_mode="cpu"),
+     {}, Route.BUILD_ROWS, []),
+    ("build-rows-small-input", dict(folded_join=True, build_rows=True, small_input=True),
+     NO_BATCH, Route.CPU_SMALL, []),
+    ("build-rows-ungrouped", dict(folded_join=True, build_rows=True, grouped=False),
+     NO_BATCH, Route.GID, []),
+    ("build-rows-mode-gid-pinned", dict(folded_join=True, build_rows=True, highcard_mode="gid"),
+     dict(groups=6), Route.GID, ["groups"]),
+    ("build-rows-mode-device-pinned", dict(folded_join=True, build_rows=True, highcard_mode="device"),
+     dict(fast=True), Route.KEYED, ["fast"]),
 ]
 
 
