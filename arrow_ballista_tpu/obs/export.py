@@ -350,6 +350,8 @@ _EXCHANGE_COUNTERS = (
     ("exchange_encode_ns", "exchange_encode_ms"),
     ("device_time_ns", "exchange_device_ms"),
     ("exchange_decode_ns", "exchange_decode_ms"),
+    # PR 35: exchanges whose program placed rows by output partition
+    ("exchange_grouped", "exchange_grouped"),
 )
 _JOIN_COUNTERS = (
     ("join_build_ns", "join_build_ms"),

@@ -855,6 +855,16 @@ class MeshRepartitionExec(ExecutionPlan):
     :class:`ShuffleWriterExec` already-partitioned output batches — zero
     hash-split files, one memory write per output partition.
 
+    Output partition ``p`` lives on device ``p % n_dev``.  The run a
+    source shard sends a device holds that device's partitions one after
+    the other, ascending, and a row's slot in it is its partition's offset
+    (the shard's rows of the device's lower partitions) plus its rank
+    within its partition, so each partition's rows arrive contiguous and
+    in input order; the host, which counted the rows a (shard, partition)
+    to size the capacity, slices them out (``execute_exchanged``: where
+    the one-hot the ranking takes, 4 B a partition, fits in what a row
+    already costs the program).
+
     The host side works a partition at a time: a small pool of workers
     (``_gang_width``; inline where the input holds a device stage) pulls
     the input partitions side by side, each coalescing, hashing and
@@ -930,9 +940,8 @@ class MeshRepartitionExec(ExecutionPlan):
     def _prepare_partitions(self, ctx: TaskContext, layout) -> tuple[list, int]:
         """The stage's input as the exchange's columns: a column list for
         each non-empty input partition, in partition order (the input's
-        fields as ``layout`` lays them out, then the destination partition
-        and its validity), and the ns this thread spent mapping
-        dictionaries.
+        fields as ``layout`` lays them out, then each row's output
+        partition), and the ns this thread spent mapping dictionaries.
 
         The partition is the unit of the host work.  PREPARE (a worker, up
         to ``width`` partitions side by side): pull the partition's
@@ -1042,7 +1051,7 @@ class MeshRepartitionExec(ExecutionPlan):
                             # past the plan-time check): an exchange
                             # failure, not a plan failure
                             raise MeshExchangeError(str(e)) from e
-                        flat.append(cols + [dest, np.ones(len(dest), dtype=bool)])
+                        flat.append(cols + [dest])
                         hash_ns += t2 - t1
                         convert_ns += (t1 - t0) + (clock() - t2)
                     if len(flat) > 1:
@@ -1096,15 +1105,31 @@ class MeshRepartitionExec(ExecutionPlan):
     def execute_exchanged(
         self, ctx: TaskContext
     ) -> Iterator[tuple[int, pa.RecordBatch]]:
-        """Yield (output_partition, batch) pairs after the mesh exchange.
+        """Yield (output_partition, batch) pairs after the mesh exchange:
+        devices ascending, then their partitions ascending, each partition's
+        rows in input order.
+
+        The exchange is GROUPED where a row's one-hot over the output
+        partitions (4 B a partition) costs no more than the row already
+        takes in the program's inputs: the program ranks each row within
+        its output partition and places it at its partition's offset in
+        the (source shard, device) run plus that rank, so the decode
+        slices every partition out of the received buffers at the bucket
+        counts the encode made (``BatchExchanger.partition_batches``): no
+        mask, sort or gather.  Past that width the program routes by
+        device as before, the partition rides as a payload column
+        (``__part``), and the decode slices each device's rows, sorts a
+        uint8/uint16 copy of their partitions (a stable radix sort) and
+        gathers.  Either way the batches are the same.
 
         Always-on counters: ``exchange_workers`` (the pool's width, 1 =
-        inline); on the task thread ``exchange_wait_ns`` (blocked until
-        the next partition IN ORDER is prepared), ``exchange_encode_ns``
-        (what is left of the encode there: dictionaries mapped at the
-        hand-over, the final concatenate, the bucket count),
-        ``device_time_ns``, ``exchange_decode_ns``; summed over the
-        workers (at width 1 this thread, inside its wait)
+        inline), ``exchange_grouped`` (1 where the program placed rows by
+        output partition, else 0); on the task thread ``exchange_wait_ns``
+        (blocked until the next partition IN ORDER is prepared),
+        ``exchange_encode_ns`` (what is left of the encode there:
+        dictionaries mapped at the hand-over, the final concatenate, the
+        bucket count), ``device_time_ns``, ``exchange_decode_ns``; summed
+        over the workers (at width 1 this thread, inside its wait)
         ``exchange_pull_ns`` (inside ``next()`` on the input),
         ``repart_time_ns`` (destination hash), ``exchange_convert_ns``
         (coalesce + flatten)."""
@@ -1129,39 +1154,52 @@ class MeshRepartitionExec(ExecutionPlan):
             mesh = M.make_mesh(n_dev)
             t0 = clock()
             with trace.span("exchange.encode"):
-                # destination column rides the exchange so one device can
-                # carry several output partitions (n_out != n_dev)
-                ext_schema = pa.schema(
-                    list(self.input.schema)
-                    + [pa.field("__part", pa.int32())]
-                )
                 cols = [np.concatenate(one) for one in zip(*prepared)]
-                # the last field is __part: its values, then its validity
-                dest_dev = (cols[-2] % n_dev).astype(np.int32)
-                total = len(dest_dev)
+                part = cols.pop()  # each row's output partition
+                total = len(part)
                 valid = np.ones(total, dtype=bool)
-                # exact per-(source shard, destination) bucket need, from
-                # the contiguous shard layout of the PADDED input: the
-                # arrays go up at exchange_rows(total) rows (the pad rows
-                # are invalid and fill the last shards), so that the
-                # program's shapes do not follow the data
+                grouped = 4 * n_out <= (
+                    part.itemsize + valid.itemsize + sum(c.itemsize for c in cols)
+                )
+                schema, width, route = self.input.schema, n_out, part
+                if not grouped:
+                    # route by device; the partition rides along
+                    schema = pa.schema(
+                        list(schema) + [pa.field("__part", pa.int32())]
+                    )
+                    width, route = n_dev, (part % n_dev).astype(np.int32)
+                    cols += [part, valid]
+                # exact rows a (source shard, partition) of the PADDED
+                # input's contiguous shards: the arrays go up at
+                # exchange_rows(total) rows (the pad rows are invalid and
+                # fill the last shards), so that the program's shapes do
+                # not follow the data.  They size the capacity (a device's
+                # run is its partitions') and bound the decode's slices.
                 rows = M.exchange_rows(total, n_dev)
-                shard_id = np.arange(total, dtype=np.int64) // (rows // n_dev)
-                need = int(
+                per_shard = rows // n_dev
+                counts = np.stack([
                     np.bincount(
-                        shard_id * n_dev + dest_dev, minlength=n_dev * n_dev
-                    ).max()
+                        route[s * per_shard:(s + 1) * per_shard], minlength=width
+                    )
+                    for s in range(n_dev)
+                ])
+                need = max(
+                    int(counts[:, d::n_dev].sum(axis=1).max())
+                    for d in range(min(n_dev, width))
                 )
                 cap = K.bucket_rows(need, floor=1)
-                ex = M.BatchExchanger(mesh, ext_schema, cap, share_from=layout)
+                ex = M.BatchExchanger(
+                    mesh, schema, cap, share_from=layout, n_out=width
+                )
             t1 = clock()
             growths = 0
             with trace.span(
-                "exchange.device", rows=total, padded_rows=rows - total
+                "exchange.device", rows=total, padded_rows=rows - total,
+                grouped=int(grouped),
             ) as dev_span:
                 while True:
                     recv_cols, recv_valid, n_dropped = ex.exchange(
-                        dest_dev, valid, cols
+                        route, valid, cols
                     )
                     if n_dropped == 0:
                         break
@@ -1171,41 +1209,33 @@ class MeshRepartitionExec(ExecutionPlan):
                             "mesh exchange capacity ceiling exceeded"
                         )
                     growths += 1
-                    ex = M.BatchExchanger(mesh, ext_schema, cap, share_from=ex)
+                    ex = M.BatchExchanger(
+                        mesh, schema, cap, share_from=ex, n_out=width
+                    )
                 dev_span.set_attr("capacity", cap)
                 dev_span.set_attr("growths", growths)
             t2 = clock()
-            out: list[tuple[int, pa.RecordBatch]] = []
-            part_col = len(ext_schema) - 1
             with trace.span("exchange.decode"):
-                for recv in ex.to_batches(recv_cols, recv_valid):
-                    if recv.num_rows == 0:
-                        continue
-                    parts = np.asarray(recv.column(part_col))
-                    core = recv.select(range(part_col))
-                    order = np.argsort(parts, kind="stable")
-                    shuffled = core.take(pa.array(order))
-                    bounds = np.searchsorted(
-                        parts[order], np.arange(n_out + 1)
-                    ).tolist()
-                    for out_p in range(n_out):
-                        lo, hi = bounds[out_p], bounds[out_p + 1]
-                        if hi > lo:
-                            out.append((out_p, shuffled.slice(lo, hi - lo)))
+                received = ex.partition_batches(recv_cols, counts)
+                out = (
+                    list(received) if grouped
+                    else [pb for _, recv in received for pb in _split(recv, n_out, n_dev)]
+                )
             add("exchange_encode_ns", merge_ns + t1 - t0)
             add("device_time_ns", t2 - t1)
             add("exchange_decode_ns", clock() - t2)
+            add("exchange_grouped", int(grouped))
             if growths:
                 add("capacity_growths", growths)
             add("mesh_exchange_rows", total)
             # what the program was handed, padding included (the
-            # destination and validity arrays and every encoded column),
+            # partition and validity arrays and every encoded column),
             # and what it handed back (devices x capacity slots a device)
             add("mesh_exchange_padded_rows", rows - total)
             add(
                 "mesh_exchange_bytes",
                 rows * (
-                    dest_dev.itemsize + valid.itemsize
+                    route.itemsize + valid.itemsize
                     + sum(c.itemsize for c in cols)
                 ),
             )
@@ -1216,6 +1246,23 @@ class MeshRepartitionExec(ExecutionPlan):
             add("mesh_devices", n_dev)
             MeshRepartitionExec.exchanges_completed += 1
         yield from out
+
+
+def _split(recv: pa.RecordBatch, n_out: int, n_dev: int) -> list:
+    """One device's received rows (input order; its partitions ride as the
+    last column) as ``(p, batch)`` a non-empty partition, ascending: a
+    stable radix sort of a uint8/uint16 copy of the partitions, one take."""
+    last = recv.num_columns - 1
+    small = np.asarray(recv.column(last)).astype(
+        np.uint8 if n_out <= 1 << 8 else np.uint16 if n_out <= 1 << 16 else np.int64
+    )
+    order = np.argsort(small, kind="stable")
+    shuffled = recv.select(range(last)).take(pa.array(order))
+    bounds = np.concatenate([[0], np.cumsum(np.bincount(small, minlength=n_out))]).tolist()
+    return [
+        (p, shuffled.slice(bounds[p], bounds[p + 1] - bounds[p]))
+        for p in range(n_out) if bounds[p + 1] > bounds[p]
+    ]
 
 
 def maybe_mesh(plan: ExecutionPlan, config) -> ExecutionPlan:
