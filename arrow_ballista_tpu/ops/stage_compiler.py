@@ -19,6 +19,8 @@ import enum
 import functools
 import itertools
 import logging
+import threading
+import time
 from typing import Callable, Iterator, Optional
 
 import numpy as np
@@ -412,7 +414,62 @@ class _BufferedExec(ExecutionPlan):
 # Compiled-kernel cache: plans are rebuilt per query, but the fused kernel
 # is a pure function of the stage's structural signature — reuse the jitted
 # callable (and with it XLA's compilation cache) across plan instances.
+# One jit a key, whichever of a stage's partitions asks first: a jit
+# compiles once for each device its arguments are committed to.
 _KERNEL_CACHE: dict[tuple, object] = {}
+_KERNEL_CACHE_LOCK = threading.Lock()
+
+# One lock a jitted program, taken by ``TpuStageExec._timed_jit`` around a
+# call: whether the call compiled is read under it, so two calls that meet
+# in a program's first compile (partitions on several chips side by side)
+# count one compile each where each compiled, and none where one did not.
+# The program is kept with its lock, so that its ``id`` is never reused.
+_JIT_LOCKS: dict[int, tuple] = {}
+_JIT_LOCKS_GUARD = threading.Lock()
+
+
+def _jit_lock(fn) -> threading.Lock:
+    with _JIT_LOCKS_GUARD:
+        held = _JIT_LOCKS.get(id(fn))
+        if held is None:
+            held = _JIT_LOCKS[id(fn)] = (fn, threading.Lock())
+        return held[1]
+
+
+def partition_device(partition: int, ctx: TaskContext) -> tuple:
+    """``(chip, device)`` that partition ``partition`` of a device stage runs
+    on: chip ``partition % n`` of the mesh the exchanges use (the same
+    ``_mesh_width`` and ``make_mesh``), where the exchange delivers that
+    partition.  ``device`` is None, the default device, where that mesh is
+    one chip or the mesh is off: one chip places nothing."""
+    from ..parallel import mesh as M
+    from ..parallel.mesh_stage import _mesh_width
+
+    if not ctx.config.mesh_enable:
+        return 0, None
+    n_dev = _mesh_width(0, ctx)
+    if n_dev <= 1:
+        return 0, None
+    chip = partition % n_dev
+    return chip, M.make_mesh(n_dev).devices.flat[chip]
+
+
+class _StageTimer:
+    """One interval added to ``tpu_stage_time_ns`` and to its chip's share,
+    ``tpu_stage_time_ns_dev<chip>``, so that the chips' counters sum to the
+    stage's."""
+
+    def __init__(self, metrics, chip: int):
+        self.metrics, self.chip = metrics, chip
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter_ns() - self.t0
+        self.metrics.add("tpu_stage_time_ns", dt)
+        self.metrics.add(f"tpu_stage_time_ns_dev{self.chip}", dt)
 
 
 # ----------------------------------------------------------- substitution
@@ -664,6 +721,19 @@ class TpuStageExec(ExecutionPlan):
     invoked once per batch; partial states accumulate on device and only
     [num_groups]-sized results return to host.  Runtime group-capacity
     overflow falls back to re-executing the original CPU subtree.
+
+    Partition ``p`` runs on chip ``p % n`` of the mesh the exchanges use
+    (:func:`partition_device`): its probe uploads, row mask, program and
+    states are committed to that chip, and a folded join's build side,
+    sorted once on the host, is placed once on each chip the first time a
+    partition there asks.  On one chip nothing is placed: the default
+    device, as without a mesh.  Partitions on the build-row route share
+    nothing else, and that route is known before any batch, so an exchange
+    above may run them side by side (:meth:`partitions_apart`); the other
+    routes are chosen from each partition's first batch, and an exchange
+    runs their partitions one at a time.
+    Always-on ``tpu_stage_time_ns`` is counted per chip as well
+    (``tpu_stage_time_ns_dev<chip>``; the chips' counters sum to it).
     """
 
     def __init__(
@@ -1064,8 +1134,11 @@ class TpuStageExec(ExecutionPlan):
             and not self._needs_keyed
         )
         self._build_rows_counted = False
-        self._build_state = None  # lazily prepared per instance
-        self._build_lock = __import__("threading").Lock()
+        # the folded join's build side, lazily prepared per instance: sorted
+        # and padded on the host once, then its arrays on each chip asked for
+        self._build_host = None
+        self._build_states: dict = {}  # device (None: the default) -> state
+        self._build_lock = threading.Lock()
         # (exprs, n_out) installed by a downstream ShuffleWriterExec so
         # the hash-partition ids ride the device instead of the host
         self._shuffle_hint = None
@@ -1085,18 +1158,22 @@ class TpuStageExec(ExecutionPlan):
         attribution: a call that grows the jit's compiled-signature cache
         paid trace + XLA compilation (jit compiles synchronously inside
         the call; only execution is async), everything else is dispatch.
-        Backs the /api/jobs/{id}/profile compile-vs-execute split."""
-        import time as _t
-
+        The call runs under the program's lock (``_jit_lock``), so a call
+        that waited for another's compile sees the cache grown already and
+        counts as the dispatch it is.  Backs the /api/jobs/{id}/profile
+        compile-vs-execute split."""
         metrics = self.metrics
         cache_size = getattr(fn, "_cache_size", None)
+        lock = _jit_lock(fn)
 
         def call(*args):
-            before = cache_size() if cache_size is not None else -1
-            t0 = _t.perf_counter_ns()
-            out = fn(*args)
-            dt = _t.perf_counter_ns() - t0
-            if before >= 0 and cache_size() > before:
+            with lock:
+                before = cache_size() if cache_size is not None else -1
+                t0 = time.perf_counter_ns()
+                out = fn(*args)
+                dt = time.perf_counter_ns() - t0
+                compiled = before >= 0 and cache_size() > before
+            if compiled:
                 metrics.add("tpu_compile_ns", dt)
                 metrics.add("kernel_compiles", 1)
             else:
@@ -1131,34 +1208,33 @@ class TpuStageExec(ExecutionPlan):
             + (("build_rows",) if build_rows else ())
             + K.algo_cache_token()
         )
-        cached = _KERNEL_CACHE.get(key)
-        self._note_kernel_cache(cached is not None)
-        if cached is None:
-            import jax
-
-            with self.metrics.timer("tpu_compile_ns"):
-                inner = K.make_partial_agg_kernel(
-                    self._filter_closure,
-                    self._arg_closures,
-                    self.specs,
-                    capacity,
-                    self._flat_names,
-                    # variance moments need the per-element-compensated scan
-                    force_sort=any(e[0] == "var" for e in self._emit),
-                )
-                if self.fused.join is not None:
-                    kernel = K.make_join_kernel(
-                        inner,
+        with _KERNEL_CACHE_LOCK:
+            cached = _KERNEL_CACHE.get(key)
+            self._note_kernel_cache(cached is not None)
+            if cached is None:
+                with self.metrics.timer("tpu_compile_ns"):
+                    inner = K.make_partial_agg_kernel(
+                        self._filter_closure,
+                        self._arg_closures,
+                        self.specs,
+                        capacity,
                         self._flat_names,
-                        self._join_slots,
-                        len(self._device_build_cols),
-                        dense=dense,
-                        build_rows=capacity if build_rows else None,
+                        # variance moments need the per-element-compensated scan
+                        force_sort=any(e[0] == "var" for e in self._emit),
                     )
-                else:
-                    kernel = inner
-                cached = (kernel, jax.jit(kernel))
-            _KERNEL_CACHE[key] = cached
+                    if self.fused.join is not None:
+                        kernel = K.make_join_kernel(
+                            inner,
+                            self._flat_names,
+                            self._join_slots,
+                            len(self._device_build_cols),
+                            dense=dense,
+                            build_rows=capacity if build_rows else None,
+                        )
+                    else:
+                        kernel = inner
+                    cached = (kernel, jax.jit(kernel))
+                _KERNEL_CACHE[key] = cached
         return cached[0], self._timed_jit(cached[1])
 
     @property
@@ -1332,6 +1408,42 @@ class TpuStageExec(ExecutionPlan):
         )
         return node.provider, sig
 
+    def _route_facts(self, build) -> dict:
+        """What :func:`choose_route` is told of this stage before any batch,
+        with the folded join's prepared ``build`` (None: no join)."""
+        return dict(
+            highcard_mode=self.config.tpu_highcard_mode,
+            device_encode=self.config.tpu_device_encode,
+            grouped=bool(self.fused.group_exprs),
+            needs_keyed=self._needs_keyed,
+            folded_join=self.fused.join is not None,
+            max_capacity=self.max_capacity,
+            build_rows=(
+                build is not None
+                and self._groups_are_build_rows
+                and K.bucket_rows(len(build[4])) <= self.max_capacity
+            ),
+        )
+
+    def partitions_apart(self, ctx: TaskContext) -> bool:
+        """May this stage's partitions run side by side, each on its own
+        chip?  Where :func:`choose_route` says ``Route.BUILD_ROWS`` before
+        any batch: that route's states, program and uploads are the
+        partition's own, and the build side is prepared under the lock.
+        Asking prepares the build side (partition 0's chip first, as its
+        run would).  A build the device cannot take says no: each
+        partition's own run then takes the join's CPU route, in turn."""
+        if self.fused.join is None or not self._groups_are_build_rows:
+            return False
+        chip, device = partition_device(0, ctx)
+        try:
+            build = self._prepare_build(ctx, device, chip)
+        except (_JoinIneligible, _JaxRuntimeError):
+            return False
+        if build[0] == "empty":
+            return False
+        return choose_route(**self._route_facts(build)) is Route.BUILD_ROWS
+
     def _execute_device(
         self, partition: int, ctx: TaskContext
     ) -> Iterator[pa.RecordBatch]:
@@ -1342,9 +1454,10 @@ class TpuStageExec(ExecutionPlan):
         from . import device_cache
 
         fused = self.fused
+        chip, device = partition_device(partition, ctx)
         build = None
         if fused.join is not None:
-            build = self._prepare_build(ctx)
+            build = self._prepare_build(ctx, device, chip)
             if build[0] == "empty":
                 # inner join against an empty build side: no rows at all
                 yield from self._materialize(
@@ -1385,7 +1498,7 @@ class TpuStageExec(ExecutionPlan):
             cached = device_cache.get(ck[0], partition, ck[1])
             if cached is not None:
                 entries, key_encoders, group_table, n_rows_in, cap = cached
-                with self.metrics.timer("tpu_stage_time_ns"):
+                with _StageTimer(self.metrics, chip):
                     with self.metrics.timer("device_time_ns"):
                         host_states = self._run_fused(
                             entries, cap,
@@ -1409,19 +1522,7 @@ class TpuStageExec(ExecutionPlan):
             from .bridge import coalesce_batches
 
             src = coalesce_batches(src, coalesce, self.metrics)
-        facts = dict(
-            highcard_mode=self.config.tpu_highcard_mode,
-            device_encode=self.config.tpu_device_encode,
-            grouped=bool(fused.group_exprs),
-            needs_keyed=self._needs_keyed,
-            folded_join=fused.join is not None,
-            max_capacity=self.max_capacity,
-            build_rows=(
-                build is not None
-                and self._groups_are_build_rows
-                and K.bucket_rows(len(build[4])) <= self.max_capacity
-            ),
-        )
+        facts = self._route_facts(build)
         min_rows = self.config.tpu_min_rows
         buffered: list[pa.RecordBatch] = []
         small = False
@@ -1463,12 +1564,12 @@ class TpuStageExec(ExecutionPlan):
         # join.probe: the batches of this partition through the folded
         # join and the aggregate (one kernel), to the fetch of the states
         probe_span = (
-            trace.span("join.probe", partition=partition)
+            trace.span("join.probe", partition=partition, device=chip)
             if fused.join is not None else trace.NOOP
         )
         codes = seg = None
-        with _closing_on_error(ra), self.metrics.timer(
-            "tpu_stage_time_ns"
+        with _closing_on_error(ra), _StageTimer(
+            self.metrics, chip
         ), probe_span:
             first = next((b for b in src if b.num_rows), None)
             if route is None:  # grouped: the first batch says
@@ -1480,19 +1581,20 @@ class TpuStageExec(ExecutionPlan):
             if route is Route.BUILD_ROWS:
                 group_table = _BuildRows(build[4])
                 host_states, n_rows_in = self._run_build_rows(
-                    first, src, build, probe_span
+                    first, src, build, probe_span, device
                 )
             elif route is Route.GID:
                 probe_span.set_attr("gid", "table")
                 _, kernel = self._kernel_for(self.capacity, dense=dense_join)
                 host_states, n_rows_in = self._run_gid(
                     first, seg, src, kernel, build, ck, fusion_retain,
-                    key_encoders, group_table, probe_span, partition,
+                    key_encoders, group_table, probe_span, partition, device,
                 )
         if route is Route.KEYED:
             return (
                 yield from self._execute_keyed(
-                    first, codes, src, ra, key_encoders, ctx, partition
+                    first, codes, src, ra, key_encoders, ctx, partition,
+                    build, chip,
                 )
             )
         if route is Route.CPU_HASH:
@@ -1561,11 +1663,13 @@ class TpuStageExec(ExecutionPlan):
     def _run_gid(
         self, first, seg, src, kernel, build, ck, fusion_retain: bool,
         key_encoders: list, group_table, probe_span, partition: int,
+        device=None,
     ) -> tuple:
         """The gid-table route: ``first`` (None: the source held no row)
         with its segment ids, then every batch left in ``src``, through
         the stage kernel at the initial capacity ``kernel`` was built
-        for.  Returns ``(host states, rows in)``."""
+        for, on ``device`` (None: the default).  Returns ``(host states,
+        rows in)``."""
         import jax
         import jax.numpy as jnp
 
@@ -1654,15 +1758,15 @@ class TpuStageExec(ExecutionPlan):
                 # valid slot and every all-true leaf companion: two
                 # eager ops replace n_pad*(1+n_trivial) host→HBM
                 # bytes
-                tail = jnp.arange(n_pad, dtype=jnp.int32) < n
+                tail = jnp.arange(n_pad, dtype=jnp.int32, device=device) < n
                 args = [
                     tail if i in trivial_idx else a
                     for i, a in enumerate(args)
                 ]
                 seg_d = (
-                    jnp.zeros(n_pad, dtype=jnp.int32)
+                    jnp.zeros(n_pad, dtype=jnp.int32, device=device)
                     if seg is None
-                    else jax.device_put(seg)
+                    else jax.device_put(seg, device)
                 )
                 if ck is not None:
                     # retained for the device cache (and the fused
@@ -1670,11 +1774,16 @@ class TpuStageExec(ExecutionPlan):
                     # pins on device because the entries outlive
                     # this query
                     args = [
-                        a if a is tail else jax.device_put(a)
+                        a if a is tail else jax.device_put(a, device)
                         for a in args
                     ]
                     entries.append((seg_d, tail, args))
                 else:
+                    args = [
+                        jax.device_put(a, device)
+                        if isinstance(a, np.ndarray) else a
+                        for a in args
+                    ]
                     out = kernel(seg_d, tail, *args)
                     acc = K.combine_states(
                         self.specs, acc, out, self._mode
@@ -1715,13 +1824,18 @@ class TpuStageExec(ExecutionPlan):
             )
         return host_states, n_rows_in
 
-    def _run_build_rows(self, first, src, build, probe_span) -> tuple:
+    def _run_build_rows(
+        self, first, src, build, probe_span, device=None
+    ) -> tuple:
         """The build-row route: ``first`` (None: the source held no row)
         and every batch left in ``src`` through ``join_aggregate``, whose
         segment id is the build row a probe row matched, worked out on the
         device: no gid table, no host key encode, no segment ids to upload.
-        Its states are the build side's bucket of slots.  Returns ``(host
-        states, rows in)``; group ``i`` of the states is build row ``i``."""
+        Its states are the build side's bucket of slots.  Everything runs on
+        ``device`` (None: the default), where ``build`` was placed; nothing
+        but the counters is shared with another partition's run.  Returns
+        ``(host states, rows in)``; group ``i`` of the states is build row
+        ``i``."""
         import jax.numpy as jnp
 
         m = len(build[4])
@@ -1754,9 +1868,11 @@ class TpuStageExec(ExecutionPlan):
             # array as handed (an all-true validity reads the mask's byte)
             probe_bytes += n_pad * (1 + sum(a.itemsize for _, a in host))
             with self.metrics.timer("device_time_ns"):
-                tail = jnp.arange(n_pad, dtype=jnp.int32) < n
+                tail = jnp.arange(n_pad, dtype=jnp.int32, device=device) < n
                 args = [
-                    tail if i in trivial_idx else a
+                    tail if i in trivial_idx
+                    else jax.device_put(a, device)
+                    if isinstance(a, np.ndarray) else a
                     for i, a in enumerate(args)
                 ]
                 *out, hit = kernel(tail, *args)
@@ -1789,12 +1905,13 @@ class TpuStageExec(ExecutionPlan):
 
     def _execute_keyed(
         self, first, codes, src, ra, key_encoders: list,
-        ctx: TaskContext, partition: int,
+        ctx: TaskContext, partition: int, build, chip: int,
     ) -> Iterator[pa.RecordBatch]:
         """The keyed route, with :meth:`_execute_device`'s contract:
         device-keyed aggregation (group ids assigned by the device sort,
         no host hash encode) of ``first`` (``codes``: its key codes where
-        the route encoded them) and what is left in ``src``.  Late key
+        the route encoded them) and what is left in ``src``, against the
+        folded join's ``build`` (None: no join) on its chip.  Late key
         overflow, cardinality past the segment ceiling, or device OOM (the
         keyed path buffers the stage input in HBM) leave to the CPU
         operators."""
@@ -1802,7 +1919,7 @@ class TpuStageExec(ExecutionPlan):
         tail = _TrackingIter(src)
         try:
             host_states, groups, n_rows_in, aux = self._run_keyed(
-                [(first, codes)], tail, key_encoders, ctx
+                [(first, codes)], tail, key_encoders, build, chip
             )
             out_batches = list(
                 self._materialize(
@@ -2093,7 +2210,7 @@ class TpuStageExec(ExecutionPlan):
                 out.extend([f"col_{ci}", f"col_{ci}__valid"])
         return tuple(out)
 
-    def _run_keyed(self, first: list, src, key_encoders, ctx: TaskContext):
+    def _run_keyed(self, first: list, src, key_encoders, build, chip: int):
         """Device-keyed aggregation (VERDICT r3 item 2): per batch the
         fused filter/join/project runs and masked scan-form columns
         buffer in HBM alongside the RAW key codes; at stream end ONE
@@ -2102,18 +2219,15 @@ class TpuStageExec(ExecutionPlan):
         returns states + unique key codes.  Host work per batch is one
         astype per key — no hash probe, no factorize.
 
+        The route places nothing itself: it computes where the folded
+        join's ``build`` is (None: no join, the default device).
+
         Returns ``(host_states, _KeyedGroups, n_rows_in, aux)`` where
         ``aux = {"median": [...], "corr": [...]}`` holds the post-sort
         pass results; raises ``ExecutionError`` (keys can't ship) or
         ``_CapacityExceeded`` (cardinality past tpu.max_capacity) for
         the caller's CPU fallback.
         """
-        fused = self.fused
-        build = None
-        if fused.join is not None:
-            # cached by the _execute_device run that chose this route
-            # (an empty build side returns there, before any routing)
-            build = self._prepare_build(ctx)
         dense_join = build is not None and build[0] == "dense"
         kinds = self._key_kinds_for(key_encoders)
         use_kinds = (
@@ -2222,7 +2336,7 @@ class TpuStageExec(ExecutionPlan):
                 pending.clear()
             dispatch_prep(keys_ops, n, trivial_idx, args)
 
-        with self.metrics.timer("tpu_stage_time_ns"):
+        with _StageTimer(self.metrics, chip):
             for batch, codes in first:
                 n_rows_in += batch.num_rows
                 feed(batch, codes)
@@ -2532,35 +2646,48 @@ class TpuStageExec(ExecutionPlan):
                 self._nojoin = cached
             return cached
 
-    def _prepare_build(self, ctx: TaskContext):
-        """Collect + sort the build side once: device arrays for the
-        kernel's searchsorted/gather, host copies for group resolution.
-        Raises _JoinIneligible (→ join on CPU) on non-unique keys or
-        un-shippable key/column ranges."""
+    def _prepare_build(self, ctx: TaskContext, device=None, chip: int = 0):
+        """The folded join's build side as the kernel takes it, on
+        ``device`` (chip ``chip`` of the mesh; None: the default device).
+        Collected, sorted and padded on the host once an instance (device
+        arrays for the kernel's searchsorted/gather, host copies for group
+        resolution); its arrays and the dense table go up to a device the
+        first time a partition there asks.  Raises _JoinIneligible (→ join
+        on CPU) on non-unique keys or un-shippable key/column ranges."""
         with self._build_lock:
-            if self._build_state is not None:
-                return self._build_state
+            state = self._build_states.get(device)
+            if state is not None:
+                return state
             spec = self.fused.join
             batches = []
-            for p in range(spec.build.output_partitioning().n):
-                for b in spec.build.execute(p, ctx):
-                    ctx.check_cancelled()
-                    if b.num_rows:
-                        batches.append(b)
+            if self._build_host is None:
+                for p in range(spec.build.output_partitioning().n):
+                    for b in spec.build.execute(p, ctx):
+                        ctx.check_cancelled()
+                        if b.num_rows:
+                            batches.append(b)
             # join_build_ns / join.build: the build itself (sort, pad,
-            # upload, table), not the child's execute that feeds it
+            # upload, table), not the child's execute that feeds it; on a
+            # further chip, its upload and table alone
             with self.metrics.timer("join_build_ns"), trace.span(
-                "join.build"
+                "join.build", device=chip
             ) as span:
-                state, m_pad = self._build_join_state(spec, batches)
-                span.set_attr("rows", len(state[4]) if m_pad else 0)
+                if self._build_host is None:
+                    self._build_host = self._sort_build(spec, batches)
+                host = self._build_host
+                state = self._place_build(host, device)
+                m_pad = len(host[1]) if host[0] != "empty" else 0
+                span.set_attr("rows", len(host[4]) if m_pad else 0)
                 span.set_attr("capacity", m_pad)
-                span.set_attr("dense", state[0] == "dense")
-            self._build_state = state
+                span.set_attr("dense", host[0] == "dense")
+            self._build_states[device] = state
             return state
 
-    def _build_join_state(self, spec: DeviceJoinSpec, batches: list):
-        """(build state, rows the build side was padded to)."""
+    def _sort_build(self, spec: DeviceJoinSpec, batches: list) -> tuple:
+        """The build side on the host: ``("empty",)``, or ``(kind, keys,
+        padded values, padded validities, sorted keys, sorted table, kmin,
+        table span)`` with kind ``"dense"`` (``keys`` = each row's slot in
+        the dense table) or ``"ok"`` (``keys`` = the sorted keys, padded)."""
         from .bridge import arrow_to_numpy
 
         if batches:
@@ -2586,7 +2713,7 @@ class TpuStageExec(ExecutionPlan):
         table = table.take(pa.array(order))
 
         if len(kv_sorted) == 0:
-            return ("empty",), 0
+            return ("empty",)
 
         # the build side goes up at its bucket, not at its own length, so
         # that the probe kernel's shapes do not follow the rows the build
@@ -2602,12 +2729,10 @@ class TpuStageExec(ExecutionPlan):
             for ci in self._device_build_cols:
                 col = table.column(ci).combine_chunks()
                 vals, validity = arrow_to_numpy(col)
-                bvals.append(
-                    jax.device_put(K._pad(K.coerce_host_values(vals), m_pad))
-                )
+                bvals.append(K._pad(K.coerce_host_values(vals), m_pad))
                 if validity is None:
                     validity = np.ones(len(vals), dtype=bool)
-                bvalids.append(jax.device_put(K._pad(validity, m_pad)))
+                bvalids.append(K._pad(validity, m_pad))
         except ExecutionError as e:
             # un-shippable key/column ranges or types: join on CPU,
             # aggregate on device (not a full-CPU fallback)
@@ -2630,11 +2755,31 @@ class TpuStageExec(ExecutionPlan):
             )
             self.metrics.add("dense_join", 1)
             return (
-                "dense", _dense_join_table(slots, span_b), bvals, bvalids,
-                kv_sorted, table, np.int64(kmin),
-            ), m_pad
-        bkeys = jax.device_put(K._pad(keys_host, m_pad, fill=keys_host[-1]))
-        return ("ok", bkeys, bvals, bvalids, kv_sorted, table), m_pad
+                "dense", slots, bvals, bvalids, kv_sorted, table,
+                np.int64(kmin), span_b,
+            )
+        bkeys = K._pad(keys_host, m_pad, fill=keys_host[-1])
+        return ("ok", bkeys, bvals, bvalids, kv_sorted, table, None, None)
+
+    @staticmethod
+    def _place_build(host: tuple, device) -> tuple:
+        """The kernel's build state on ``device`` (None: the default) from
+        :meth:`_sort_build`'s host arrays: ``("empty",)``, ``("dense",
+        table, values, validities, sorted keys, sorted table, kmin)`` or
+        ``("ok", sorted keys, values, validities, sorted keys, sorted
+        table)``."""
+        if host[0] == "empty":
+            return host
+        kind, keys, bvals, bvalids, kv_sorted, table, kmin, span_b = host
+        bvals = [jax.device_put(v, device) for v in bvals]
+        bvalids = [jax.device_put(v, device) for v in bvalids]
+        keys = jax.device_put(keys, device)
+        if kind == "dense":
+            return (
+                "dense", _dense_join_table(keys, span_b), bvals, bvalids,
+                kv_sorted, table, kmin,
+            )
+        return ("ok", keys, bvals, bvalids, kv_sorted, table)
 
     def _fetch_states(self, acc, n_groups: Optional[int] = None) -> Optional[list]:
         """One packed device→host fetch of the whole state tuple.
@@ -2881,12 +3026,12 @@ class TpuStageExec(ExecutionPlan):
                     .to_numpy(zero_copy_only=False)
                     .astype(np.int64)
                 )
-                bkeys_host = self._build_state[4]
+                bkeys_host = self._build_host[4]
                 jk_positions = np.searchsorted(bkeys_host, jk_vals)
                 jk_positions = np.minimum(
                     jk_positions, max(len(bkeys_host) - 1, 0)
                 )
-            build_table = self._build_state[5]
+            build_table = self._build_host[5]
             ci = fused.join.build_cols[slot]
             vals = build_table.column(ci).take(pa.array(jk_positions))
             if not vals.type.equals(field_t):

@@ -61,3 +61,11 @@ def since(before: dict) -> dict:
     return {
         k: now[k] - before[k] for k in _FIELDS if now[k] != before[k]
     }
+
+
+def credit(counts: dict) -> None:
+    """Add ``counts`` (a :func:`since` taken on another thread) to this
+    thread's totals: what a worker obtained for this thread's task."""
+    c = _counts()
+    for k, v in counts.items():
+        c[k] += v

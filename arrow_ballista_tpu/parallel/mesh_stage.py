@@ -116,27 +116,40 @@ def _close(it) -> None:
         close()
 
 
-def _in_partition_order(prepare, n_parts: int, width: int, stop):
+def _in_partition_order(
+    prepare, n_parts: int, width: int, stop, name: str = "gang"
+):
     """``prepare(p)`` for p = 0 .. n_parts-1, results in that order, up to
     ``width`` partitions in the making side by side (in flight or done
     and waiting their turn), one more being handed over.  An error of
     ``prepare(p)`` is raised when p's turn comes.  Closing the generator
     -- after the last result, an error or the consumer's own exit -- sets
     ``stop`` and joins every worker, so no thread outlives the stage.
-    Width 1 runs ``prepare`` inline on the caller's thread."""
+    Width 1 runs ``prepare`` inline on the caller's thread.  The workers
+    are named ``<name>_<n>`` after their caller.  What XLA compiled for a
+    partition on a worker (a device stage below an exchange) is credited
+    to the caller's thread at that partition's hand-over, where the task's
+    compile counters are read."""
     if width <= 1:
         for p in range(n_parts):
             yield prepare(p)
         return
-    pool = ThreadPoolExecutor(width, thread_name_prefix="gang")
+    from ..ops import xla_meter
+
+    def metered(p: int) -> tuple:
+        before = xla_meter.snapshot()
+        return prepare(p), xla_meter.since(before)
+
+    pool = ThreadPoolExecutor(width, thread_name_prefix=name)
     todo = iter(range(n_parts))
     pending: deque = deque()
     try:
-        pending.extend(pool.submit(prepare, p) for p in islice(todo, width))
+        pending.extend(pool.submit(metered, p) for p in islice(todo, width))
         while pending:
-            part = pending.popleft().result()
+            part, compiled = pending.popleft().result()
+            xla_meter.credit(compiled)
             # top up before the hand-over, so no worker idles through it
-            pending.extend(pool.submit(prepare, p) for p in islice(todo, 1))
+            pending.extend(pool.submit(metered, p) for p in islice(todo, 1))
             yield part
     finally:
         stop.set()
@@ -805,18 +818,39 @@ def _byte_groups(batches: list, limit: Optional[int]) -> Iterator[list]:
         yield group
 
 
-def _holds_device_stage(plan: ExecutionPlan) -> bool:
-    """Does this subtree hold an operator that runs on the device?"""
+def _device_stages(plan: ExecutionPlan) -> list:
+    """The operators of this subtree that run on the device."""
     from ..ops.stage_compiler import TpuStageExec
     from ..ops.window_compiler import TpuWindowExec
 
-    stack = [plan]
+    found, stack = [], [plan]
     while stack:
         node = stack.pop()
         if isinstance(node, (TpuStageExec, TpuWindowExec)):
-            return True
+            found.append(node)
         stack.extend(node.children())
-    return False
+    return found
+
+
+def _exchange_width(
+    plan: ExecutionPlan, ctx: TaskContext, n_parts: int, n_dev: int
+) -> int:
+    """How many of ``plan``'s partitions an exchange over ``n_dev`` chips
+    prepares side by side: the gang stage's rule (``_gang_width``) over
+    host operators; over device stages, no more than one a chip, and only
+    where every one of them runs its partitions apart
+    (``TpuStageExec.partitions_apart``: the build-row route, each partition
+    on its own chip); 1 (inline) otherwise, and always on one chip."""
+    from ..ops.stage_compiler import TpuStageExec
+
+    stages = _device_stages(plan)
+    if not stages:
+        return _gang_width(ctx, n_parts)
+    if n_dev > 1 and all(
+        isinstance(s, TpuStageExec) and s.partitions_apart(ctx) for s in stages
+    ):
+        return min(n_dev, _gang_width(ctx, n_parts))
+    return 1
 
 
 class MeshExchangeError(Exception):
@@ -866,13 +900,15 @@ class MeshRepartitionExec(ExecutionPlan):
     already costs the program).
 
     The host side works a partition at a time: a small pool of workers
-    (``_gang_width``; inline where the input holds a device stage) pulls
-    the input partitions side by side, each coalescing, hashing and
-    flattening its own partition once; the task thread takes them in
-    partition order, concatenates them and makes the one device call, so
-    the rows go up in the order a sequential read would give.  The whole
-    input is buffered on the host, so ``mesh.exchange_max_rows`` bounds
-    it: the workers sum the rows pulled at every batch and all stop
+    (``_exchange_width``: the gang stage's rule over host operators; over a
+    device stage on the build-row route, a worker a chip, each partition
+    running on its own chip; inline over any other device stage, and on
+    one chip) pulls the input partitions side by side, each coalescing,
+    hashing and flattening its own partition once; the task thread takes
+    them in partition order, concatenates them and makes the one device
+    call, so the rows go up in the order a sequential read would give.
+    The whole input is buffered on the host, so ``mesh.exchange_max_rows``
+    bounds it: the workers sum the rows pulled at every batch and all stop
     pulling when the sum passes the ceiling (MeshExchangeError → writer
     fallback).
 
@@ -949,10 +985,12 @@ class MeshRepartitionExec(ExecutionPlan):
         pull), coalesce them once at the Arrow level, hash the
         destinations once, flatten to numpy once, strings against the
         partition's OWN dictionaries.  Workers share nothing mutable but
-        the rows pulled so far, a slot a partition; the width is the gang
-        stage's rule, and 1 (inline) where the input holds a device stage:
-        running that stage's partitions side by side is its own question
-        (they share one instance's join build and gid table).
+        the rows pulled so far, a slot a partition; the width is
+        ``_exchange_width``'s: the gang stage's rule over host operators, a
+        worker a chip over a device stage whose partitions run apart (the
+        build-row route: partition ``p`` on chip ``p % n_dev``, its states
+        its own, the build side placed on each chip once under the stage's
+        lock), and 1 (inline) over any other device stage and on one chip.
         HAND OVER (this thread, in partition order): the row ceiling on
         the running count, and the partition's dictionaries mapped into
         the layout's.  What is buffered: every prepared partition's numpy
@@ -970,11 +1008,11 @@ class MeshRepartitionExec(ExecutionPlan):
         n_out = self.partitioning.n
         exprs = list(self.partitioning.exprs)
         n_parts = self.input.output_partitioning().n
-        width = (
-            1 if _holds_device_stage(self.input)
-            else _gang_width(ctx, n_parts)
-        )
+        n_dev = _mesh_width(self.n_devices, ctx)
+        width = _exchange_width(self.input, ctx, n_parts, n_dev)
         add("exchange_workers", width)
+        # over a device stage, the chip each input partition runs on
+        on_chip = bool(_device_stages(self.input))
         # the exchange buffers the stage input in host memory (~2x resident
         # plus device staging): a row ceiling keeps huge shuffles on the
         # streaming hash-split path instead of OOMing this task
@@ -1008,6 +1046,7 @@ class MeshRepartitionExec(ExecutionPlan):
                 part_span = trace.span(
                     "exchange.partition", parent=stage_ctx, partition=p,
                     worker=threading.current_thread().name,
+                    **({"device": p % n_dev} if on_chip else {}),
                 )
             pull_ns = hash_ns = convert_ns = 0
             out = _ExchangePart()
@@ -1077,7 +1116,7 @@ class MeshRepartitionExec(ExecutionPlan):
         prepared: list = []
         rows_seen = merge_ns = 0
         with contextlib.closing(
-            _in_partition_order(prepare, n_parts, width, stop)
+            _in_partition_order(prepare, n_parts, width, stop, "exchange")
         ) as in_order:
             for p in range(n_parts):
                 hand_span = trace.NOOP

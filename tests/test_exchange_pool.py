@@ -6,7 +6,8 @@ Held here: the exchanged batches are, row for row, what the per-batch loop
 it replaces gave, at every width; an error surfaces at its partition's
 turn, a cancel ends the stage, and either way every input iterator is
 closed and every worker joined; the row ceiling still raises and the writer
-still falls back; a device stage below is prepared inline."""
+still falls back; a device stage below is prepared inline on one chip, and
+a worker a chip where its partitions run apart on several."""
 
 import datetime
 import threading
@@ -44,7 +45,8 @@ def _force_width(monkeypatch, width):
 
 
 def _gang_threads():
-    return [t for t in threading.enumerate() if t.name.startswith("gang")]
+    """The pool's workers still alive (an exchange names its own)."""
+    return [t for t in threading.enumerate() if t.name.startswith(("gang", "exchange"))]
 
 
 def _batch(rng, n, shift=0):
@@ -287,9 +289,9 @@ def test_an_error_stops_the_partitions_still_in_the_making(monkeypatch):
     seen = {}
     real = mesh_stage._in_partition_order
 
-    def spy(prepare, n_parts, width, stop):
+    def spy(prepare, n_parts, width, stop, *name):
         seen["stop"] = stop
-        return real(prepare, n_parts, width, stop)
+        return real(prepare, n_parts, width, stop, *name)
 
     def on_batch(p, i):
         if (p, i) == (0, 1):
@@ -394,33 +396,50 @@ def test_byte_groups_keep_the_order_and_the_limit():
 
 
 # --------------------------------------- (e) what engages the pool, and not
-def test_a_device_stage_below_is_prepared_inline(monkeypatch):
+def _q3_join_stage(devices: int):
+    """q3's folded join (its groups are its build rows) over four input
+    partitions, as a session with ``devices`` chips in its mesh plans it."""
     from arrow_ballista_tpu.ops.stage_compiler import TpuStageExec
+    from benchmarks.tpch import datagen
+    from benchmarks.tpch.queries import QUERIES
 
-    _force_width(monkeypatch, 8)
-    cfg = BallistaConfig({"ballista.tpu.min_rows": "0", "ballista.mesh.enable": "false",
-                          "ballista.shuffle.partitions": "2"})
+    cfg = BallistaConfig({"ballista.tpu.min_rows": "0", "ballista.mesh.devices": str(devices),
+                          "ballista.shuffle.partitions": "4"})
     ctx = SessionContext(cfg)
-    ctx.register_table("t", MemoryTable(_ragged_partitions(), SCHEMA))
-    plan = ctx.sql("select s, sum(v) as sv, count(*) as c from t group by s").physical_plan()
+    for t in ("customer", "orders", "lineitem"):
+        ctx.register_table(t, MemoryTable.from_table(datagen.gen_table(t, 0.005), 4))
+    plan = ctx.sql(QUERIES[3].split("order by")[0]).physical_plan()
     stack, stages = [plan], []
     while stack:
         n = stack.pop()
-        if isinstance(n, TpuStageExec) and n.output_partitioning().n > 1:
+        if isinstance(n, TpuStageExec) and n.fused.join is not None:
             stages.append(n)
         stack.extend(n.children())
     (stage,) = stages
-    assert mesh_stage._holds_device_stage(plan) and not mesh_stage._holds_device_stage(_node(MemoryTable([], SCHEMA))[0])
-    node = MeshRepartitionExec(stage, Partitioning("hash", 2, (pe.Col(0, "s"),)))
-    got = list(node.execute_exchanged(TaskContext(config=cfg)))
-    assert node.metrics.to_dict()["exchange_workers"] == 1 and _gang_threads() == []
-    # the partial states of every input partition, each on the side its key hashes to
-    distinct = sum(len({s for b in p for s in b.column(4).to_pylist()}) for p in _ragged_partitions())
-    assert sum(b.num_rows for _, b in got) == distinct
-    sides = {}
-    for p, b in got:
-        for s in b.column(0).to_pylist():
-            assert sides.setdefault(s, p) == p
+    assert stage.output_partitioning().n == 4
+    return stage, cfg
+
+
+@pytest.mark.parametrize("devices,width", [(1, 1), (4, 4)], ids=["one-chip-inline", "a-worker-a-chip"])
+def test_a_device_stage_below_is_prepared_inline(monkeypatch, devices, width):
+    """One chip: the device stage's partitions run inline, as before.  Four:
+    its partitions run apart (the build-row route), a worker a chip, and
+    the exchanged batches are the inline run's, row for row."""
+    _force_width(monkeypatch, 8)
+    got = {}
+    for d in sorted({1, devices}):
+        stage, cfg = _q3_join_stage(d)
+        assert mesh_stage._device_stages(stage) == [stage]
+        assert stage.partitions_apart(TaskContext(config=cfg))  # the route, on any chips
+        node = MeshRepartitionExec(stage, Partitioning("hash", 4, (pe.Col(0, "l_orderkey"),)))
+        got[d] = list(node.execute_exchanged(TaskContext(config=cfg)))
+        assert _gang_threads() == []
+        m = node.metrics.to_dict()
+        assert m["exchange_workers"] == (width if d == devices else 1) and m["mesh_devices"] == d
+    assert sum(b.num_rows for _, b in got[1]) > 0
+    _same_sequence(got[devices], got[1])
+    # no device stage below: the gang stage's rule, whatever the chips
+    assert mesh_stage._device_stages(_node(MemoryTable([], SCHEMA))[0]) == []
 
 
 def test_width_follows_what_the_gang_stage_observes(monkeypatch):
@@ -461,7 +480,7 @@ def test_partition_and_handover_spans_hang_under_the_tasks_span(monkeypatch, wid
         assert a["rows"] == sum(_kept(b) for b in p)
         assert a["batches"] == sum(1 for b in p if _kept(b))
         assert a["pull_ns"] > 0 and (a["convert_ns"] > 0) == bool(a["rows"]) == (a["hash_ns"] > 0)
-        assert a["worker"].startswith("gang") == (width > 1)
+        assert a["worker"].startswith("exchange_") == (width > 1)
     assert all(s["attrs"]["wait_ns"] >= 0 for s in handed)
     # the older three stay where they were
     for name in ("exchange.encode", "exchange.device", "exchange.decode"):

@@ -203,7 +203,7 @@ def test_build_side_at_a_bucket_edge_joins_as_the_unpadded_numpy_join(m, wide):
     got = stage.metrics.to_dict()
     assert not any(got.get(k) for k in OFF_ROUTE + ("cpu_fallback",))
     if m == 0:
-        assert stage._build_state == ("empty",)
+        assert list(stage._build_states.values()) == [("empty",)]
         return
     # a pad row matched no probe key: c above is the unpadded join's count
     # although the probe asks for the key the pad rows repeat and for keys
@@ -211,7 +211,7 @@ def test_build_side_at_a_bucket_edge_joins_as_the_unpadded_numpy_join(m, wide):
     assert got["join_build_rows"] == m and got["join_build_capacity"] == K.bucket_rows(m)
     wide = wide and m > 1  # one key spans one slot: always the dense table
     assert got.get("dense_join", 0) == (0 if wide else 1)
-    state = stage._build_state
+    (state,) = stage._build_states.values()  # one partition, one chip
     assert state[0] == ("ok" if wide else "dense")
     assert all(int(a.shape[0]) == K.bucket_rows(m) for a in state[2] + state[3])
     assert all(not bool(np.asarray(v)[m:].any()) for v in state[3])  # pad columns read invalid
