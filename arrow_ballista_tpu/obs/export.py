@@ -345,6 +345,7 @@ _EXCHANGE_COUNTERS = (
     ("exchange_workers", "exchange_workers"),
     ("exchange_wait_ns", "exchange_wait_ms"),
     ("exchange_pull_ns", "exchange_pull_ms"),
+    ("exchange_pull_batches", "exchange_pull_batches"),
     ("repart_time_ns", "exchange_hash_ms"),
     ("exchange_convert_ns", "exchange_convert_ms"),
     ("exchange_encode_ns", "exchange_encode_ms"),

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import dataclasses
 import functools
 import os
 import threading
@@ -34,6 +35,7 @@ from typing import Iterator, Optional
 import numpy as np
 import pyarrow as pa
 
+from ..config import BATCH_SIZE
 from ..exec.operators import ExecutionPlan, Partitioning, TaskContext
 from ..obs import trace
 
@@ -818,6 +820,30 @@ def _byte_groups(batches: list, limit: Optional[int]) -> Iterator[list]:
         yield group
 
 
+# An exchange's workers pull an input of host operators this many rows a
+# batch: the rows of a parquet row group as pyarrow's writer cuts one by
+# default (its reader then fills a batch across row groups).  The
+# worker coalesces its whole partition anyway, so the batch size only sets
+# how often the scan and the filter return to Python, and each return waits
+# to win the GIL back from every other thread of the process: at 8,192 rows
+# a lineitem partition of 5M rows made 611 such trips, and the filter cost
+# several times more side by side than alone.
+_PULL_ROWS = 1 << 20
+
+
+def _pull_context(ctx: TaskContext) -> TaskContext:
+    """``ctx`` with a batch size of at least ``_PULL_ROWS`` rows, for an
+    exchange's workers to pull an input of host operators with.  Only
+    that pull: every other operator keeps the session's batch size, and
+    over a device stage it is part of the stage's program signature."""
+    if ctx.batch_size >= _PULL_ROWS:
+        return ctx
+    settings = {**ctx.config.settings, BATCH_SIZE: str(_PULL_ROWS)}
+    return dataclasses.replace(
+        ctx, config=dataclasses.replace(ctx.config, settings=settings)
+    )
+
+
 def _device_stages(plan: ExecutionPlan) -> list:
     """The operators of this subtree that run on the device."""
     from ..ops.stage_compiler import TpuStageExec
@@ -907,6 +933,11 @@ class MeshRepartitionExec(ExecutionPlan):
     hashing and flattening its own partition once; the task thread takes
     them in partition order, concatenates them and makes the one device
     call, so the rows go up in the order a sequential read would give.
+    A worker pulls an input of host operators a parquet row group's rows
+    at a time (``_PULL_ROWS``), not at the session's batch size, which
+    would only send the scan and the filter back through Python and the
+    GIL every few thousand rows; over a device stage it pulls at the
+    session's, which is part of the stage's program signature.
     The whole input is buffered on the host, so ``mesh.exchange_max_rows``
     bounds it: the workers sum the rows pulled at every batch and all stop
     pulling when the sum passes the ceiling (MeshExchangeError → writer
@@ -982,7 +1013,10 @@ class MeshRepartitionExec(ExecutionPlan):
         The partition is the unit of the host work.  PREPARE (a worker, up
         to ``width`` partitions side by side): pull the partition's
         batches from the input (scan, decode and filter run inside that
-        pull), coalesce them once at the Arrow level, hash the
+        pull; over host operators at ``_PULL_ROWS`` rows a batch, since
+        the partition is coalesced whole anyway, and over a device stage
+        at the session's batch size, part of the stage's program), coalesce
+        them once at the Arrow level, hash the
         destinations once, flatten to numpy once, strings against the
         partition's OWN dictionaries.  Workers share nothing mutable but
         the rows pulled so far, a slot a partition; the width is
@@ -998,7 +1032,9 @@ class MeshRepartitionExec(ExecutionPlan):
         its partition's Arrow batches until they are flattened; the
         workers sum the rows pulled at every batch and all stop pulling
         when the sum passes the ceiling, so no more than the ceiling and a
-        batch or two a worker is ever held."""
+        batch or two a worker (a row group each over host operators) is
+        ever held.  ``exchange_pull_batches`` counts the non-empty batches
+        the workers pulled, summed over them."""
         from ..errors import Cancelled, ExecutionError
         from ..ops.bridge import _concat_batches
         from ..shuffle.execution_plans import partition_indices
@@ -1013,6 +1049,7 @@ class MeshRepartitionExec(ExecutionPlan):
         add("exchange_workers", width)
         # over a device stage, the chip each input partition runs on
         on_chip = bool(_device_stages(self.input))
+        pull_ctx = ctx if on_chip else _pull_context(ctx)
         # the exchange buffers the stage input in host memory (~2x resident
         # plus device staging): a row ceiling keeps huge shuffles on the
         # streaming hash-split path instead of OOMing this task
@@ -1055,7 +1092,7 @@ class MeshRepartitionExec(ExecutionPlan):
             with part_span:
                 try:
                     check_stop()
-                    it = iter(self.input.execute(p, ctx))
+                    it = iter(self.input.execute(p, pull_ctx))
                     while True:
                         batch, ns = _pull(it)
                         pull_ns += ns
@@ -1103,6 +1140,7 @@ class MeshRepartitionExec(ExecutionPlan):
                 finally:
                     _close(it)
                     add("exchange_pull_ns", pull_ns)
+                    add("exchange_pull_batches", len(batches))
                     add("repart_time_ns", hash_ns)
                     add("exchange_convert_ns", convert_ns)
                     if traced:
@@ -1170,6 +1208,7 @@ class MeshRepartitionExec(ExecutionPlan):
         bucket count), ``device_time_ns``, ``exchange_decode_ns``; summed
         over the workers (at width 1 this thread, inside its wait)
         ``exchange_pull_ns`` (inside ``next()`` on the input),
+        ``exchange_pull_batches`` (the non-empty batches pulled),
         ``repart_time_ns`` (destination hash), ``exchange_convert_ns``
         (coalesce + flatten)."""
         from ..ops import kernels as K
