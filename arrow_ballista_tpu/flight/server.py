@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Optional
 
 import pyarrow as pa
@@ -67,35 +66,32 @@ class ShuffleFlightService(flight.FlightServerBase):
 
     @staticmethod
     def _traced_stream(batches, trace_id: str, parent: str, path: str):
-        """Wrap a batch stream so the serving window is one span in the
-        CALLER's trace (closed when the stream drains or breaks).
-        Items may be bare RecordBatches or ``(batch, app_metadata)``
-        tuples (the multi-partition stream tags each batch with its
-        partition index)."""
-        t0_unix, t0_mono = time.time_ns(), time.monotonic_ns()
+        """Wrap a batch stream so the serving window is one span (closed
+        when the stream drains or breaks): in the CALLER's trace, and in
+        the profiler's while one records; the stream itself where
+        neither does.  Items may be bare RecordBatches or ``(batch,
+        app_metadata)`` tuples (the multi-partition stream tags each
+        batch with its partition index)."""
+        with trace.activate(trace_id, parent):
+            sp = trace.manual_span("flight.do_get", path=path)
+        if sp is trace.NOOP_MANUAL:
+            return batches
+        return ShuffleFlightService._spanned(batches, sp)
+
+    @staticmethod
+    def _spanned(batches, sp):
         nbytes = 0
-        error = ""
         try:
             for b in batches:
                 data = b[0] if isinstance(b, tuple) else b
                 nbytes += int(getattr(data, "nbytes", 0) or 0)
                 yield b
         except BaseException as e:
-            error = f"{type(e).__name__}: {e}"
+            sp.set_attr("error", f"{type(e).__name__}: {e}")
             raise
         finally:
-            attrs = {"path": path, "bytes": nbytes}
-            if error:
-                attrs["error"] = error
-            trace.record_raw(
-                "flight.do_get",
-                trace_id,
-                trace.new_id(),
-                parent or trace_id,
-                t0_unix,
-                time.monotonic_ns() - t0_mono,
-                **attrs,
-            )
+            sp.set_attr("bytes", nbytes)
+            sp.finish()
 
     # ------------------------------------------------------------ sources
     def _open_file_reader(self, raw_path: str):
@@ -190,11 +186,7 @@ class ShuffleFlightService(flight.FlightServerBase):
                 with pa.ipc.open_stream(buf) as reader:
                     yield from reader
 
-            stream = mem_gen()
-            if trace_id and trace.is_enabled():
-                stream = self._traced_stream(
-                    stream, trace_id, parent, msg.path
-                )
+            stream = self._traced_stream(mem_gen(), trace_id, parent, msg.path)
             return flight.GeneratorStream(schema, stream)
         source, reader = self._open_file_reader(msg.path)
 
@@ -205,9 +197,7 @@ class ShuffleFlightService(flight.FlightServerBase):
             finally:
                 source.close()
 
-        stream = gen()
-        if trace_id and trace.is_enabled():
-            stream = self._traced_stream(stream, trace_id, parent, msg.path)
+        stream = self._traced_stream(gen(), trace_id, parent, msg.path)
         return flight.GeneratorStream(reader.schema, stream)
 
     def _do_get_multi(self, paths, trace_id: str, parent: str):
@@ -229,11 +219,9 @@ class ShuffleFlightService(flight.FlightServerBase):
                 for batch in self._iter_source(path):
                     yield batch, tag
 
-        stream = gen()
-        if trace_id and trace.is_enabled():
-            stream = self._traced_stream(
-                stream, trace_id, parent, f"[{len(paths)} partitions]"
-            )
+        stream = self._traced_stream(
+            gen(), trace_id, parent, f"[{len(paths)} partitions]"
+        )
         return flight.GeneratorStream(schema, stream)
 
 

@@ -350,6 +350,11 @@ _EXCHANGE_COUNTERS = (
     ("exchange_convert_ns", "exchange_convert_ms"),
     ("exchange_encode_ns", "exchange_encode_ms"),
     ("device_time_ns", "exchange_device_ms"),
+    # the device call's four phases, one after the other: they sum to it
+    ("exchange_pad_ns", "exchange_pad_ms"),
+    ("exchange_upload_ns", "exchange_upload_ms"),
+    ("exchange_program_ns", "exchange_program_ms"),
+    ("exchange_fetch_ns", "exchange_fetch_ms"),
     ("exchange_decode_ns", "exchange_decode_ms"),
     # PR 35: exchanges whose program placed rows by output partition
     ("exchange_grouped", "exchange_grouped"),

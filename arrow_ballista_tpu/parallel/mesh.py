@@ -12,6 +12,7 @@ plane (flight/, shuffle/).
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -19,7 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import trace
+
 DATA_AXIS = "dp"
+
+# the four phases of an exchange call, in the order they run
+# (``BatchExchanger.exchange``)
+EXCHANGE_PHASES = ("pad", "upload", "program", "fetch")
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = DATA_AXIS) -> Mesh:
@@ -349,17 +356,43 @@ class BatchExchanger(ExchangeLayout):
         return self.flatten(batch, self.encoders)
 
     # ------------------------------------------------------------ exchange
-    def exchange(self, part: np.ndarray, valid: np.ndarray, cols):
+    def exchange(
+        self, part: np.ndarray, valid: np.ndarray, cols,
+        laps: Optional["PhaseClock"] = None,
+    ):
         """Run the sharded exchange; returns (recv_cols, recv_valid,
         n_dropped) as host arrays.  ``part`` is each row's output
         partition.  The inputs go up padded to :func:`exchange_rows`; a pad
         row is ``valid = False``, which the program sends to the sentinel
-        partition and delivers nowhere."""
+        partition and delivers nowhere.
+
+        Four phases, one after the other, each ended by a lap of ``laps``
+        (a :class:`PhaseClock`; the time since its last read goes to the
+        phase) and each a child span of the caller's: ``pad`` every array
+        to the padded rows (``exchange.pad``), ``upload`` every array and
+        wait until all are on the mesh (``exchange.upload``, attr
+        ``bytes``), ``program`` dispatch and wait for its outputs
+        (``exchange.program``), ``fetch`` every output to the host
+        (``exchange.fetch``, attr ``bytes``).  The two waits keep the copy
+        up out of the program's time and the program out of the fetch's;
+        the program cannot start before its inputs are there anyway, and
+        the first fetch waited for it before."""
+        laps = PhaseClock() if laps is None else laps
         rows = exchange_rows(len(part), self.mesh.devices.size)
-        sharded = shard_batch(self.mesh, [part, valid] + list(cols), rows)
-        out = self._fn(*sharded)
-        host = [np.asarray(o) for o in out[:-1]]
-        return host[:-1], host[-1], int(np.asarray(out[-1]))
+        with trace.span("exchange.pad", rows=rows):
+            padded = pad_batch(self.mesh, [part, valid] + list(cols), rows)
+        laps.lap("pad")
+        with trace.span("exchange.upload", bytes=sum(a.nbytes for a in padded)):
+            placed = jax.block_until_ready(shard_batch(self.mesh, padded, rows))
+        laps.lap("upload")
+        with trace.span("exchange.program"):
+            out = jax.block_until_ready(self._fn(*placed))
+        laps.lap("program")
+        with trace.span("exchange.fetch") as fetch_span:
+            host = [np.asarray(o) for o in out]
+            fetch_span.set_attr("bytes", sum(h.nbytes for h in host))
+        laps.lap("fetch")
+        return host[:-2], host[-2], int(host[-1])
 
     # ------------------------------------------------------------- → host
     def partition_batches(self, recv_cols, counts):
@@ -644,18 +677,46 @@ def exchange_rows(total: int, n_dev: int) -> int:
     return -(-K.bucket_rows(total) // n_dev) * n_dev
 
 
+def pad_batch(
+    mesh: Mesh, arrays: Sequence[np.ndarray], rows: Optional[int] = None
+) -> list[np.ndarray]:
+    """Host arrays padded (``kernels._pad``: zeros, so a padded validity
+    column reads False) to ``rows``, or to the next multiple of the mesh;
+    an array already that long is itself."""
+    from ..ops import kernels as K
+
+    n_dev = mesh.devices.size
+    return [
+        K._pad(a, rows if rows is not None else -(-len(a) // n_dev) * n_dev)
+        for a in arrays
+    ]
+
+
 def shard_batch(
     mesh: Mesh, arrays: Sequence[np.ndarray], rows: Optional[int] = None
 ) -> list[jax.Array]:
-    """Place host arrays onto the mesh sharded along the row axis, padded
-    (``kernels._pad``: zeros, so a padded validity column reads False) to
-    ``rows``, or to the next multiple of the mesh."""
-    from ..ops import kernels as K
-
+    """Place host arrays onto the mesh sharded along the row axis: every
+    array padded first (:func:`pad_batch`), then every one put.  The puts
+    are asynchronous: ``jax.block_until_ready`` on the result waits for
+    the copies."""
     sharding = NamedSharding(mesh, P(DATA_AXIS))
-    n_dev = mesh.devices.size
-    out = []
-    for a in arrays:
-        n = rows if rows is not None else -(-len(a) // n_dev) * n_dev
-        out.append(jax.device_put(K._pad(a, n), sharding))
-    return out
+    return [jax.device_put(a, sharding) for a in pad_batch(mesh, arrays, rows)]
+
+
+class PhaseClock:
+    """Consecutive reads of one clock, each charging the time since the
+    read before to a phase of :data:`EXCHANGE_PHASES`: the phases split
+    the interval from ``start`` to the last read exactly."""
+
+    __slots__ = ("ns", "last")
+
+    def __init__(self, start: Optional[int] = None):
+        self.ns = dict.fromkeys(EXCHANGE_PHASES, 0)
+        self.last = time.perf_counter_ns() if start is None else start
+
+    def lap(self, phase: str) -> int:
+        """Charge the time since the last read to ``phase``; the read."""
+        now = time.perf_counter_ns()
+        self.ns[phase] += now - self.last
+        self.last = now
+        return now

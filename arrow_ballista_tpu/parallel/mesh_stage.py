@@ -424,7 +424,6 @@ class MeshGangExec(ExecutionPlan):
                     add("gang_scan_ns", scan_ns)
                     add("key_encode_time_ns", encode_ns)
                     add("gang_convert_ns", convert_ns)
-                    add("bridge_time_ns", convert_ns)
                     add("gang_batches", len(batches))
                     if traced:
                         for k, v in (
@@ -497,7 +496,6 @@ class MeshGangExec(ExecutionPlan):
                             add("gang_wait_ns", wait_ns)
                             add("gang_merge_ns", merge_ns)
                             add("gang_upload_ns", upload_ns)
-                            add("bridge_time_ns", upload_ns)
                             add("gang_uploads", uploads)
                             add("gang_upload_bytes", upload_bytes)
                             add("gang_partitions", 1)
@@ -563,7 +561,6 @@ class MeshGangExec(ExecutionPlan):
         t2 = clock()
         add("gang_assemble_ns", t1 - t0)
         add("gang_step_ns", t2 - t1)
-        add("device_time_ns", t2 - t0)
         add("mesh_rows_in", n_rows)
         add("mesh_devices", n_dev)
         return Route.GID, self._timed_materialize(
@@ -1058,8 +1055,9 @@ class MeshRepartitionExec(ExecutionPlan):
         # worker alone (no lock for 18 threads to queue behind), and the
         # worker whose write comes last sees every other in its sum
         pulled = [0] * n_parts
-        # the exchange.* spans hang under the task's current span
-        stage_ctx = trace.current_context() if trace.is_enabled() else None
+        # the exchange.* spans hang under the task's current span, in the
+        # recorder's trace and, while a profiler records, in its trace
+        stage_ctx = trace.current_context()
         traced = stage_ctx is not None
         stop = threading.Event()
 
@@ -1205,7 +1203,11 @@ class MeshRepartitionExec(ExecutionPlan):
         (blocked until the next partition IN ORDER is prepared),
         ``exchange_encode_ns`` (what is left of the encode there:
         dictionaries mapped at the hand-over, the final concatenate, the
-        bucket count), ``device_time_ns``, ``exchange_decode_ns``; summed
+        bucket count), ``device_time_ns`` (the exchange calls, capacity
+        growths included) split exactly into ``exchange_pad_ns``,
+        ``exchange_upload_ns``, ``exchange_program_ns`` and
+        ``exchange_fetch_ns`` (``BatchExchanger.exchange``'s phases),
+        ``exchange_decode_ns``; summed
         over the workers (at width 1 this thread, inside its wait)
         ``exchange_pull_ns`` (inside ``next()`` on the input),
         ``exchange_pull_batches`` (the non-empty batches pulled),
@@ -1270,6 +1272,9 @@ class MeshRepartitionExec(ExecutionPlan):
                     mesh, schema, cap, share_from=layout, n_out=width
                 )
             t1 = clock()
+            # the call's phases split t1..t2 exactly; a growth's set-up
+            # goes to the next pad, the span's close to the last fetch
+            laps = M.PhaseClock(t1)
             growths = 0
             with trace.span(
                 "exchange.device", rows=total, padded_rows=rows - total,
@@ -1277,7 +1282,7 @@ class MeshRepartitionExec(ExecutionPlan):
             ) as dev_span:
                 while True:
                     recv_cols, recv_valid, n_dropped = ex.exchange(
-                        route, valid, cols
+                        route, valid, cols, laps
                     )
                     if n_dropped == 0:
                         break
@@ -1292,7 +1297,7 @@ class MeshRepartitionExec(ExecutionPlan):
                     )
                 dev_span.set_attr("capacity", cap)
                 dev_span.set_attr("growths", growths)
-            t2 = clock()
+            t2 = laps.lap("fetch")
             with trace.span("exchange.decode"):
                 received = ex.partition_batches(recv_cols, counts)
                 out = (
@@ -1301,6 +1306,8 @@ class MeshRepartitionExec(ExecutionPlan):
                 )
             add("exchange_encode_ns", merge_ns + t1 - t0)
             add("device_time_ns", t2 - t1)
+            for phase, ns in laps.ns.items():
+                add(f"exchange_{phase}_ns", ns)
             add("exchange_decode_ns", clock() - t2)
             add("exchange_grouped", int(grouped))
             if growths:

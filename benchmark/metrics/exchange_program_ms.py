@@ -1,0 +1,15 @@
+"""``exchange_program_ns`` of ``MeshRepartitionExec``: the exchange program's
+dispatch, then one wait for its outputs; the device trace's
+``jit_local_exchange`` is the same program's device time. Summed over a
+query's exchanges that moved rows, mean over the queries that have one; one
+of the four phases that sum to ``exchange_ms`` exactly.  None on a program
+without the counter."""
+
+from benchmark.metrics import _exchange_phase
+
+UNIT, BETTER, SOURCE = "ms", "lower", "program_counter"
+LAYER, MOVES = "exchange", "query_geomean_s"
+
+
+def read(run):
+    return _exchange_phase.read(run, "exchange_program_ns")

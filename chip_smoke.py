@@ -50,13 +50,17 @@ TABLES = ("lineitem", "orders", "customer")
 BUDGET_S = 1200  # the contract's limit, compilation included
 # operator rows of the job detail that are device stages
 DEVICE_OPS = ("TpuStageExec", "MeshGangExec", "TpuWindowExec")
+# a device stage's time on the device: TpuStageExec's device_time_ns, the
+# gang's two device phases (assemble + step)
+DEVICE_NS = ("device_time_ns", "gang_assemble_ns", "gang_step_ns")
 # counters lifted from the raw operator metrics into each run's summary
 COUNTERS = (
     "device_error", "cpu_fallback", "tpu_fallback", "mesh_fallback",
     "join_fallback", "highcard_fallback", "keyed_path",
     "mesh_exchange_fallback", "mesh_exchange_rows", "mesh_devices",
     "mesh_rows_in", "cache_hits", "capacity_growths",
-    "device_time_ns", "tpu_execute_ns", "tpu_compile_ns", "kernel_compiles",
+    "device_time_ns", "gang_assemble_ns", "gang_step_ns",
+    "tpu_execute_ns", "tpu_compile_ns", "kernel_compiles",
     "compile_cache_misses", "xla_compiles", "xla_compile_ns",
     "xla_cache_hits",
 )
@@ -324,7 +328,7 @@ def run_query(ctx, rest_port: int, sql: str) -> tuple:
             f"stage{s['stage_id']}:{op}"
             for s in stages
             for op, vals in s["ops"].items()
-            if op in DEVICE_OPS and vals.get("device_time_ns", 0) > 0
+            if op in DEVICE_OPS and sum(vals.get(k, 0) for k in DEVICE_NS) > 0
         ],
         "counters": {k: v for k, v in totals.items() if v},
         "gang_mesh_devices": by_op.get("MeshGangExec", {}).get("mesh_devices", 0),

@@ -870,3 +870,152 @@ def test_standalone_gang_job_reports_task_run_time_phase_split_and_spans():
             cur = slices[cur["args"]["parent_span_id"]]
             names.append(cur["name"])
         assert "task.execute" in names, (e["name"], names)
+
+
+# =====================================================================
+# the profiler's trace: every span reaches it, obs on or off
+# =====================================================================
+def _host_events(trace_dir) -> list:
+    """(name, line, metadata) of every event on the profile's host plane;
+    a line is one thread id's."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    return [
+        (e.name, i, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+        for i, line in enumerate(plane.lines) for e in line.events
+    ]
+
+
+EXCHANGE_SQL = "select a.k, sum(b.v) as s from a join b on a.k = b.k group by a.k"
+
+
+def _keyed_table(n: int, parts: int, seed: int):
+    import numpy as np
+
+    from arrow_ballista_tpu.catalog import MemoryTable
+
+    rng = np.random.default_rng(seed)
+    t = pa.table({"k": rng.integers(0, 50, n), "v": rng.random(n)})
+    per = -(-n // parts)
+    return MemoryTable([t.slice(i * per, per).to_batches() for i in range(parts)])
+
+
+def test_profiler_trace_holds_the_programs_spans_with_obs_off(tmp_path, monkeypatch):
+    """A join whose two inputs go through the device exchange, served by
+    a local scheduler and executor with obs OFF, under a profiler
+    session: the host plane holds task.execute and the exchange's spans,
+    each with its task's job and stage, the pool workers' too; the
+    recorder holds nothing."""
+    import jax
+
+    from arrow_ballista_tpu.client.context import BallistaContext
+    from arrow_ballista_tpu.parallel import mesh_stage
+
+    monkeypatch.setattr(mesh_stage, "_usable_cores", lambda: 4)
+    config = {**OBS_CONFIG, "ballista.obs.enabled": "false", "ballista.mesh.enable": "true",
+              "ballista.mesh.devices": "4"}
+    ctx = BallistaContext.standalone(config=BallistaConfig(config), num_executors=1, concurrent_tasks=2)
+    try:
+        ctx.register_table("a", _keyed_table(3000, 3, 1))
+        ctx.register_table("b", _keyed_table(2000, 3, 2))
+        ctx.sql(EXCHANGE_SQL).collect()  # compiled outside the session
+        seen = set(ctx._job_ids)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            out = ctx.sql(EXCHANGE_SQL).collect()
+        finally:
+            jax.profiler.stop_trace()
+        (job_id,) = set(ctx._job_ids) - seen
+    finally:
+        ctx.close()
+    assert out.num_rows == 50
+    assert not trace.is_enabled() and get_recorder().drain() == []
+
+    events = [e for e in _host_events(tmp_path) if e[2].get("job") == job_id]
+    names = {name for name, _, _ in events}
+    assert {"task.execute", "exchange.device", "exchange.pad", "exchange.upload",
+            "exchange.program", "exchange.fetch", "exchange.partition", "shuffle.write"} <= names
+    for name, _, meta in events:
+        assert "stage" in meta, name
+    # the workers prepare partitions on threads of their own, under the
+    # job and stage of the task that started them
+    workers = [meta for name, _, meta in events if name == "exchange.partition"]
+    stages = {meta["stage"] for name, _, meta in events if name == "exchange.device"}
+    assert any(meta["worker"].startswith("exchange_") for meta in workers)
+    assert {meta["stage"] for meta in workers} == stages
+    (up,) = {meta["bytes"] > 0 for name, _, meta in events if name == "exchange.upload"}
+    assert up
+    # attrs set before the span closed ride along
+    assert all("capacity" in meta for name, _, meta in events if name == "exchange.device")
+
+
+def test_span_is_the_shared_noop_once_the_profiler_stops(tmp_path):
+    import jax
+
+    trace.configure(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on = trace.span("x")
+        assert on is not trace.NOOP
+        assert trace.manual_span("y") is not trace.NOOP_MANUAL
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.span("x", key="value") is trace.NOOP
+    assert trace.manual_span("y") is trace.NOOP_MANUAL
+    with on:
+        on.set_attr("after", 1)  # a span made while profiling stays usable
+    assert get_recorder().drain() == []
+
+
+def test_importing_the_span_api_loads_no_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; import arrow_ballista_tpu.obs.trace as t; "
+            "assert t.span('x') is t.NOOP; assert 'jax' not in sys.modules, 'jax'")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_obs_on_spans_reach_the_recorder_and_the_profiler(tmp_path):
+    """With obs on, one span reaches both: the recorder as before and the
+    profiler with its attrs, late ones too, and its task's job and stage,
+    across an explicit parent on another thread and a manual span."""
+    import jax
+
+    trace.configure(enabled=True, process="test-proc")
+    tid = trace.new_id()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.activate(tid), trace.span("task.execute", job="j7", stage=4) as task:
+            task.set_attr("late", 5)
+            ms = trace.manual_span("gen", rows=0)
+
+            def worker(ctx):
+                with trace.span("in-thread", parent=ctx):
+                    pass
+
+            t = threading.Thread(target=worker, args=(trace.current_context(),))
+            t.start()
+            t.join()
+            ms.set_attr("rows", 3)
+            ms.finish()
+    finally:
+        jax.profiler.stop_trace()
+    recorded = {s["name"]: s for s in get_recorder().drain()}
+    assert set(recorded) == {"task.execute", "gen", "in-thread"}
+    assert {s["trace"] for s in recorded.values()} == {tid}
+    assert recorded["in-thread"]["parent"] == recorded["task.execute"]["span"]
+    seen = {name: (line, meta) for name, line, meta in _host_events(tmp_path)
+            if name in recorded}
+    assert set(seen) == set(recorded)
+    assert seen["task.execute"][1]["late"] == 5
+    assert seen["gen"][1]["rows"] == 3
+    for name in recorded:
+        assert (seen[name][1]["job"], seen[name][1]["stage"]) == ("j7", 4), name
+    assert seen["in-thread"][0] != seen["task.execute"][0]
