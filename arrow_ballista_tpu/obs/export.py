@@ -358,6 +358,8 @@ _EXCHANGE_COUNTERS = (
     ("exchange_decode_ns", "exchange_decode_ms"),
     # PR 35: exchanges whose program placed rows by output partition
     ("exchange_grouped", "exchange_grouped"),
+    # the 32-bit words a row takes in the exchange program
+    ("exchange_row_words", "exchange_row_words"),
 )
 _JOIN_COUNTERS = (
     ("join_build_ns", "join_build_ms"),

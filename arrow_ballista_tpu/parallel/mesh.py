@@ -118,6 +118,81 @@ def make_distributed_agg_step(
 
 # ------------------------------------------------- on-device repartition
 _EXCHANGE_CACHE: dict = {}
+# the exchange program keys a row by ``device * capacity + slot`` as an i32
+# (the TPU has no 64-bit compare): a mesh's devices x capacity stay under it
+EXCHANGE_MAX_SLOTS = 1 << 31
+
+
+def exchange_row_words(dtypes) -> int:
+    """The 32-bit words a row of columns of ``dtypes`` takes in the
+    exchange program's packed form: one a column of 4 bytes or fewer, two
+    a column of 8, and one flags word for every 32 bits among the bool
+    columns and the routing's own delivered bit."""
+    dtypes = [np.dtype(d) for d in dtypes]
+    bits = 1 + sum(d == np.bool_ for d in dtypes)
+    return sum(
+        2 if d.itemsize == 8 else 1 for d in dtypes if d != np.bool_
+    ) + -(-bits // 32)
+
+
+def _uint(itemsize: int):
+    return {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}[itemsize]
+
+
+def _pack_rows(cols, ok) -> tuple:
+    """The columns and ``ok`` as W ``uint32[rows]`` words a row: a 4-byte
+    column is one word (a bitcast), an 8-byte one two (low word first), a
+    narrower one a word it is widened into, and every bool a bit of a
+    flags word (``ok`` bit 0, then the bool columns in order, 32 to a
+    word)."""
+    words, bits = [], [ok]
+    for c in cols:
+        size = c.dtype.itemsize
+        if c.dtype == jnp.bool_:
+            bits.append(c)
+        elif size == 8:
+            u = jax.lax.bitcast_convert_type(c, jnp.uint64)
+            words += [u.astype(jnp.uint32), (u >> 32).astype(jnp.uint32)]
+        else:
+            words.append(
+                jax.lax.bitcast_convert_type(c, _uint(size)).astype(jnp.uint32)
+            )
+    for i in range(0, len(bits), 32):
+        flags = bits[i].astype(jnp.uint32)
+        for k, b in enumerate(bits[i + 1:i + 32], start=1):
+            flags = flags | (b.astype(jnp.uint32) << k)
+        words.append(flags)
+    return tuple(words)
+
+
+def _unpack_rows(recv, dtypes) -> tuple:
+    """:func:`_pack_rows` undone over ``recv`` ``uint32[sources, W,
+    capacity]``: each column ``[sources * capacity]`` in its own dtype,
+    then ``ok``."""
+    def word(i):
+        return recv[:, i, :].reshape(-1)
+
+    out, bools, i = [], [], 0
+    for d in dtypes:
+        size = d.itemsize
+        if d == jnp.bool_:
+            bools.append(len(out))
+            out.append(None)
+            continue
+        if size == 8:
+            u = word(i).astype(jnp.uint64) | (word(i + 1).astype(jnp.uint64) << 32)
+            i += 2
+        else:
+            u = word(i).astype(_uint(size))
+            i += 1
+        out.append(jax.lax.bitcast_convert_type(u, d))
+
+    def bit(j):
+        return (word(i + j // 32) >> (j % 32)) & 1 == 1
+
+    for j, at in enumerate(bools, start=1):
+        out[at] = bit(j)
+    return tuple(out) + (bit(0),)
 
 
 def ici_batch_exchange(
@@ -126,14 +201,25 @@ def ici_batch_exchange(
     """Multi-column hash-repartition exchange over ICI.
 
     Generalizes :func:`ici_all_to_all_repartition` (single f64 column) to a
-    typed multi-column payload (VERDICT.md round-1 item 4): the routing —
-    a row's slot in its (source shard, destination device) run,
-    per-destination staging slots, overflow accounting — is computed
-    ONCE from (part, valid), then every column
-    scatters into its own [n_dev, capacity] staging buffer and rides its
-    own ``all_to_all``.  Columns may be any device dtype (f32/f64, i32,
-    bool, dictionary codes); validity masks travel as ordinary bool
-    columns.
+    typed multi-column payload: the routing — a row's slot in its (source
+    shard, destination device) run, overflow accounting — is computed ONCE
+    from (part, valid), and a row then moves ONCE.  The program packs the
+    columns into W 32-bit words a row (:func:`_pack_rows`; W =
+    :func:`exchange_row_words` of the columns' dtypes: a word a 4-byte
+    column, two an 8-byte one, the bools and the delivered bit as bits of
+    a flags word), sorts the ``[W, rows]`` word matrix along its rows by
+    (destination device, slot) with one sort, slices each device's run
+    into a ``[n_dev, W, capacity]`` staging buffer, sends that with one
+    ``all_to_all`` and unpacks it into the columns again
+    (:func:`_unpack_rows`), bit for bit.  A row that goes nowhere
+    (invalid, pad or overflow) sorts past every run.  On the TPU a scatter
+    costs a serial step an element it writes (and sorts its indices
+    first), so one sort of the packed rows does the work of a scatter a
+    column.  The sort takes two operands, the key broadcast to the word
+    matrix and the matrix: the TPU compiler's time grows with a sort's
+    operands (a key and W separate words took minutes at q3's shapes).
+    Columns may be any device dtype (f32/f64, i32, bool, dictionary
+    codes); validity masks travel as ordinary bool columns.
 
     ``part`` is a row's output partition, one of ``n_out`` (default: one a
     device); its destination device is ``part % n_dev``.  The run a source
@@ -148,9 +234,10 @@ def ici_batch_exchange(
 
     Returns ``fn(part i32[rows], valid bool[rows], *cols) →
     (*recv_cols [n_dev*capacity], recv_valid bool[n_dev*capacity],
-    n_dropped i32)``.  ``n_dropped`` is the global count of valid rows that
-    overflowed a (source, destination) bucket — callers MUST re-run with a
-    larger capacity (or fall back to the Flight shuffle) when non-zero.
+    n_dropped i32)``; a slot no row filled reads zero in every column.
+    ``n_dropped`` is the global count of valid rows that overflowed a
+    (source, destination) bucket — callers MUST re-run with a larger
+    capacity (or fall back to the Flight shuffle) when non-zero.
     """
     from jax import shard_map
 
@@ -166,12 +253,13 @@ def ici_batch_exchange(
     # below[p, q]: partition q shares p's device and comes before it
     p = np.arange(n_out)
     below = (p[None, :] < p[:, None]) & (p[None, :] % n_dev == p[:, None] % n_dev)
+    assert n_dev * capacity < EXCHANGE_MAX_SLOTS, (n_dev, capacity)
 
     def local_exchange(part, valid, *cols):
         # a row's rank among the valid rows of its partition, in row order:
         # a running count a partition (there are few), no sort and no
         # gather.  Invalid rows (pad rows among them) take the sentinel
-        # partition, rank nowhere and land in the spill column.
+        # partition and rank nowhere.
         part_m = jnp.where(valid, part, n_out)
         bound = part_m[None, :] == jnp.arange(n_out, dtype=part_m.dtype)[:, None]
         run = jnp.cumsum(bound.astype(jnp.int32), axis=1)
@@ -185,21 +273,42 @@ def ici_batch_exchange(
         n_dropped = jax.lax.psum(
             jnp.sum(overflow.astype(jnp.int32)), DATA_AXIS
         )
-        safe_dest = jnp.where(real, part_m % n_dev, n_dev - 1)
-        slot = jnp.where(ok, idx_within, capacity)
-
-        def route(c, fill_ok=False):
-            cs = ok if fill_ok else c
-            stage = jnp.zeros((n_dev, capacity + 1), cs.dtype)
-            stage = stage.at[safe_dest, slot].set(cs, mode="drop")
-            stage = stage[:, :capacity]
-            return jax.lax.all_to_all(
-                stage, DATA_AXIS, split_axis=0, concat_axis=0, tiled=False
-            ).reshape(-1)
-
-        recv_cols = tuple(route(c) for c in cols)
-        recv_valid = route(None, fill_ok=True)
-        return recv_cols + (recv_valid, n_dropped)
+        safe_dest = jnp.where(real, part_m % n_dev, n_dev - 1).astype(jnp.int32)
+        # the rows in (device, slot) order, those not delivered last: the
+        # keys of delivered rows are distinct, so every word row of the
+        # [W, rows] matrix sorts its delivered rows into the same order.
+        # The key is an i32 (EXCHANGE_MAX_SLOTS)
+        key = jnp.where(
+            ok, safe_dest * capacity + idx_within.astype(jnp.int32), n_dev * capacity
+        )
+        words = jnp.stack(_pack_rows(cols, ok))
+        moved = jax.lax.sort(
+            (jnp.broadcast_to(key, words.shape), words),
+            dimension=1, num_keys=1, is_stable=False,
+        )[1]
+        # device d's run starts after the lower devices' rows; its slots
+        # past its count are zero
+        count = jnp.stack(
+            [jnp.sum((ok & (safe_dest == d)).astype(jnp.int32)) for d in range(n_dev)]
+        )
+        start = jnp.cumsum(count) - count
+        rows = part.shape[0]
+        room = capacity if n_dev == 1 else rows + capacity
+        if room > rows:
+            moved = jnp.pad(moved, ((0, 0), (0, room - rows)))
+        lane = jax.lax.iota(jnp.int32, capacity)
+        stage = jnp.stack([
+            jnp.where(
+                lane < count[d],
+                jax.lax.dynamic_slice(moved, (0, start[d]), (words.shape[0], capacity)),
+                jnp.uint32(0),
+            )
+            for d in range(n_dev)
+        ])
+        recv = jax.lax.all_to_all(
+            stage, DATA_AXIS, split_axis=0, concat_axis=0, tiled=False
+        )
+        return _unpack_rows(recv, [c.dtype for c in cols]) + (n_dropped,)
 
     fn = _EXCHANGE_CACHE[cache_key] = jax.jit(
         shard_map(
@@ -350,6 +459,14 @@ class BatchExchanger(ExchangeLayout):
         self.capacity = capacity
         self.n_out = mesh.devices.size if n_out is None else n_out
         self._fn = ici_batch_exchange(mesh, self.n_cols, capacity, self.n_out)
+        import pyarrow as pa
+
+        # the words a row takes in the program (exchange_row_words): the
+        # layout's columns, read off an empty batch of the schema
+        empty = pa.RecordBatch.from_pylist([], schema=schema)
+        self.row_words = exchange_row_words(
+            c.dtype for c in self.flatten(empty, self.new_encoders())
+        )
 
     def to_columns(self, batch) -> list[np.ndarray]:
         """Flatten one RecordBatch into the exchange's column list."""
@@ -372,11 +489,12 @@ class BatchExchanger(ExchangeLayout):
         to the padded rows (``exchange.pad``), ``upload`` every array and
         wait until all are on the mesh (``exchange.upload``, attr
         ``bytes``), ``program`` dispatch and wait for its outputs
-        (``exchange.program``), ``fetch`` every output to the host
-        (``exchange.fetch``, attr ``bytes``).  The two waits keep the copy
-        up out of the program's time and the program out of the fetch's;
-        the program cannot start before its inputs are there anyway, and
-        the first fetch waited for it before."""
+        (``exchange.program``, attr ``row_words``: the words a row takes
+        in the program, :func:`exchange_row_words`), ``fetch`` every
+        output to the host (``exchange.fetch``, attr ``bytes``).  The two
+        waits keep the copy up out of the program's time and the program
+        out of the fetch's; the program cannot start before its inputs are
+        there anyway, and the first fetch waited for it before."""
         laps = PhaseClock() if laps is None else laps
         rows = exchange_rows(len(part), self.mesh.devices.size)
         with trace.span("exchange.pad", rows=rows):
@@ -385,7 +503,7 @@ class BatchExchanger(ExchangeLayout):
         with trace.span("exchange.upload", bytes=sum(a.nbytes for a in padded)):
             placed = jax.block_until_ready(shard_batch(self.mesh, padded, rows))
         laps.lap("upload")
-        with trace.span("exchange.program"):
+        with trace.span("exchange.program", row_words=self.row_words):
             out = jax.block_until_ready(self._fn(*placed))
         laps.lap("program")
         with trace.span("exchange.fetch") as fetch_span:

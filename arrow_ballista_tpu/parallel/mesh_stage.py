@@ -1199,8 +1199,10 @@ class MeshRepartitionExec(ExecutionPlan):
 
         Always-on counters: ``exchange_workers`` (the pool's width, 1 =
         inline), ``exchange_grouped`` (1 where the program placed rows by
-        output partition, else 0); on the task thread ``exchange_wait_ns``
-        (blocked until the next partition IN ORDER is prepared),
+        output partition, else 0), ``exchange_row_words`` (the 32-bit
+        words a row takes in the program, ``mesh.exchange_row_words``); on
+        the task thread ``exchange_wait_ns`` (blocked until the next
+        partition IN ORDER is prepared),
         ``exchange_encode_ns`` (what is left of the encode there:
         dictionaries mapped at the hand-over, the final concatenate, the
         bucket count), ``device_time_ns`` (the exchange calls, capacity
@@ -1232,6 +1234,18 @@ class MeshRepartitionExec(ExecutionPlan):
                 return
 
             mesh = M.make_mesh(n_dev)
+
+            def exchanger(cap, share_from):
+                # the program keys a row's slot as an i32: past that, the
+                # writer's Flight shuffle
+                if n_dev * cap >= M.EXCHANGE_MAX_SLOTS:
+                    raise MeshExchangeError(
+                        f"{n_dev} devices x {cap} slots pass the exchange's key"
+                    )
+                return M.BatchExchanger(
+                    mesh, schema, cap, share_from=share_from, n_out=width
+                )
+
             t0 = clock()
             with trace.span("exchange.encode"):
                 cols = [np.concatenate(one) for one in zip(*prepared)]
@@ -1268,9 +1282,7 @@ class MeshRepartitionExec(ExecutionPlan):
                     for d in range(min(n_dev, width))
                 )
                 cap = K.bucket_rows(need, floor=1)
-                ex = M.BatchExchanger(
-                    mesh, schema, cap, share_from=layout, n_out=width
-                )
+                ex = exchanger(cap, layout)
             t1 = clock()
             # the call's phases split t1..t2 exactly; a growth's set-up
             # goes to the next pad, the span's close to the last fetch
@@ -1292,9 +1304,7 @@ class MeshRepartitionExec(ExecutionPlan):
                             "mesh exchange capacity ceiling exceeded"
                         )
                     growths += 1
-                    ex = M.BatchExchanger(
-                        mesh, schema, cap, share_from=ex, n_out=width
-                    )
+                    ex = exchanger(cap, ex)
                 dev_span.set_attr("capacity", cap)
                 dev_span.set_attr("growths", growths)
             t2 = laps.lap("fetch")
@@ -1310,6 +1320,7 @@ class MeshRepartitionExec(ExecutionPlan):
                 add(f"exchange_{phase}_ns", ns)
             add("exchange_decode_ns", clock() - t2)
             add("exchange_grouped", int(grouped))
+            add("exchange_row_words", ex.row_words)
             if growths:
                 add("capacity_growths", growths)
             add("mesh_exchange_rows", total)
