@@ -29,7 +29,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark import datagen, queries  # noqa: E402
+from benchmark import suites  # noqa: E402
 
 NS = (
     "mesh_stage_time_ns", "gang_wait_ns", "gang_merge_ns", "gang_upload_ns",
@@ -52,7 +52,8 @@ def main() -> None:
     widths = [int(w) for w in args.widths.split(",")]
 
     data = tempfile.mkdtemp(prefix="gang_width_")
-    made = datagen.generate(data, ["lineitem"], args.sf, args.seed, args.files)
+    tpch = suites.load("tpch")
+    made = suites.generate(data, tpch, ["lineitem"], args.sf, args.seed, args.files)
 
     import jax
 
@@ -77,8 +78,8 @@ def main() -> None:
 
     ctx = SessionContext()
     ctx.register_parquet("lineitem", os.path.join(data, "lineitem"))
-    draws = queries.Draws(args.seed, (1, 6))
-    texts = {k: queries.render(k, draws.window(k, 0)) for k in (1, 6)}
+    draws = suites.Draws(tpch, args.seed, (1, 6))
+    texts = {k: tpch.render(k, draws.window(k, 0)) for k in (1, 6)}
 
     def run(kind: int) -> tuple:
         plan = ctx.sql(texts[kind]).physical_plan()

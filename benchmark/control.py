@@ -2,8 +2,8 @@
 
     python3 benchmark/control.py --workload tpch-sf1-1chip.scan-agg --seeds 11 12 13
 
-The control is the plain reference computed one precision below the
-program's stated float32 (``reference.answer(..., precision="bfloat16")``)
+The control is the cell's plain reference computed one precision below the
+program's stated float32 (``suite.answer(..., precision="bfloat16")``)
 and put in the program's place: for each seed it generates the cell's tables, draws as many
 parameter sets of each kind as a window completes, and prints the numbers
 the comparison would read for them.  The smallest ``rel_gap_max`` over the
@@ -23,22 +23,22 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark import compare, datagen, harness, queries, reference  # noqa: E402
+from benchmark import compare, harness, reference, suites  # noqa: E402
 
 
 def reading(workload: str, seed: int, per_kind: int, sf=None, precision="bfloat16") -> dict:
     resolved = harness.resolve(workload, harness.benchmark_json())
-    config, kinds = resolved["config"], list(dict.fromkeys(resolved["traffic"]["kinds"]))
-    tables = [t for t in config["tables"] if any(t in queries.TABLES_OF[k] for k in kinds)]
+    config, suite = resolved["config"], resolved["suite"]
+    kinds = list(dict.fromkeys(resolved["traffic"]["kinds"]))
+    tables = [t for t in config["tables"] if any(t in suite.TABLES_OF[k] for k in kinds)]
     work = tempfile.mkdtemp(prefix="abt_control_")
     try:
-        datagen.generate(work, tables, sf or float(config["scale_factor"]), seed,
-                         int(config["files_per_table"]))
+        suites.generate(work, suite, tables, suite.scale(config, sf), seed, int(config["files_per_table"]))
         data = reference.Data(work)
-        draws = queries.Draws(seed, kinds, per_kind)
+        draws = suites.Draws(suite, seed, kinds, per_kind)
         pairs = [
-            (reference.answer(data, k, draws.window(k, i), precision),
-             reference.answer(data, k, draws.window(k, i)))
+            (suite.answer(data, k, draws.window(k, i), precision),
+             suite.answer(data, k, draws.window(k, i)))
             for k in kinds for i in range(per_kind)
         ]
         return compare.judge(pairs)

@@ -2,8 +2,9 @@
 
 A copy of the program's ``benchmarks/tpch/datagen.py`` generators (numpy,
 dbgen-like distributions, NOT dbgen: correct schemas, key relationships and
-value ranges) and of ``chip_smoke.py``'s parallel writer, kept here so that
-no later PR can change the data a cell is measured on.  One change from the
+value ranges), kept here so that no later PR can change the data a cell is
+measured on; the ``tpch`` suite names them, and ``suites.generate`` writes
+them in parallel.  One change from the
 original: every slice of lineitem holds exactly four rows an order
 (``_exact_total``), so that row counts, and with them every array shape the
 program sees, are the same for every seed.  Row and record
@@ -13,9 +14,7 @@ widths are the source's: every column of the three tables is written.
 from __future__ import annotations
 
 import datetime as dt
-import multiprocessing
 import os
-import time
 
 import numpy as np
 import pyarrow as pa
@@ -37,9 +36,6 @@ _WORDS = np.array(
      "regular", "ironic", "even", "bold", "silent", "deposits", "accounts",
      "requests", "packages", "theodolites", "instructions", "foxes"]
 )
-
-# the tables a configuration may name; a run writes those its traffic's kinds scan
-TABLES = ("lineitem", "orders", "customer")
 
 
 def _slice(total: int, seed: int, i: int, k: int):
@@ -156,42 +152,10 @@ def gen_customer(sf: float, seed: int, i: int, k: int) -> pa.Table:
     )
 
 
-_GEN = {"lineitem": gen_lineitem, "orders": gen_orders, "customer": gen_customer}
-
-
 def _write_chunk(job) -> tuple:
-    """Pool worker: one slice of one table, generated and written."""
-    import pyarrow.parquet as pq
+    """One slice of one table of the ``tpch`` suite, generated and written:
+    ``(table, i, k, sf, seed, out_dir)``, a job of ``suites.generate`` less
+    the suite's file."""
+    from benchmark import suites
 
-    name, i, k, sf, seed, out_dir = job
-    tbl = _GEN[name](sf, seed + TABLES.index(name), i, k)
-    path = os.path.join(out_dir, name, f"part-{i:03d}.parquet")
-    pq.write_table(tbl, path)
-    return name, tbl.num_rows, os.path.getsize(path)
-
-
-def generate(out_dir: str, tables, sf: float, seed: int, files: int, procs: int = 0) -> dict:
-    """Write ``tables`` under ``out_dir/<table>/part-NNN.parquet``; returns
-    the row counts, the bytes written and the seconds it took."""
-    unknown = [t for t in tables if t not in _GEN]
-    if unknown:
-        raise ValueError(f"no generator for table(s) {unknown}")
-    for name in tables:
-        os.makedirs(os.path.join(out_dir, name), exist_ok=True)
-    jobs = [(name, i, files, sf, seed, out_dir) for name in tables for i in range(files)]
-    procs = procs or max(1, min(files, (os.cpu_count() or 2) - 1))
-    t0 = time.monotonic()
-    rows = dict.fromkeys(tables, 0)
-    size = 0
-    # spawn: workers import this module alone and never see jax
-    with multiprocessing.get_context("spawn").Pool(procs) as pool:
-        for name, n, nbytes in pool.imap_unordered(_write_chunk, jobs):
-            rows[name] += n
-            size += nbytes
-    return {
-        "rows": rows,
-        "parquet_bytes": size,
-        "files_per_table": files,
-        "seconds": time.monotonic() - t0,
-        "processes": procs,
-    }
+    return suites.write_chunk((os.path.join(suites.HERE, "tpch.py"), *job))

@@ -1,12 +1,15 @@
-"""Resolution by name: a cell, its configuration, its traffic mix and the
-per-layer metric readers are data files found by the names in
-``BENCHMARK.json``.  A later PR adds a file and an entry, and edits nothing."""
+"""Resolution by name: a cell, its configuration, its traffic mix, the
+configuration's query suite and the per-layer metric readers are files found
+by the names in ``BENCHMARK.json`` and the configuration.  A later PR adds a
+file and an entry, and edits nothing."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+
+from benchmark import suites
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -22,7 +25,8 @@ def benchmark_json(root: str = REPO) -> dict:
 
 
 def resolve(workload: str, bench: dict, bench_dir: str = HERE) -> dict:
-    """The cell ``workload`` with its configuration and traffic loaded.
+    """The cell ``workload`` with its configuration, traffic and suite
+    (``suites/<config's "suite">.py``, ``tpch`` where it names none) loaded.
     Cells of BENCHMARK.json first, then those kept for later."""
     cells = list(bench["workloads"])
     later = os.path.join(bench_dir, "cells_later.json")
@@ -38,7 +42,8 @@ def resolve(workload: str, bench: dict, bench_dir: str = HERE) -> dict:
     traffic = load_json(os.path.join(bench_dir, "traffic", cell["traffic"] + ".json"))
     if int(config["chips"]) != int(cell["chips"]):
         raise ValueError(f"cell {workload} asks {cell['chips']} chips, its configuration {config['chips']}")
-    return {"cell": cell, "config": config, "traffic": traffic}
+    suite = suites.load(config.get("suite", "tpch"), os.path.join(bench_dir, "suites"))
+    return {"cell": cell, "config": config, "traffic": traffic, "suite": suite}
 
 
 def load_readers(bench_dir: str = HERE) -> dict:

@@ -1,4 +1,5 @@
-"""TPC-H q1, q6, q3 with the spec's substitution parameters.
+"""TPC-H q1, q6, q3 with the spec's substitution parameters (the ``tpch``
+suite's kinds).
 
 The texts are the program's validation texts (``benchmarks/tpch/queries.py``)
 with their literals replaced by the substitution parameters of TPC-H spec
@@ -15,7 +16,6 @@ own.  Every seed gives other literals; a run repeats its own.
 from __future__ import annotations
 
 import datetime as dt
-import random
 
 SEGMENTS = ("AUTOMOBILE", "BUILDING", "FURNITURE", "HOUSEHOLD", "MACHINERY")
 
@@ -142,25 +142,3 @@ def render(kind: int, p: dict) -> str:
         return _Q3.format(segment=p["segment"], date=p["date"])
     raise ValueError(f"no query text for kind {kind}")
 
-
-class Draws:
-    """Per-kind parameter sets in an order fixed by the seed.  The window's
-    i-th query of a kind uses entry ``i % sets``; ``window_sets(k)`` are the
-    entries warm-up has to run; ``aside(k)`` is one the window never uses."""
-
-    def __init__(self, seed: int, kinds, sets: int = 1):
-        self.sets = max(1, int(sets))
-        self._sets = {}
-        for kind in sorted(set(kinds)):
-            sets = parameter_sets(kind)
-            random.Random(f"{seed}/params/{kind}").shuffle(sets)
-            self._sets[kind] = sets
-
-    def window_sets(self, kind: int) -> list:
-        return self._sets[kind][: self.sets]
-
-    def window(self, kind: int, i: int) -> dict:
-        return self._sets[kind][i % min(self.sets, len(self._sets[kind]))]
-
-    def aside(self, kind: int) -> dict:
-        return self._sets[kind][-1]

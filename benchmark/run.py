@@ -34,7 +34,7 @@ REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
 from benchmark import (  # noqa: E402
-    compare, datagen, harness, jobstats, queries, reference, trace_reduce, window,
+    compare, harness, jobstats, reference, suites, trace_reduce, window,
 )
 from benchmark.cluster import Cluster, ClusterFailure, new_job_id  # noqa: E402
 
@@ -52,7 +52,7 @@ def parse_args(argv=None):
     ap.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
                     help="'cpu' is the labelled rehearsal, never a fallback")
     ap.add_argument("--sf", type=float, default=None,
-                    help="rehearsal only: another scale factor than the configuration's")
+                    help="rehearsal only: another scale than the configuration's, as its suite reads it")
     ap.add_argument("--keep", default="", help="copy logs, job details and the trace's reduction here")
     args = ap.parse_args(argv)
     if args.sf is not None and args.platform != "cpu":
@@ -60,25 +60,26 @@ def parse_args(argv=None):
     return args
 
 
-def collect(ctx, seen: set, kind: int, params: dict) -> window.WithJob:
-    """The timed call, ``sql(text).collect()``, and the id of the job it ran,
-    from the client's own books (``seen``: the ids this context had before).
-    A record finds its job by that id, whoever else was submitting."""
+def collect(ctx, seen: set, suite, kind, params: dict) -> window.WithJob:
+    """The timed call, ``sql(text).collect()`` of the suite's text, and the
+    id of the job it ran, from the client's own books (``seen``: the ids this
+    context had before).  A record finds its job by that id, whoever else
+    was submitting."""
     try:
-        answer = ctx.sql(queries.render(kind, params)).collect()
+        answer = ctx.sql(suite.render(kind, params)).collect()
     except Exception as e:
         e.job_id = new_job_id(ctx, seen)
         raise
     return window.WithJob(answer, new_job_id(ctx, seen))
 
 
-def timed(ctx, seen: set, kind: int, params: dict) -> dict:
+def timed(ctx, seen: set, suite, kind, params: dict) -> dict:
     """One query outside the window (warm-up, CPU-operator read)."""
     rec = {"kind": kind, "params": params, "unix_submit": time.time(), "error": None,
            "answer": None, "job_id": None}
     t = time.monotonic()
     try:
-        rec["answer"], rec["job_id"] = collect(ctx, seen, kind, params)
+        rec["answer"], rec["job_id"] = collect(ctx, seen, suite, kind, params)
     except Exception as e:  # noqa: BLE001 - reported with the run's failure
         rec["error"] = f"{type(e).__name__}: {e}"
         rec["job_id"] = getattr(e, "job_id", None)
@@ -92,7 +93,7 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     test's stand-in with its five methods).  Everything a run does between
     the cluster's start and its stop; tests drive it with the timed path
     broken underneath."""
-    cell, config, traffic = resolved["cell"], resolved["config"], resolved["traffic"]
+    cell, config, traffic, suite = (resolved[k] for k in ("cell", "config", "traffic", "suite"))
     chips = int(cell["chips"])
     kinds = list(dict.fromkeys(traffic["kinds"]))
     clients = int(traffic.get("clients", 1))
@@ -100,26 +101,26 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     ctxs = [served.client(settings) for _ in range(clients)]
     seen = [set() for _ in ctxs]  # per client, the job ids already given to a record
     fixed = traffic.get("requests")
-    draws = queries.Draws(seed, kinds, traffic.get("parameter_sets", 1))
+    draws = suites.Draws(suite, seed, kinds, traffic.get("parameter_sets", 1))
 
     warmup = []
     # the cell's own kinds, and every set of literals the window will send
     # (the program compiles for each), once each
-    todo = [(int(r["kind"]), dict(r["params"])) for r in fixed] if fixed else [
+    todo = [(r["kind"], dict(r["params"])) for r in fixed] if fixed else [
         (kind, p) for kind in kinds for p in draws.window_sets(kind)
     ]
     for kind, params in todo:
-        rec = timed(ctxs[0], seen[0], kind, params)
-        log(f"warm-up q{kind}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
+        rec = timed(ctxs[0], seen[0], suite, kind, params)
+        log(f"warm-up {suite.label(kind)}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
         if rec["error"]:
-            raise ClusterFailure(f"warm-up of q{kind} failed: {rec['error']}")
+            raise ClusterFailure(f"warm-up of {suite.label(kind)} failed: {rec['error']}")
         warmup.append(rec)
     cpu_ops = []
     if trace:
         ref_ctx, ref_seen = served.client({**settings, "ballista.tpu.enable": "false"}), set()
         for kind in kinds:
-            rec = timed(ref_ctx, ref_seen, kind, draws.aside(kind))
-            log(f"cpu operators q{kind}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
+            rec = timed(ref_ctx, ref_seen, suite, kind, draws.aside(kind))
+            log(f"cpu operators {suite.label(kind)}: {rec['latency_s']:.2f}s {rec['error'] or ''}")
             cpu_ops.append(rec)
         ref_ctx.close()
     trace_marks: dict = {}
@@ -135,8 +136,8 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
     setup_s = time.monotonic() - T0
     log(f"set-up done in {setup_s:.1f}s; window of {seconds:g}s")
     result = window.run_window(
-        traffic, seed, seconds,
-        lambda c, kind, params: collect(ctxs[c], seen[c], kind, params),
+        suite, traffic, seed, seconds,
+        lambda c, kind, params: collect(ctxs[c], seen[c], suite, kind, params),
         after_first_cycle=stop_trace,
     )
     stop_trace()
@@ -161,19 +162,22 @@ def measure(served, resolved: dict, data: dict, seed: int, seconds: float, trace
             )
             if why:
                 r["wrong_route"] = why
-                log(f"q{r['kind']} client {r['client']} #{r['seq']} job {r['job_id']} off the cell's path: {why}")
+                log(f"{suite.label(r['kind'])} client {r['client']} #{r['seq']} job {r['job_id']} "
+                    f"off the cell's path: {why}")
     return {
         "setup_s": setup_s, "records": records, "window_s": result["window_s"],
         "warmup": warmup, "cpu_ops": cpu_ops, "memory": memory,
         "trace_marks": trace_marks, "chips": chips, "job_details": details, "paired_by_time": len(by_time),
         "rows_of_kind": {
-            k: sum(data["rows"][t] for t in queries.TABLES_OF[k]) for k in kinds
+            k: sum(data["rows"][t] for t in suite.TABLES_OF[k]) for k in kinds
         },
+        "suite": suite,
     }
 
 
 def judge(measured: dict, data_dir: str) -> dict:
-    """Every answer the window completed against the plain reference."""
+    """Every answer the window completed against the suite's plain reference."""
+    suite = measured["suite"]
     ref = reference.Data(data_dir)
     answers: dict = {}  # a window repeats its parameter sets: one reference answer each
     pairs = []
@@ -181,8 +185,8 @@ def judge(measured: dict, data_dir: str) -> dict:
         if r["error"] is None:
             key = (r["kind"], json.dumps(r["params"], sort_keys=True))
             if key not in answers:
-                answers[key] = reference.answer(ref, r["kind"], r["params"])
-            pairs.append((r["answer"], answers[key], queries.ORDER_BY[r["kind"]]))
+                answers[key] = suite.answer(ref, r["kind"], r["params"])
+            pairs.append((r["answer"], answers[key], suite.ORDER_BY[r["kind"]]))
     return compare.judge(pairs)
 
 
@@ -201,7 +205,7 @@ def reduce_trace(measured: dict, trace_dir: str, rehearsal: bool) -> dict:
     red["queries"] = traced
     spans, covered = [], []
     for r in traced:
-        j, tag = r["job"], f"q{r['kind']}"
+        j, tag = r["job"], measured["suite"].label(r["kind"])
         a = j["submitted_us"] * 1e3 - zero
         b = (j["end_us"] or j["submitted_us"]) * 1e3 - zero
         covered.append((a, b))
@@ -227,7 +231,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     bench = harness.benchmark_json()
     resolved = harness.resolve(args.workload, bench)
-    cell, config = resolved["cell"], resolved["config"]
+    cell, config, suite = resolved["cell"], resolved["config"], resolved["suite"]
     readers = harness.load_readers()
     peaks_table = harness.load_json(os.path.join(HERE, "peaks.json"))
     try:
@@ -236,9 +240,9 @@ def main(argv=None) -> int:
         log(f"the program is not in this checkout: {e}")
         return 2
     rehearsal = args.platform == "cpu"
-    sf = args.sf if args.sf is not None else float(config["scale_factor"])
+    scale = suite.scale(config, args.sf)
     kinds = list(dict.fromkeys(resolved["traffic"]["kinds"]))
-    tables = [t for t in config["tables"] if any(t in queries.TABLES_OF[k] for k in kinds)]
+    tables = [t for t in config["tables"] if any(t in suite.TABLES_OF[k] for k in kinds)]
 
     work = tempfile.mkdtemp(prefix="abt_bench_")
     cluster = Cluster(work, args.platform)
@@ -246,8 +250,8 @@ def main(argv=None) -> int:
         # the cluster first: with no chip the executor's start-up fails
         # while the data is still being written
         cluster.start()
-        data = datagen.generate(work, tables, sf, args.seed, int(config["files_per_table"]))
-        log(f"data: SF{sf:g} {data['rows']} {data['parquet_bytes'] / 1e6:.0f} MB in {data['seconds']:.1f}s")
+        data = suites.generate(work, suite, tables, scale, args.seed, int(config["files_per_table"]))
+        log(f"data: scale {scale} {data['rows']} {data['parquet_bytes'] / 1e6:.0f} MB in {data['seconds']:.1f}s")
         info = cluster.wait_ready()
         log(f"executor up: {info}")
         if info["platform"] != args.platform or int(info["device_count"]) != int(cell["chips"]):
@@ -301,7 +305,7 @@ def main(argv=None) -> int:
     }
     if args.trace:
         run = {
-            "cell": cell, "config": config, "traffic": resolved["traffic"],
+            "cell": cell, "config": config, "traffic": resolved["traffic"], "suite": suite,
             "chips": measured["chips"], "window": good, "window_all": records,
             "window_s": measured["window_s"],
             "warmup": measured["warmup"], "cpu_ops": measured["cpu_ops"],
@@ -318,7 +322,7 @@ def main(argv=None) -> int:
                 out["metrics"][m["name"]] = {"value": float(e2e[m["name"]]), "unit": m["unit"]}
     out["window"] = {
         "window_s": measured["window_s"], "seconds": args.seconds,
-        "latencies_s": {f"q{k}": [r["latency_s"] for r in good if r["kind"] == k] for k in kinds},
+        "latencies_s": {suite.label(k): [r["latency_s"] for r in good if r["kind"] == k] for k in kinds},
         "compared": verdict["compared"],
         "completions_by_client": [
             sum(r["client"] == c for r in good) for c in range(int(resolved["traffic"].get("clients", 1)))
@@ -326,7 +330,7 @@ def main(argv=None) -> int:
         "paired_by_time": measured["paired_by_time"],
     }
     if rehearsal:
-        out["rehearsal"] = f"--platform cpu at SF{sf:g}: no number here is a device's"
+        out["rehearsal"] = f"--platform cpu at scale {scale}: no number here is a device's"
     out["compared"] = {k: [v["value"], v["limit"]] for k, v in verdict["numbers"].items()}
     log(f"widest gap in column {verdict['widest']!r}")
     for name, v in verdict["numbers"].items():

@@ -14,8 +14,8 @@ if REPO not in sys.path:
 @pytest.fixture(scope="session")
 def small_data(tmp_path_factory):
     """lineitem/orders/customer at SF0.01, 12 files a table, seed 5."""
-    from benchmark import datagen
+    from benchmark import suites
 
     out = str(tmp_path_factory.mktemp("tpch"))
-    info = datagen.generate(out, ("lineitem", "orders", "customer"), 0.01, 5, 12, procs=2)
+    info = suites.generate(out, suites.load("tpch"), ("lineitem", "orders", "customer"), 0.01, 5, 12, procs=2)
     return out, info

@@ -1,12 +1,13 @@
-"""A stand-in for the served system: the plain reference answers the SQL
-text, with a fault planted where the answer is produced.  It lets the tests
+"""A stand-in for the served system: the suite's plain reference (``tpch``
+unless one is given) answers the SQL text, with a fault planted where the
+answer is produced.  It lets the tests
 drive the rest of a run (warm-up, window, job matching, route checks, the
 comparison) without a cluster."""
 
 import itertools
 import time
 
-from benchmark import queries, reference
+from benchmark import reference, suites
 
 
 class _Frame:
@@ -15,7 +16,8 @@ class _Frame:
 
 
 class FakeServed:
-    def __init__(self, data_dir, chips=1, fault=None, precision="float64", mesh_devices=None, ids=True):
+    def __init__(self, data_dir, chips=1, fault=None, precision="float64", mesh_devices=None, ids=True,
+                 suite=None):
         self.chips, self.fault, self.precision = chips, fault, precision
         self.ids = ids  # False: contexts keep no job ids, and records fall to the time rule
         self.mesh_devices = chips if mesh_devices is None else mesh_devices
@@ -25,8 +27,9 @@ class FakeServed:
         elif fault == "no_exchange":  # one shard's partial result, the others never merged
             files = set(range(0, 12, 4))
         self.data = reference.Data(data_dir, files)
+        self.suite = suite or suites.load("tpch")
         self.texts = {
-            queries.render(k, p): (k, p) for k in (1, 6, 3) for p in queries.parameter_sets(k)
+            self.suite.render(k, p): (k, p) for k in self.suite.TABLES_OF for p in self.suite.parameter_sets(k)
         }
         self.jobs = []
         self._n = itertools.count()  # several clients answer at once
@@ -50,7 +53,7 @@ class FakeServed:
     def _answer(self, text, ctx=None):
         kind, params = self.texts[text]
         t0 = time.time()
-        table = reference.answer(self.data, kind, params, self.precision)
+        table = self.suite.answer(self.data, kind, params, self.precision)
         if self.fault == "altered":  # an answer altered where it is produced
             name = table.column_names[-1] if kind == 6 else "revenue" if kind == 3 else "sum_charge"
             col = table.column(name).to_pylist()

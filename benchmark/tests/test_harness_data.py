@@ -17,7 +17,7 @@ def test_new_config_traffic_and_metric_resolve_without_an_edit(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bench = harness.benchmark_json()
     before = {
-        p: open(os.path.join(dp, p), "rb").read()
+        os.path.join(dp, p): open(os.path.join(dp, p), "rb").read()
         for dp, _, fs in os.walk(root / "benchmark") for p in fs
     }
     bdir = str(root / "benchmark")
@@ -54,10 +54,8 @@ def test_new_config_traffic_and_metric_resolve_without_an_edit(tmp_path):
     # a reader with nothing to read is left out of the line, never 0
     assert "scan_roofline" not in out and "device_idle_share" not in out
     # nothing that was there changed
-    for dp, _, fs in os.walk(root / "benchmark"):
-        for p in fs:
-            if p in before:
-                assert open(os.path.join(dp, p), "rb").read() == before[p]
+    for path, content in before.items():
+        assert open(path, "rb").read() == content
 
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")

@@ -7,7 +7,7 @@ plans longer than a q6, and the time rule hands a q1 the job of a q3."""
 
 import pytest
 
-from benchmark import cluster, harness, jobstats, run, window
+from benchmark import cluster, harness, jobstats, run, suites, window
 from benchmark.tests.fake_served import FakeServed
 from benchmark.tests.test_gang_phase_metrics import _stage
 
@@ -101,7 +101,7 @@ def test_a_window_record_keeps_the_id_its_submit_returned_or_raised():
             raise Refused("refused")
         return window.WithJob((kind, params), f"j{c}") if kind == 1 else (kind, params)
 
-    res = window.run_window({"kinds": [1, 6, 3], "clients": 2}, 1, 0.05, submit)
+    res = window.run_window(suites.load("tpch"), {"kinds": [1, 6, 3], "clients": 2}, 1, 0.05, submit)
     by_kind = {r["kind"]: r for r in res["queries"] if r["client"] == 1}
     assert by_kind[1]["job_id"] == "j1" and by_kind[1]["answer"][0] == 1
     assert by_kind[6]["job_id"] == "j-failed" and by_kind[6]["error"].startswith("Refused")

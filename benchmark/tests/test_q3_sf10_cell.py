@@ -35,7 +35,8 @@ def test_cell_resolves_to_its_configuration_and_traffic():
     # every per-layer metric of the SF1 cell, and the build-row program's share
     listed = {m["name"] for m in harness.metrics_of_cell(bench, CELL, "per_layer")}
     sf1_listed = {m["name"] for m in harness.metrics_of_cell(bench, SF1_CELL, "per_layer")}
-    assert listed == sf1_listed and "join_agg_roofline" in listed and len(listed) == 22
+    assert listed == sf1_listed and "join_agg_roofline" in listed
+    assert CELL in next(m for m in bench["per_layer"] if m["name"] == "join_agg_roofline")["workloads"]
     e2e = {m["name"] for m in harness.metrics_of_cell(bench, CELL, "end_to_end")}
     assert e2e == {"setup_s", "query_geomean_s", "scan_rows_rate"}
 

@@ -3,7 +3,9 @@ import time
 
 import pytest
 
-from benchmark import queries, window
+from benchmark import queries, suites, window
+
+TPCH = suites.load("tpch")
 
 
 def _fake_submit(latency):
@@ -15,7 +17,7 @@ def _fake_submit(latency):
 
 def test_window_counts_whole_queries_only_and_ends_at_last_completion():
     traffic = {"kinds": [1, 6], "clients": 1, "order": "cycle"}
-    res = window.run_window(traffic, 3, 1.0, _fake_submit({1: 0.12, 6: 0.07}))
+    res = window.run_window(TPCH, traffic, 3, 1.0, _fake_submit({1: 0.12, 6: 0.07}))
     recs = res["queries"]
     assert recs and all(r["error"] is None for r in recs)
     # no query is cut: every record has its whole latency, none ends past the window
@@ -48,14 +50,14 @@ def test_failed_query_is_counted_not_fatal():
         if kind == 6:
             raise RuntimeError("refused")
         time.sleep(0.05)
-    res = window.run_window({"kinds": [1, 6], "clients": 1}, 1, 0.4, submit)
+    res = window.run_window(TPCH, {"kinds": [1, 6], "clients": 1}, 1, 0.4, submit)
     assert any(r["error"] for r in res["queries"]) and any(r["error"] is None for r in res["queries"])
 
 
 def test_after_first_cycle_hook_fires_once_between_cycles():
     seen = []
     res = window.run_window(
-        {"kinds": [1, 6], "clients": 1}, 1, 0.6, _fake_submit({1: 0.05, 6: 0.05}),
+        TPCH, {"kinds": [1, 6], "clients": 1}, 1, 0.6, _fake_submit({1: 0.05, 6: 0.05}),
         after_first_cycle=lambda: seen.append(time.monotonic()),
     )
     assert len(seen) == 1 and len(res["queries"]) > 2
@@ -63,7 +65,7 @@ def test_after_first_cycle_hook_fires_once_between_cycles():
 
 def test_several_clients_shuffle_and_fixed_requests():
     traffic = {"kinds": [1, 6, 3], "clients": 3, "order": "shuffle", "parameter_sets": 2}
-    res = window.run_window(traffic, 11, 0.5, _fake_submit({1: 0.03, 6: 0.02, 3: 0.04}))
+    res = window.run_window(TPCH, traffic, 11, 0.5, _fake_submit({1: 0.03, 6: 0.02, 3: 0.04}))
     assert {r["client"] for r in res["queries"]} == {0, 1, 2}
     # a kind's queries cycle through its two parameter sets and no more
     for kind in (1, 6, 3):
@@ -71,21 +73,21 @@ def test_several_clients_shuffle_and_fixed_requests():
         assert len(seen) == 2
     fixed = {"kinds": [1], "clients": 1, "requests": [
         {"kind": 1, "params": {"delta": 90}}, {"kind": 6, "params": {"year": 1994, "discount": 0.06, "quantity": 24}}]}
-    res = window.run_window(fixed, 1, 0.2, _fake_submit({1: 0.03, 6: 0.02}))
+    res = window.run_window(TPCH, fixed, 1, 0.2, _fake_submit({1: 0.03, 6: 0.02}))
     assert [r["kind"] for r in res["queries"][:3]] == [1, 6, 1]
     assert res["queries"][0]["params"] == {"delta": 90}
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 12345])
 def test_parameter_draws_are_spec_valid_and_warmup_differs_from_window(seed):
-    draws = queries.Draws(seed, [1, 6, 3], sets=3)
+    draws = suites.Draws(TPCH, seed, [1, 6, 3], sets=3)
     for kind in (1, 6, 3):
         warm = draws.window_sets(kind)
         win = [draws.window(kind, i) for i in range(40)]
         # the window sends only literals that warm-up has run, and cycles them
         assert len(warm) == 3 and all(p in warm for p in win) and win[:6] == warm + warm
         assert draws.aside(kind) not in warm
-        assert queries.Draws(seed, [kind]).window(kind, 5) == warm[0]  # one set by default
+        assert suites.Draws(TPCH, seed, [kind]).window(kind, 5) == warm[0]  # one set by default
         for p in queries.parameter_sets(kind):
             if kind == 1:
                 assert 60 <= p["delta"] <= 120
@@ -96,6 +98,7 @@ def test_parameter_draws_are_spec_valid_and_warmup_differs_from_window(seed):
             else:
                 assert p["segment"] in queries.SEGMENTS
                 assert dt.date(1995, 3, 1) <= dt.date.fromisoformat(p["date"]) <= dt.date(1995, 3, 31)
-    assert queries.Draws(seed, [1]).window(1, 0) == draws.window(1, 0)  # the same seed, the same inputs
-    other = queries.Draws(seed + 1, [6], sets=8)
-    assert [other.window(6, i) for i in range(8)] != [queries.Draws(seed, [6], sets=8).window(6, i) for i in range(8)]
+    assert suites.Draws(TPCH, seed, [1]).window(1, 0) == draws.window(1, 0)  # the same seed, the same inputs
+    other = suites.Draws(TPCH, seed + 1, [6], sets=8)
+    same_seed = suites.Draws(TPCH, seed, [6], sets=8)
+    assert [other.window(6, i) for i in range(8)] != [same_seed.window(6, i) for i in range(8)]

@@ -2,7 +2,7 @@
 
 One general loop reads a traffic file's parameters:
 
-- ``kinds``: the query kinds of the mix (TPC-H query numbers).
+- ``kinds``: the query kinds of the mix (the suite's kind ids).
 - ``clients``: closed-loop clients, each a thread with its own remote
   context and session; a client sends its next query when its last one has
   returned, with no think time.
@@ -13,7 +13,7 @@ One general loop reads a traffic file's parameters:
   through (default 1); warm-up runs every one, since the program compiles
   for each new set of literals.
 - ``requests``: optional fixed list of ``{"kind", "params"}`` replayed in
-  order instead of drawn parameters.
+  order instead of drawn parameters; a kind as the file gives it.
 
 Whole-query windows: a client submits its next query only while the time
 elapsed plus that kind's last latency fits in the window's seconds.  No
@@ -28,7 +28,7 @@ import random
 import threading
 import time
 
-from . import queries
+from . import suites
 
 # What ``submit`` may return in place of the bare answer: the answer and the
 # id of the job that ran it (``cluster.new_job_id``).  An exception may carry
@@ -36,11 +36,11 @@ from . import queries
 WithJob = collections.namedtuple("WithJob", "answer job_id")
 
 
-def plan(traffic: dict, seed: int, client: int):
+def plan(suite, traffic: dict, seed: int, client: int):
     """Endless iterator of (kind, params) for one client."""
     kinds = list(traffic["kinds"])
     fixed = traffic.get("requests")
-    draws = queries.Draws(seed, kinds, traffic.get("parameter_sets", 1))
+    draws = suites.Draws(suite, seed, kinds, traffic.get("parameter_sets", 1))
     rng = random.Random(f"{seed}/order/{client}")
     used = dict.fromkeys(kinds, 0)
     clients = int(traffic.get("clients", 1))
@@ -50,7 +50,7 @@ def plan(traffic: dict, seed: int, client: int):
     i = client
     while fixed:  # a fixed set, dealt round-robin to the clients
         r = fixed[i % len(fixed)]
-        yield int(r["kind"]), dict(r["params"])
+        yield r["kind"], dict(r["params"])
         i += clients
     while True:
         round_ = list(kinds)
@@ -61,7 +61,7 @@ def plan(traffic: dict, seed: int, client: int):
             used[kind] += 1
 
 
-def run_window(traffic: dict, seed: int, seconds: float, submit, after_first_cycle=None) -> dict:
+def run_window(suite, traffic: dict, seed: int, seconds: float, submit, after_first_cycle=None) -> dict:
     """Drive the window.  ``submit(client, kind, params)`` returns the
     answer, or a ``WithJob`` (or raises); a record keeps the job's id as
     ``job_id``.  A kind with no completion yet is submitted while
@@ -77,7 +77,7 @@ def run_window(traffic: dict, seed: int, seconds: float, submit, after_first_cyc
 
     def client_loop(c: int) -> None:
         per_cycle = len(traffic["kinds"])
-        for n, (kind, params) in enumerate(plan(traffic, seed, c)):
+        for n, (kind, params) in enumerate(plan(suite, traffic, seed, c)):
             if c == 0 and n == per_cycle and after_first_cycle is not None:
                 after_first_cycle()
             with lock:
